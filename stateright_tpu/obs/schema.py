@@ -1,25 +1,18 @@
 """The versioned run-telemetry event schema.
 
 One schema, every producer: the four device engines, the host BFS/DFS
-checkers, ``profiling.py``, ``bench.py``, and ``tools/device_session.py``
-all emit events that validate against the definitions here, so a single
-trace file (``STpu_TRACE=path``, JSONL) can be linted
-(``tools/trace_lint.py``), exported to a Perfetto-loadable Chrome trace
+checkers, ``profiling.py`` and ``bench.py`` all emit events that
+validate against the definitions here, so a single trace file
+(``STpu_TRACE=path``, JSONL) can be linted (``tools/trace_lint.py``),
+exported to a Perfetto-loadable Chrome trace
 or a Prometheus text dump (``tools/trace_export.py``), and diffed across
 rounds without per-engine parsers.
 
-Two event families share the stream:
-
-- **Trace events** carry a ``type`` key: ``run_start``, ``wave``,
-  ``span``, ``counter``, ``gauge``, ``grow``, ``overflow_redispatch``,
-  ``run_end``. The tracer stamps every one with ``schema_version``,
-  ``engine``, ``run`` (a per-tracer id, so interleaved producers in one
-  file separate cleanly), and ``t`` (``time.monotonic()`` seconds).
-- **Session events** carry an ``event`` key — the
-  ``tools/device_session.py`` stdout protocol (``init`` / ``sweep`` /
-  ``done`` / ...), which predates the tracer but is versioned and
-  timestamped by the same rules so ``trace_lint`` validates a captured
-  session verbatim.
+Every event carries a ``type`` key: ``run_start``, ``wave``,
+``span``, ``counter``, ``gauge``, ``grow``, ``overflow_redispatch``,
+``run_end``, .... The tracer stamps every one with ``schema_version``,
+``engine``, ``run`` (a per-tracer id, so interleaved producers in one
+file separate cleanly), and ``t`` (``time.monotonic()`` seconds).
 
 The WAVE event is the load-bearing one: every engine emits the exact
 same field set (``WAVE_FIELDS``) per dispatch, with ``null`` for fields
@@ -535,11 +528,6 @@ EVENT_TYPES: Dict[str, Dict[str, tuple]] = {
 _STAMPED = {"type": _STR, "schema_version": _INT, "engine": _STR,
             "run": _STR, "t": _NUM}
 
-#: Required fields of a device_session stdout event (the rest of the
-#: payload is event-specific and unconstrained).
-SESSION_FIELDS = {"event": _STR, "schema_version": _INT, "t": _NUM,
-                  "unix_t": _NUM}
-
 
 def _typecheck(value, types) -> bool:
     # bool subclasses int: a field typed int/float must not accept True.
@@ -563,19 +551,10 @@ def _check_fields(obj: dict, fields: Dict[str, tuple],
 
 
 def validate_event(obj) -> List[str]:
-    """Validates one decoded event (trace or session family); returns a
-    list of error strings (empty = valid)."""
+    """Validates one decoded event; returns a list of error strings
+    (empty = valid)."""
     if not isinstance(obj, dict):
         return ["event is not a JSON object"]
-    if "event" in obj and "type" not in obj:
-        where = f"session event {obj.get('event')!r}"
-        errors = _check_fields(obj, SESSION_FIELDS, where)
-        if (isinstance(obj.get("schema_version"), int)
-                and obj["schema_version"] > SCHEMA_VERSION):
-            errors.append(f"{where}: schema_version "
-                          f"{obj['schema_version']} is newer than this "
-                          f"validator ({SCHEMA_VERSION})")
-        return errors
     etype = obj.get("type")
     where = f"trace event {etype!r}"
     if etype not in EVENT_TYPES:

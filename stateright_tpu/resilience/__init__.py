@@ -32,15 +32,14 @@ Resilience and Elasticity sections of ARCHITECTURE.md.
 from .elastic import ElasticChecker, elastic_check
 from .faults import (FAULT_POINTS, FAULTS_ENV, ExchangeIntegrityError,
                      FaultPlan, InjectedFault, InjectedOom, NULL_PLAN,
-                     fault_plan_from_env, is_oom, reset_fault_plans,
-                     strip_point)
+                     fault_plan_from_env, is_oom, reset_fault_plans)
 from .membership import Membership, OwnerMap
 from .supervisor import Supervisor, newest_valid_checkpoint, supervise
 
 __all__ = [
     "FAULT_POINTS", "FAULTS_ENV", "ExchangeIntegrityError", "FaultPlan",
     "InjectedFault", "InjectedOom", "NULL_PLAN", "fault_plan_from_env",
-    "is_oom", "reset_fault_plans", "strip_point",
+    "is_oom", "reset_fault_plans",
     "Supervisor", "newest_valid_checkpoint", "supervise",
     "ElasticChecker", "elastic_check", "Membership", "OwnerMap",
 ]

@@ -897,6 +897,12 @@ class _Handle:
         self.kill_event = kill_event
 
 
+def _backend() -> str:
+    import jax
+
+    return jax.default_backend()
+
+
 class ElasticChecker:
     """Runs an owner-partitioned BFS over ``workers`` elastic workers.
 
@@ -934,6 +940,13 @@ class ElasticChecker:
             raise ValueError(
                 f"transport must be 'thread' or 'process', got "
                 f"{transport!r}")
+        if transport == "process" and _backend() == "tpu":
+            # A chip belongs to one process: spawn children that import
+            # JAX would contend with this one for it.
+            raise ValueError(
+                "transport='process' is refused on a TPU backend: each "
+                "worker process would need the chip this process holds; "
+                "use transport='thread'")
         if workers < 1:
             raise ValueError("need at least one worker")
         self._factory = model_factory

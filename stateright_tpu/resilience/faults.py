@@ -56,7 +56,7 @@ from ..obs.tracer import tracer_from_env
 __all__ = [
     "FAULTS_ENV", "FAULT_POINTS", "InjectedFault", "InjectedOom",
     "ExchangeIntegrityError", "FaultPlan", "NULL_PLAN",
-    "fault_plan_from_env", "reset_fault_plans", "strip_point", "is_oom",
+    "fault_plan_from_env", "reset_fault_plans", "is_oom",
 ]
 
 #: Environment knob: a comma-separated fault spec (see module docstring).
@@ -81,8 +81,6 @@ FAULT_POINTS: Dict[str, str] = {
     "a2a_corrupt": "sharded all-to-all: the Nth exchange delivers a "
                    "corrupted fingerprint payload",
     "host_crash": "host BFS worker: raise in the Nth check block",
-    "child_death": "bench device child: os._exit mid-run at the Nth "
-                   "supervision tick (models SIGKILL/preemption)",
     "worker_crash": "elastic worker: die (hard-exit / abrupt socket "
                     "close) at the Nth coordinated round — the "
                     "coordinator's lease machinery must turn it into "
@@ -334,13 +332,3 @@ def reset_fault_plans() -> None:
         for plan in _PLANS.values():
             plan.close()
         _PLANS.clear()
-
-
-def strip_point(spec: str, point: str) -> str:
-    """Returns ``spec`` without any entries for ``point``. The bench
-    uses this when respawning a dead device child: an inherited
-    ``child_death`` spec would kill the respawn at the same
-    deterministic tick, by construction forever."""
-    return ",".join(
-        e for e in spec.split(",")
-        if e.strip() and e.strip().split("@")[0].strip() != point)

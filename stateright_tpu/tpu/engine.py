@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from ..checker.base import Checker
 from ..checker.path import Path
 from ..checker.visitor import as_visitor
+from ..jit_cache import enable_persistent_jit_cache
 from ..model import Expectation, Model
 from ..obs import (prof_from_env, recorder_from_env, tracer_from_env,
                    wave_obs_from_env)
@@ -184,6 +185,9 @@ class TpuBfsChecker(Checker):
                  wave_kernel: Optional[bool] = None,
                  wave_matmul: Optional[bool] = None,
                  async_io: Optional[bool] = None):
+        # Before this process's first compile: JAX decides once whether
+        # the persistent cache is in use (jit_cache.py).
+        enable_persistent_jit_cache()
         model = builder._model
         # Cross-instance compiled-program sharing (jit_cache.
         # WaveProgramCache): armed only when BOTH a cache and a model
@@ -279,9 +283,12 @@ class TpuBfsChecker(Checker):
             wave_kernel = os.environ.get(
                 "STpu_WAVE_KERNEL", "") not in ("", "0")
         self._wave_kernel_on = bool(wave_kernel)
-        if self._wave_kernel_on:
-            from .pallas_table import PALLAS_AVAILABLE
+        if self._wave_kernel_on or table_impl == "pallas":
+            from .pallas_table import PALLAS_AVAILABLE, refuse_on_tpu
 
+            refuse_on_tpu("wave_kernel=True" if self._wave_kernel_on
+                          else "table_impl='pallas'")
+        if self._wave_kernel_on:
             if not PALLAS_AVAILABLE:
                 warnings.warn(
                     "wave_kernel requested but pallas is unavailable "

@@ -9,11 +9,11 @@ latency; the round-5/7 kernel (``dedup_and_insert_pallas``) stages the
 whole table into VMEM once, runs every probe round at VMEM latency,
 and writes the table back once — the structure a TPU actually wants
 for a probe chain. The capacity gate derives from the backend's
-reported per-core VMEM budget when it exposes one
-(``_vmem_budget_bytes``) and falls back to the classic 16 MB
-assumption (tables up to 2^20 uint64 entries = 8 MB) otherwise; the
-engine degrades to the XLA path above the gate and when Pallas is
-unavailable.
+reported per-core VMEM budget when it exposes one, else from a table
+keyed by ``device_kind`` (``_vmem_budget_bytes``); the engine degrades
+to the XLA path above the gate and when Pallas is unavailable. On a
+TPU the kernels are refused outright (``TPU_REFUSAL``): the TPU
+compiler cannot pass their uint64 operands into Mosaic.
 
 Two dedup levels run in the probe kernel (ISSUE 2): the intra-wave
 *local dedup* (first-occurrence collapse of duplicate fingerprints
@@ -49,7 +49,7 @@ the differential suites (``tests/test_wave_kernel.py``) pin counts,
 discoveries, parent maps, and checkpoint payload bytes knob-on vs off
 across all four engines. On the CPU backend the kernels run in Pallas
 interpret mode (``pl.pallas_call(..., interpret=True)``) — correct but
-not fast; the TPU lowering is what the hardware session A/Bs.
+not fast. On a TPU the engines refuse them (``refuse_on_tpu``).
 
 Reference analog: the ``DashMap`` visited set of `bfs.rs:26,245-259`
 plus the per-worker successor loop of `bfs.rs:75-152`, collapsed into
@@ -66,7 +66,8 @@ import jax.numpy as jnp
 
 from .hashing import SENTINEL
 
-__all__ = ["PALLAS_AVAILABLE", "pallas_table_capacity_ok",
+__all__ = ["PALLAS_AVAILABLE", "TPU_REFUSAL", "refuse_on_tpu",
+           "pallas_table_capacity_ok",
            "pallas_table_capacity_limit", "dedup_and_insert_pallas",
            "default_interpret", "wave_kernel_ok", "sender_kernel_ok",
            "wave_kernel_bytes", "build_wave_megakernel",
@@ -80,10 +81,6 @@ except ImportError:  # pragma: no cover - jax always bundles pallas here
     pl = None
     PALLAS_AVAILABLE = False
 
-#: fallback VMEM capacity gate when the backend does not expose a VMEM
-#: budget (uint64 entries; 2^20 * 8 B = 8 MB of the canonical ~16 MB)
-_MAX_VMEM_CAPACITY = 1 << 20
-
 #: fraction of the reported VMEM budget the resident table may take —
 #: the probe state (fps, candidate mask, indices, steps) and the local
 #: dedup scratch must co-reside with it.
@@ -96,9 +93,25 @@ _CAPACITY_LIMIT_CACHE: list = []
 #: the compiler's own spills and double-buffering.
 _WAVE_KERNEL_VMEM_FRACTION = 0.9
 
-#: the canonical per-core VMEM assumption when the backend exposes no
-#: budget (the same 16 MB the table-fraction gate is derived from).
-_FALLBACK_VMEM_BYTES = 16 << 20
+#: per-core VMEM a kernel may use, by ``device_kind``, for devices that
+#: report no budget. ``cpu`` is the interpret-mode stand-in, sized like
+#: the TPU default. ``TPU v5 lite`` (v5e) takes Mosaic's default scoped
+#: VMEM limit, 16 MiB. A kind missing here is an error, never a guess.
+_VMEM_BYTES_BY_KIND = {"cpu": 16 << 20, "TPU v5 lite": 16 << 20}
+
+#: Why the kernels in this module cannot run on a TPU. Their visited
+#: table and fingerprints are uint64, and Mosaic has no 64-bit vectors.
+#: Compiled for a described v5e (PR 21, tests/test_chip_compile.py), a
+#: uint64 constant of 2^63 or more fails to lower (``IntegerAttr``);
+#: with the constants made small, the uint64-to-int32 convert recurses
+#: without end in the lowering; and a kernel that only adds uint64
+#: vectors is refused by XLA itself, which cannot pass 64-bit operands
+#: into a ``tpu_custom_call``.
+TPU_REFUSAL = (
+    "Mosaic has no 64-bit vectors, and XLA refuses uint64 operands of a "
+    "tpu_custom_call (\"UNIMPLEMENTED: While rewriting computation to "
+    "not contain X64 element types ...\"); the kernels' uint64 table "
+    "and fingerprints need a uint32-pair layout first (ROADMAP A4)")
 
 _BACKEND_DECISION_CACHE: list = []
 
@@ -114,51 +127,50 @@ def default_interpret() -> bool:
     return _BACKEND_DECISION_CACHE[0]
 
 
-def _vmem_budget_bytes() -> Optional[int]:
-    """The per-core VMEM budget, when the backend exposes one. JAX has
-    no stable cross-version API for this, so probe the known spellings
-    (device attribute, then ``memory_stats()`` keys) and return None —
-    caller falls back to the canonical constant — when none answers.
+def refuse_on_tpu(what: str) -> None:
+    """Raises where the kernels would have to lower for a TPU: they
+    never run interpret mode there, and never swap silently to XLA."""
+    if not default_interpret():
+        raise NotImplementedError(
+            f"{what} cannot run on a TPU: {TPU_REFUSAL}")
+
+
+def _vmem_budget_bytes() -> int:
+    """The per-core VMEM budget: what the device reports, else the
+    ``device_kind`` table above. JAX has no stable cross-version API
+    for this, so probe the known spellings (device attribute, then
+    ``memory_stats()`` keys). Raises for a kind the table lacks.
     Note ``jax.local_devices()`` initializes the default backend if
-    none exists yet; the engines only reach this from wave-program
-    builds (a backend is already live), but a DIRECT call to
-    ``pallas_table_capacity_limit()`` before platform selection will
-    pin the default backend as a side effect."""
-    try:
-        device = jax.local_devices()[0]
-    except Exception:  # noqa: BLE001 — no backend, no budget
-        return None
+    none exists yet."""
+    device = jax.local_devices()[0]
     for attr in ("vmem_size_bytes", "core_vmem_size_bytes"):
         value = getattr(device, attr, None)
         if value:
             return int(value)
-    stats_fn = getattr(device, "memory_stats", None)
-    if callable(stats_fn):
-        try:
-            stats = stats_fn() or {}
-        except Exception:  # noqa: BLE001 — some backends raise here
-            return None
-        for key in ("vmem_size_bytes", "vmem_bytes_limit",
-                    "vmem_bytes_reservable_limit"):
-            if stats.get(key):
-                return int(stats[key])
-    return None
+    stats = device.memory_stats() or {}
+    for key in ("vmem_size_bytes", "vmem_bytes_limit",
+                "vmem_bytes_reservable_limit"):
+        if stats.get(key):
+            return int(stats[key])
+    try:
+        return _VMEM_BYTES_BY_KIND[device.device_kind]
+    except KeyError:
+        raise NotImplementedError(
+            f"no VMEM budget for device kind {device.device_kind!r}: "
+            "the device reports none and pallas_table."
+            "_VMEM_BYTES_BY_KIND has no entry") from None
 
 
 def pallas_table_capacity_limit() -> int:
     """Largest table capacity (uint64 entries, power of two) the kernel
-    will stage into VMEM: derived from the backend budget when exposed,
-    else the canonical ``2^20``. Cached per process — the budget is a
-    hardware property, and this is called per wave-program build."""
+    will stage into VMEM, derived from the VMEM budget. Cached per
+    process — the budget is a hardware property, and this is called per
+    wave-program build."""
     if not _CAPACITY_LIMIT_CACHE:
-        budget = _vmem_budget_bytes()
-        if budget:
-            entries = max(1, int(budget * _VMEM_TABLE_FRACTION) // 8)
-            limit = 1 << (entries.bit_length() - 1)  # power-of-two floor
-            limit = max(limit, 1 << 12)
-        else:
-            limit = _MAX_VMEM_CAPACITY
-        _CAPACITY_LIMIT_CACHE.append(limit)
+        entries = max(1, int(_vmem_budget_bytes()
+                             * _VMEM_TABLE_FRACTION) // 8)
+        limit = 1 << (entries.bit_length() - 1)  # power-of-two floor
+        _CAPACITY_LIMIT_CACHE.append(max(limit, 1 << 12))
     return _CAPACITY_LIMIT_CACHE[0]
 
 
@@ -323,10 +335,6 @@ def wave_kernel_bytes(batch: int, fanout: int, width: int,
             + extra_bytes)                     # caller extras (matmul)
 
 
-def _vmem_budget() -> int:
-    return _vmem_budget_bytes() or _FALLBACK_VMEM_BYTES
-
-
 def wave_kernel_ok(capacity: int, batch: int, fanout: int, width: int,
                    row_width: int, extra_bytes: int = 0) -> bool:
     """Whether the full megakernel (with the table staged in VMEM) fits
@@ -336,7 +344,7 @@ def wave_kernel_ok(capacity: int, batch: int, fanout: int, width: int,
     return (PALLAS_AVAILABLE
             and wave_kernel_bytes(batch, fanout, width, row_width,
                                   capacity, extra_bytes)
-            <= _WAVE_KERNEL_VMEM_FRACTION * _vmem_budget())
+            <= _WAVE_KERNEL_VMEM_FRACTION * _vmem_budget_bytes())
 
 
 def sender_kernel_ok(batch: int, fanout: int, width: int,
@@ -347,7 +355,7 @@ def sender_kernel_ok(batch: int, fanout: int, width: int,
     return (PALLAS_AVAILABLE
             and wave_kernel_bytes(batch, fanout, width, row_width, 0,
                                   extra_bytes)
-            <= _WAVE_KERNEL_VMEM_FRACTION * _vmem_budget())
+            <= _WAVE_KERNEL_VMEM_FRACTION * _vmem_budget_bytes())
 
 
 def _wave_front(dm, use_sym: bool, layout, store_rows, valid,

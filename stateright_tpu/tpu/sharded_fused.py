@@ -114,8 +114,15 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             host_table_insert(table[i], np.fromiter(
                 (int(f) for f in bucket), np.uint64, len(bucket)))
         self._seed_occ = [len(b) for b in buckets]
+        self._shard_occ = self._seed_occ
         self._resident = len(fps)
         return jax.device_put(table.reshape(n * cap), self._shard_spec())
+
+    def shard_occupancy(self) -> list:
+        """Visited-table entries held by each shard, as of the last
+        processed dispatch: where the states landed on the mesh."""
+        with self._lock:
+            return [int(o) for o in self._shard_occ]
 
     def _table_bytes(self, capacity: int) -> int:
         # Capacity is PER SHARD; the device footprint is the mesh's.
@@ -341,9 +348,12 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
              disc, waves, target) = carry
             # Every operand is either replicated (succ_total, disc,
             # waves, target) or globally reduced, so all shards agree.
+            # The TPU lowers 64-bit all-reduces for sums only, so the
+            # maxima run in int32: a shard's rows stay far below 2^31
+            # (a 2^31-entry table alone would fill a 16 GB chip).
             live = jax.lax.psum(tail - head, "shard")
-            worst_tail = jax.lax.pmax(tail, "shard")
-            worst_occ = jax.lax.pmax(occ, "shard")
+            worst_tail = jax.lax.pmax(tail.astype(jnp.int32), "shard")
+            worst_occ = jax.lax.pmax(occ.astype(jnp.int32), "shard")
             any_err = jax.lax.pmax(err.astype(jnp.int32), "shard") > 0
             more = (waves < K) & (live > 0) & ~any_err
             more = more & (worst_tail + R <= ucap)
@@ -576,6 +586,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 self._shard_heads = heads
                 self._shard_tails = tails
                 self._resident = int(occs.sum())  # device occupancy
+                self._shard_occ = occs
                 self._state_count = base_states + succ_total
                 novel = new_total - arena_total
                 self._unique_count += novel

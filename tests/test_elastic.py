@@ -664,3 +664,15 @@ def test_elastic_paxos_kill_and_join_exact_space(tmp_path):
     kinds = [e["type"] for e in c.events]
     assert kinds == ["worker_lost", "migrate_done", "worker_join",
                      "rebalance"]
+
+
+def test_process_transport_is_refused_on_a_tpu(monkeypatch):
+    """A chip belongs to one process: spawn-transport workers would
+    contend with the coordinator for it, so a TPU backend refuses
+    them before any worker starts."""
+    from stateright_tpu.resilience import elastic
+
+    monkeypatch.setattr(elastic, "_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="refused on a TPU"):
+        ElasticChecker(partial(TwoPhaseSys, RMS), workers=2,
+                       transport="process")

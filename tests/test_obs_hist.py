@@ -546,9 +546,9 @@ def test_bench_compare_rounds():
     assert rc == 0
     # Trajectory mode over three rounds.
     rc, out, _ = _bench_compare(
-        os.path.join(_REPO, "BENCH_r05.json"), r07, r09)
+        os.path.join(_REPO, "BENCH_r04.json"), r07, r09)
     assert rc == 0
-    assert "r05" in out and "delta%" in out
+    assert "r04" in out and "delta%" in out
 
 
 # -- Service soak (slow) ---------------------------------------------------

@@ -15,7 +15,7 @@ Contracts pinned here:
   contract; the wider 4-engine parity suites are the main guard).
 - **Tooling round trip**: a capture lints clean (this is the tier-1
   wiring of trace_lint), exports to a Chrome/Perfetto trace, and dumps
-  Prometheus text; the device_session event family validates too.
+  Prometheus text.
 """
 
 import io
@@ -155,19 +155,13 @@ def test_tracer_spans_counters_nested(tmp_path):
     assert events[-1]["counters"] == {"widgets": 5}
 
 
-def test_trace_lint_cli_and_session_events(tmp_path, monkeypatch):
+def test_trace_lint_cli(tmp_path, monkeypatch):
     """trace_lint runs standalone (the tier-1 wiring) on an engine
-    capture, validates the device_session event family, and actually
-    rejects malformed streams."""
+    capture, and actually rejects malformed streams."""
     path = tmp_path / "run.jsonl"
     monkeypatch.setenv("STpu_TRACE", str(path))
     _spawn(TwoPhaseSys(3), "classic").join()
     monkeypatch.delenv("STpu_TRACE")
-    # A device_session-style event shares the stream format.
-    with open(path, "a", encoding="utf-8") as f:
-        f.write(json.dumps({"event": "init", "platform": "cpu",
-                            "schema_version": SCHEMA_VERSION,
-                            "t": 1.0, "unix_t": 2.0}) + "\n")
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "trace_lint.py"),
          str(path)], capture_output=True, text=True)
@@ -209,26 +203,13 @@ def test_trace_export_chrome_and_prometheus(tmp_path, monkeypatch):
     assert str(c.state_count()) in text
 
 
-def test_session_schema_version_lockstep():
-    """tools/device_session.py duplicates the schema version as a
-    literal (it must emit before any package import); keep it pinned
-    to the real one."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "_device_session", os.path.join(_REPO, "tools",
-                                        "device_session.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.SESSION_SCHEMA_VERSION == SCHEMA_VERSION
-    # And its emit() output validates as a session event.
-    import contextlib
-
-    buf = io.StringIO()
-    with contextlib.redirect_stdout(buf):
-        mod.emit({"event": "init", "platform": "cpu"})
-    evt = json.loads(buf.getvalue())
-    assert validate_event(evt) == []
+def test_untyped_event_is_rejected():
+    """Every event carries a ``type``; the retired session family's
+    untyped ``{"event": ...}`` lines no longer validate."""
+    errors = validate_event({"event": "init", "platform": "cpu",
+                             "schema_version": SCHEMA_VERSION,
+                             "t": 1.0, "unix_t": 2.0})
+    assert errors and "unknown type" in errors[0]
 
 
 def test_report_flushes_and_prints_rate():

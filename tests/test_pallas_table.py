@@ -99,12 +99,31 @@ def test_engine_parity_2pc():
 
 def test_capacity_limit_is_sane():
     """The VMEM-derived gate is a power of two in a plausible range
-    (falls back to 2^20 when the backend exposes no budget — the CPU
-    backend here usually doesn't)."""
+    (2^20 from the device-kind table when the backend exposes no
+    budget — the CPU backend here doesn't)."""
     limit = pallas_table_capacity_limit()
     assert limit >= 1 << 12
     assert limit & (limit - 1) == 0
     assert pallas_table_capacity_limit() == limit  # cached, stable
+
+
+def test_vmem_budget_of_unknown_device_kind_raises(monkeypatch):
+    """No silent default: a device that reports no VMEM budget and is
+    missing from the device-kind table is an error."""
+    from stateright_tpu.tpu import pallas_table
+
+    class Device:
+        device_kind = "TPU v99"
+
+        def memory_stats(self):
+            return None
+
+    monkeypatch.setattr(pallas_table.jax, "local_devices",
+                        lambda: [Device()])
+    with pytest.raises(NotImplementedError, match="TPU v99"):
+        pallas_table._vmem_budget_bytes()
+    Device.device_kind = "TPU v5 lite"
+    assert pallas_table._vmem_budget_bytes() == 16 << 20
 
 
 def test_capacity_fallback_warns_once():

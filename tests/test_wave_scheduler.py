@@ -170,7 +170,7 @@ def test_succ_path_opts_bit_identical_paxos(engine):
 
 
 def test_scheduler_stats_report_succ_telemetry():
-    """bench.py / device_session forward scheduler_stats verbatim, so
+    """bench.py forwards scheduler_stats verbatim, so
     the successor-path keys must be present and self-consistent."""
     c = TwoPhaseSys(4).checker().spawn_tpu_bfs(
         batch_size=64, fused=False).join()
@@ -299,45 +299,36 @@ def test_steady_rate_excludes_compile_time():
     assert abs(bench._steady_rate(Lazy()) - 100.0) < 1e-6
 
 
-def test_parity_gate_uses_device_counts(monkeypatch):
-    """When the device child streamed back its own parity counts, the
-    gate compares the HOST reference against those (the backend that
-    produced the headline), without a local device rerun."""
+def test_parity_gate_runs_device_in_process(monkeypatch):
+    """The gate compares the host reference with a device run made in
+    this process, on the backend the headline runs on, and records that
+    backend."""
     import bench
 
-    class Host:
+    class Run:
+        def __init__(self, unique):
+            self.unique = unique
+
         def unique_state_count(self):
-            return 8832
+            return self.unique
 
         def discoveries(self):
             return {"atomicity": None}
 
+    device = {"unique": 8832}
+    monkeypatch.setattr(bench, "RESULT", dict(bench.RESULT))
     monkeypatch.setitem(bench._PARITY, "status", "pending")
     monkeypatch.setattr(bench, "_host_bfs",
-                        lambda model, cap=None: (Host(), 100.0, 1.0))
-
-    def boom(*a, **k):
-        raise AssertionError("local device parity rerun not expected")
-
-    monkeypatch.setattr(bench, "_tpu_bfs", boom)
+                        lambda model, cap=None: (Run(8832), 100.0, 1.0))
+    monkeypatch.setattr(bench, "_tpu_bfs", lambda *a, **k: (
+        Run(device["unique"]), 123.0, True))
     monkeypatch.setenv("BENCH_PARITY_RMS", "5")
-    bench.RESULT["device_parity"] = {
-        "platform": "tpu", "rms": 5, "unique": 8832,
-        "discoveries": ["atomicity"], "rate": 123.0, "finished": True}
-    try:
+    bench._stage_parity_gate("tpu")
+    assert bench._PARITY["status"] == "ok"
+    assert bench.RESULT["parity_backend"] == "tpu"
+    assert "tpu backend" in bench.RESULT["parity"]
+    # Mismatched counts must fail the gate.
+    bench._PARITY["status"] = "pending"
+    device["unique"] = 8831
+    with pytest.raises(AssertionError, match="unique-state mismatch"):
         bench._stage_parity_gate("tpu")
-        assert bench._PARITY["status"] == "ok"
-        assert bench.RESULT["parity_backend"] == "tpu"
-        assert "tpu backend" in bench.RESULT["parity"]
-        # Mismatched counts must fail the gate.
-        bench._PARITY["status"] = "pending"
-        bench.RESULT["device_parity"]["unique"] = 8831
-        with pytest.raises(AssertionError, match="unique-state mismatch"):
-            bench._stage_parity_gate("tpu")
-    finally:
-        bench.RESULT.pop("device_parity", None)
-        bench.RESULT.pop("parity_backend", None)
-        bench.RESULT.pop("parity", None)
-        bench.RESULT.pop("parity_host_states_per_sec", None)
-        bench.RESULT.pop("parity_tpu_states_per_sec", None)
-        bench._PARITY["status"] = "pending"

@@ -119,7 +119,7 @@ def to_chrome(events: List[dict]) -> dict:
         etype = evt.get("type")
         t = evt.get("t")
         if etype is None or not isinstance(t, (int, float)):
-            continue  # session-family events have no type/track
+            continue  # not a trace event: no track
         pid = pid_for(evt)
         run = evt.get("run", "?")
         if evt.get("engine") == "elastic_worker":

@@ -1,14 +1,9 @@
 #!/usr/bin/env python
 """Validates a JSONL telemetry stream against the obs schema.
 
-Accepts both families sharing the stream format:
-
-- an ``STpu_TRACE`` capture (trace events: ``run_start`` / ``wave`` /
-  ``span`` / ``counter`` / ``gauge`` / ``grow`` /
-  ``overflow_redispatch`` / ``run_end``), and
-- a ``tools/device_session.py`` stdout capture (session events:
-  ``init`` / ``sweep`` / ``done`` / ... — versioned and timestamped by
-  the same rules).
+Accepts an ``STpu_TRACE`` capture (``run_start`` / ``wave`` /
+``span`` / ``counter`` / ``gauge`` / ``grow`` / ``overflow_redispatch``
+/ ``run_end`` / ... events).
 
 Used by the tier-1 suite (``tests/test_obs_trace.py``) and runnable
 standalone::
@@ -131,8 +126,7 @@ withdrawn for v6+ captures. v5 and older captures still lint under
 their own rules.
 
 Dependency-free beyond ``stateright_tpu.obs.schema`` (no jax, no
-backend init) — safe to run against a capture while a measurement
-session holds the accelerator.
+backend init) — safe to run while another process holds the chip.
 """
 
 from __future__ import annotations
@@ -163,8 +157,7 @@ def _too_new(obj) -> bool:
 def lint_lines(lines) -> Tuple[Dict[str, int], List[str]]:
     """Validates an iterable of JSONL lines; returns
     ``(counts_by_kind, errors)``. ``counts_by_kind`` tallies event
-    types (trace family) and event names (session family), plus a
-    ``runs`` entry."""
+    types, plus a ``runs`` entry."""
     counts: Dict[str, int] = {}
     errors: List[str] = []
     last_wave: Dict[str, int] = {}
@@ -261,7 +254,7 @@ def lint_lines(lines) -> Tuple[Dict[str, int], List[str]]:
         if first_event:
             dump_mode = obj.get("type") == "postmortem"
             first_event = False
-        kind = obj.get("type") or f"session:{obj.get('event')}"
+        kind = obj.get("type")
         counts[kind] = counts.get(kind, 0) + 1
         run = obj.get("run")
         if run:
@@ -770,9 +763,8 @@ def lint_file(path: str) -> Tuple[Dict[str, int], List[str]]:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="validate a JSONL telemetry stream (STpu_TRACE "
-                    "capture or device_session stdout) against the obs "
-                    "schema")
+        description="validate a JSONL telemetry stream (an STpu_TRACE "
+                    "capture) against the obs schema")
     ap.add_argument("path", help="JSONL file to validate")
     ap.add_argument("--quiet", action="store_true",
                     help="suppress individual errors (summary only)")
