@@ -986,20 +986,6 @@ def _stage_headline(platform):
         if native_rate:
             RESULT["native_host_states_per_sec"] = round(native_rate, 1)
             _set_headline(native_rate, "native C++ spawn_bfs")
-    if _remaining() > 45:
-        # Per-stage wave-time attribution (staged timed dispatches on a
-        # short run of the same workload) — the data that decides where
-        # the next device optimization goes.
-        try:
-            from stateright_tpu.tpu.profiling import measure_wave_breakdown
-
-            RESULT["wave_breakdown"] = measure_wave_breakdown(
-                model, batch_size=batch, max_waves=8,
-                max_batch_size=max_batch,
-                deadline_s=max(10.0, _remaining() - 35))
-        except Exception as e:  # noqa: BLE001 — attribution is optional
-            RESULT["wave_breakdown_error"] = \
-                f"{type(e).__name__}: {e}"[:300]
 
 
 def _stage_service(platform) -> None:

@@ -197,7 +197,10 @@ class FlightRecorder:
                     # v13 cost attribution: null when the profiler is
                     # disarmed / the program has no cost model /
                     # the dispatch was not sampled.
-                    "cost_flops", "cost_bytes", "cost_ratio"):
+                    "cost_flops", "cost_bytes", "cost_ratio",
+                    # v15 loop rounds and host seconds: null where
+                    # not counted.
+                    "probe_rounds", "dedup_rounds", "host_s"):
             out.setdefault(key, None)
         return out
 

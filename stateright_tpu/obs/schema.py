@@ -35,6 +35,7 @@ __all__ = [
     "WAVE_FIELDS", "WAVE_FIELDS_V1", "WAVE_FIELDS_V2",
     "WAVE_FIELDS_V5", "WAVE_FIELDS_V6", "WAVE_FIELDS_V8",
     "WAVE_FIELDS_V9", "WAVE_FIELDS_V11", "WAVE_FIELDS_V12",
+    "WAVE_FIELDS_V14",
     "validate_event", "validate_line",
 ]
 
@@ -195,10 +196,20 @@ SHED_REASONS = ("slo_burn", "brownout", "retry_budget", "queue_full")
 #: round-10 ``requested``/``kept`` honesty: ``requested`` is the rung
 #: the policy asked for, ``kept`` the rung actually in force after
 #: actuation.
-#: v1-v13 streams still validate (against their version's field set);
+#: v15: fused dispatches count their loops — wave events gained
+#: ``probe_rounds`` and ``dedup_rounds`` (the rounds the visited-table
+#: probe loop and the local-dedup loop ran, summed over the dispatch's
+#: waves) and ``host_s`` (the host loop's own seconds on the dispatch:
+#: its launch and processing, less the blocking stats read). ``null``
+#: on producers that do not count them (every engine but the fused
+#: one on its XLA path). The fused host loop's spans (``fused.launch``,
+#: ``fused.process``, ``fused.stats_wait``, ``fused.grow``,
+#: ``fused.checkpoint``, ``fused.parent_sync``) are ordinary ``span``
+#: events when tracing is on.
+#: v1-v14 streams still validate (against their version's field set);
 #: streams NEWER than this validator are rejected with a clear
 #: upgrade message instead of a cascade of field-set mismatches.
-SCHEMA_VERSION = 14
+SCHEMA_VERSION = 15
 
 #: Environment knob: set to a file path to stream JSONL events there.
 #: Unset means the null tracer — the hot loop pays one attribute check.
@@ -318,6 +329,11 @@ WAVE_FIELDS: Dict[str, tuple] = {
     "cost_flops": _NUM + (_NULL,),
     "cost_bytes": _NUM + (_NULL,),
     "cost_ratio": _NUM + (_NULL,),
+    # v15: the fused dispatch's loop rounds and host seconds (null
+    # where not counted).
+    "probe_rounds": _INT + (_NULL,),
+    "dedup_rounds": _INT + (_NULL,),
+    "host_s": _NUM + (_NULL,),
 }
 
 #: v5 attribution keys (absent from v2-v4 wave events).
@@ -343,52 +359,62 @@ _WAVE_V12_KEYS = ("expand_impl",)
 #: v13 cost-attribution keys (absent from v1-v12 wave events).
 _WAVE_V13_KEYS = ("cost_flops", "cost_bytes", "cost_ratio")
 
+#: v15 loop-round and host-time keys (absent from v1-v14 wave events).
+_WAVE_V15_KEYS = ("probe_rounds", "dedup_rounds", "host_s")
+
 #: The v1 wave field set (no bandwidth gauges) — v1 captures validate
 #: against this exactly.
 WAVE_FIELDS_V1: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in ("bytes_per_state", "arena_bytes", "table_bytes")
     + _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
-    + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS}
+    + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
+    + _WAVE_V15_KEYS}
 
 #: The v2-v4 wave field set (bandwidth gauges, no attribution keys).
 WAVE_FIELDS_V2: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS
     + _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
-    + _WAVE_V13_KEYS}
+    + _WAVE_V13_KEYS + _WAVE_V15_KEYS}
 
 #: The v5 wave field set (attribution keys, no tier gauges).
 WAVE_FIELDS_V5: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
-    + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS}
+    + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS}
 
 #: The v6-v7 wave field set (tier gauges, no kernel-path keys).
 WAVE_FIELDS_V6: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V8_KEYS + _WAVE_V9_KEYS + _WAVE_V10_KEYS
-    + _WAVE_V12_KEYS + _WAVE_V13_KEYS}
+    + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS}
 
 #: The v8 wave field set (kernel-path keys, no mux attribution).
 WAVE_FIELDS_V8: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
-    + _WAVE_V13_KEYS}
+    + _WAVE_V13_KEYS + _WAVE_V15_KEYS}
 
 #: The v9 wave field set (mux attribution, no async-I/O gauge).
 WAVE_FIELDS_V9: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS}
+    if k not in _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
+    + _WAVE_V15_KEYS}
 
 #: The v10-v11 wave field set (async-I/O gauge, no expand_impl).
 WAVE_FIELDS_V11: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V12_KEYS + _WAVE_V13_KEYS}
+    if k not in _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS}
 
 #: The v12 wave field set (expand_impl, no cost attribution).
 WAVE_FIELDS_V12: Dict[str, tuple] = {
-    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V13_KEYS}
+    k: v for k, v in WAVE_FIELDS.items()
+    if k not in _WAVE_V13_KEYS + _WAVE_V15_KEYS}
+
+#: The v13-v14 wave field set (cost attribution, no loop rounds).
+WAVE_FIELDS_V14: Dict[str, tuple] = {
+    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V15_KEYS}
 
 _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            3: WAVE_FIELDS_V2, 4: WAVE_FIELDS_V2,
@@ -400,7 +426,8 @@ _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            11: WAVE_FIELDS_V11, 12: WAVE_FIELDS_V12,
                            # v14 added event types only; its wave
                            # field set matches v13.
-                           13: WAVE_FIELDS, 14: WAVE_FIELDS}
+                           13: WAVE_FIELDS_V14, 14: WAVE_FIELDS_V14,
+                           15: WAVE_FIELDS}
 
 #: Required fields per trace event type (beyond the stamped
 #: schema_version/engine/run/t, which every event carries).

@@ -31,6 +31,12 @@ Spans nest per thread (``depth`` is a thread-local counter) and record
 monotonic start + duration; counters accumulate per tracer and dump
 their totals in the ``run_end`` event, so a consumer can read final
 tallies without folding the stream.
+
+Every span, on either tracer, is also a ``jax.profiler.TraceAnnotation``
+of the same name: inside a profiler session it lands on the host plane
+of the device trace, on the device ops' clock; outside one it records
+nothing. ``span`` is therefore the one tracer method the host loops call
+without an ``enabled`` guard (at dispatch rate, never per wave).
 """
 
 from __future__ import annotations
@@ -51,10 +57,11 @@ _RUN_SEQ = itertools.count()
 
 
 class NullTracer:
-    """The disabled tracer: every method is a no-op, ``enabled`` is
-    False. Hot paths must check ``enabled`` BEFORE building event
-    payloads — the null methods exist only so cold paths (close, span
-    around a growth rehash) need no guard."""
+    """The disabled tracer: every method but ``span`` is a no-op,
+    ``enabled`` is False. Hot paths must check ``enabled`` BEFORE
+    building event payloads — the null methods exist only so cold paths
+    (close) need no guard. ``span`` still opens the profiler
+    annotation."""
 
     __slots__ = ()
     enabled = False
@@ -79,7 +86,8 @@ class NullTracer:
 
     @contextmanager
     def span(self, name, **attrs):
-        yield
+        with _annotation(name):
+            yield
 
     def close(self) -> None:
         pass
@@ -194,7 +202,10 @@ class RunTracer:
                     # v13 cost attribution: null when the profiler is
                     # disarmed / the program has no cost model /
                     # the dispatch was not sampled.
-                    "cost_flops", "cost_bytes", "cost_ratio"):
+                    "cost_flops", "cost_bytes", "cost_ratio",
+                    # v15 loop rounds and host seconds: null where
+                    # not counted.
+                    "probe_rounds", "dedup_rounds", "host_s"):
             evt.setdefault(key, None)
         self._write(evt, number_wave=True)
 
@@ -226,7 +237,7 @@ class RunTracer:
 
     def span_event(self, name: str, start: float, dur: float,
                    depth: int = 0, **attrs) -> None:
-        """A pre-measured span (profiling.py times its stages itself)."""
+        """A pre-measured span."""
         evt = {"type": "span", "name": name, "t": start,
                "dur": round(dur, 6), "depth": depth}
         if attrs:
@@ -236,12 +247,13 @@ class RunTracer:
     @contextmanager
     def span(self, name: str, **attrs):
         """Measures a nested span: monotonic start/end, per-thread
-        depth."""
+        depth; also a profiler annotation."""
         depth = getattr(self._local, "depth", 0)
         self._local.depth = depth + 1
         start = time.monotonic()
         try:
-            yield
+            with _annotation(name):
+                yield
         finally:
             self._local.depth = depth
             self.span_event(name, start, time.monotonic() - start,
@@ -265,6 +277,14 @@ class RunTracer:
         with self._lock:
             self._closed = True
             self._f.close()
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` (imported here, so that the
+    jax-free tools can import ``obs``)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 def _jsonable(obj):

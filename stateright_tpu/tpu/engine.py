@@ -1915,18 +1915,22 @@ def dedup_impl(table_impl: str, capacity: int):
     growth must not kill a checker.
 
     The returned function runs BOTH dedup levels —
-    ``fn(fps, visited) -> (new_mask, new_count, cand_count, merged)``:
+    ``fn(fps, visited) -> (new_mask, new_count, cand_count, merged,
+    rounds)``:
     the intra-wave local collapse (``first_occurrence_candidates``)
     first, then the global probe (``global_insert``) over the distinct
     survivors only, with ``cand_count`` (how many candidates reached
-    the global probe) surfaced for the collapse-ratio telemetry."""
+    the global probe) surfaced for the collapse-ratio telemetry, and
+    ``rounds``, the int32 scalars ``(local dedup rounds, probe rounds)``
+    of the two XLA loops (the Pallas table counts neither and gives
+    zeros)."""
     if table_impl == "pallas":
         from .pallas_table import (dedup_and_insert_pallas,
                                    pallas_table_capacity_ok)
 
         if pallas_table_capacity_ok(capacity):
             return lambda fps, visited: dedup_and_insert_pallas(
-                fps, visited, capacity)
+                fps, visited, capacity) + ((jnp.int32(0),) * 2,)
         if capacity not in _PALLAS_DEGRADE_WARNED:
             _PALLAS_DEGRADE_WARNED.add(capacity)
             warnings.warn(
@@ -1935,11 +1939,12 @@ def dedup_impl(table_impl: str, capacity: int):
                 "the XLA table", RuntimeWarning)
 
     def xla(fps, visited):
-        candidate = first_occurrence_candidates(fps)
+        candidate, local_rounds = first_occurrence_counted(fps)
         cand_count = jnp.sum(candidate, dtype=jnp.int32)
-        new_mask, new_count, merged = global_insert(
+        new_mask, new_count, merged, probe_rounds = global_insert_counted(
             fps, candidate, visited, capacity)
-        return new_mask, new_count, cand_count, merged
+        return (new_mask, new_count, cand_count, merged,
+                (local_rounds, probe_rounds))
 
     return xla
 
@@ -2106,8 +2111,8 @@ def build_wave(dm: DeviceModel, batch_size: int, capacity: int,
                 else expand_frontier(dm, reg, valid))
             dedup_fps, path_fps = fingerprint_successors(
                 dm, succ_flat, sflat, use_sym)
-            new_mask, new_count, cand_count, merged = dedup(dedup_fps,
-                                                            visited)
+            new_mask, new_count, cand_count, merged, _ = dedup(
+                dedup_fps, visited)
             # Compact new successors to the front, preserving (frontier
             # row, action) order — the host enqueue order of bfs.rs:262
             # — and gather only the ladder's K rows (packing AFTER the
@@ -2262,13 +2267,22 @@ def build_regather(dm: DeviceModel, batch_size: int, out_rows: int,
 
 
 # -- Wave building blocks (shared with the sharded engine) ----------------
+#
+# Each stage runs under a ``jax.named_scope`` (``properties``, ``expand``,
+# ``fingerprint``, ``local_dedup``, ``probe``); the fused wave body adds
+# ``load`` and ``store``. The names land in every compiled program's op
+# metadata, so a device trace attributes each op to its stage
+# (``benchmark/trace_stages.py``); with no profiler running they cost
+# nothing.
 
+@jax.named_scope("properties")
 def eval_properties(prop_fns, vecs):
     """Property predicates at "pop time" (bfs.rs:192-226); ``None`` slots
     are host-fallback properties."""
     return [None if fn is None else jax.vmap(fn)(vecs) for fn in prop_fns]
 
 
+@jax.named_scope("expand")
 def expand_frontier(dm: DeviceModel, vecs, valid):
     """Successor generation with boundary pruning (bfs.rs:231-244).
 
@@ -2288,6 +2302,7 @@ def expand_frontier(dm: DeviceModel, vecs, valid):
     return succ.reshape(s, dm.state_width), sv.reshape(s), succ_count, terminal
 
 
+@jax.named_scope("fingerprint")
 def fingerprint_successors(dm: DeviceModel, succ_flat, valid_flat,
                            use_sym: bool):
     """``(dedup_fps, path_fps)``: under symmetry, dedup by the
@@ -2383,6 +2398,13 @@ def first_occurrence_candidates(dedup_fps):
     one group. Replaced a stable u64 argsort that was ~70% of the
     dedup stage on the XLA CPU backend (22k-row waves: 5.9 of 8.4 ms).
     """
+    return first_occurrence_counted(dedup_fps)[0]
+
+
+@jax.named_scope("local_dedup")
+def first_occurrence_counted(dedup_fps):
+    """``first_occurrence_candidates`` plus its loop's round count
+    (int32): ``(first, rounds)``."""
     n = dedup_fps.shape[0]
     m = 1 << max(int(n - 1).bit_length() + 1, 4)  # >= 2n, power of two
     shift = jnp.uint64(64 - (m.bit_length() - 1))
@@ -2393,11 +2415,11 @@ def first_occurrence_candidates(dedup_fps):
     pending0 = dedup_fps != jnp.uint64(SENTINEL)
 
     def cond(carry):
-        _, pending, _ = carry
+        _, pending, _, _ = carry
         return pending.any()
 
     def body(carry):
-        h, pending, first = carry
+        h, pending, first, rounds = carry
         scratch = jnp.full((m,), n, jnp.int32).at[
             jnp.where(pending, h, m)].min(rows, mode="drop")
         winner_row = scratch[h]
@@ -2406,11 +2428,11 @@ def first_occurrence_candidates(dedup_fps):
         first = first | (same & (winner_row == rows))
         pending = pending & ~same
         h = jnp.where(pending, (h + step) & (m - 1), h)
-        return h, pending, first
+        return h, pending, first, rounds + 1
 
-    _, _, first = jax.lax.while_loop(
-        cond, body, (h0, pending0, jnp.zeros((n,), bool)))
-    return first
+    _, _, first, rounds = jax.lax.while_loop(
+        cond, body, (h0, pending0, jnp.zeros((n,), bool), jnp.int32(0)))
+    return first, rounds
 
 
 def dedup_and_insert(dedup_fps, visited, capacity: int):
@@ -2440,6 +2462,15 @@ def global_insert(dedup_fps, candidate, visited, capacity: int):
     with load factor <= 1/2 (guaranteed by ``_grow_table``) probe
     chains are O(1) expected, so the per-wave cost never depends on
     table occupancy."""
+    new_mask, new_count, visited, _ = global_insert_counted(
+        dedup_fps, candidate, visited, capacity)
+    return new_mask, new_count, visited
+
+
+@jax.named_scope("probe")
+def global_insert_counted(dedup_fps, candidate, visited, capacity: int):
+    """``global_insert`` plus its loop's round count (int32):
+    ``(new_mask, new_count, visited, rounds)``."""
     sentinel = jnp.uint64(SENTINEL)
 
     shift = jnp.uint64(64 - (capacity.bit_length() - 1))
@@ -2449,11 +2480,11 @@ def global_insert(dedup_fps, candidate, visited, capacity: int):
             .astype(jnp.int32) | 1)  # odd: tours the power-of-two table
 
     def cond(carry):
-        _, _, pending, _ = carry
+        _, _, pending, _, _ = carry
         return pending.any()
 
     def body(carry):
-        table, idx, pending, is_new = carry
+        table, idx, pending, is_new, rounds = carry
         cur = table[idx]
         found = pending & (cur == dedup_fps)
         empty = pending & (cur == sentinel)
@@ -2466,9 +2497,10 @@ def global_insert(dedup_fps, candidate, visited, capacity: int):
         is_new = is_new | won
         pending = pending & ~(found | won)
         idx = jnp.where(pending, (idx + step) & slot_mask, idx)
-        return table, idx, pending, is_new
+        return table, idx, pending, is_new, rounds + 1
 
-    visited, _, _, new_mask = jax.lax.while_loop(
+    visited, _, _, new_mask, rounds = jax.lax.while_loop(
         cond, body,
-        (visited, idx0, candidate, jnp.zeros(dedup_fps.shape, bool)))
-    return new_mask, jnp.sum(new_mask, dtype=jnp.int32), visited
+        (visited, idx0, candidate, jnp.zeros(dedup_fps.shape, bool),
+         jnp.int32(0)))
+    return new_mask, jnp.sum(new_mask, dtype=jnp.int32), visited, rounds

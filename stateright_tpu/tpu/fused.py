@@ -61,12 +61,14 @@ __all__ = ["FusedTpuBfsChecker", "FusedUnsupported"]
 # (possibly one or more launches later). ``WAVES`` is reset per
 # dispatch; ``TARGET`` rides along unchanged; ``CAND`` accumulates the
 # distinct candidates that reached the global probe (the local-dedup
-# collapse telemetry); discovery fingerprints are bitcast into the tail
-# slots (they also travel as a separate donated array between
-# dispatches).
+# collapse telemetry); ``PROBE_ROUNDS``/``DEDUP_ROUNDS`` are the rounds
+# the dispatch's table-probe and local-dedup loops ran, summed over its
+# waves (per dispatch, like ``WAVES``); discovery fingerprints are
+# bitcast into the tail slots (they also travel as a separate donated
+# array between dispatches).
 (ST_HEAD, ST_TAIL, ST_OCC, ST_SUCC, ST_CAND, ST_TARGET, ST_ERR,
- ST_WAVES) = range(8)
-ST_DISC = 8
+ ST_WAVES, ST_PROBE_ROUNDS, ST_DEDUP_ROUNDS) = range(10)
+ST_DISC = 10
 
 
 class FusedUnsupported(TypeError):
@@ -223,18 +225,19 @@ class FusedTpuBfsChecker(TpuBfsChecker):
 
         def wave(carry):
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
-             succ_total, cand_total, err, disc, waves) = carry
-            idx = head + jnp.arange(B, dtype=jnp.int64)
-            valid = idx < tail
-            idx_c = jnp.minimum(idx, ucap - 1)
-            # The arena stores PACKED rows; unpack the batch to real
-            # lanes at wave start (compute is layout-independent).
-            bstore = vecs_a[idx_c]
-            bvecs = bstore
-            if layout is not None:
-                bvecs = layout.unpack(bstore)
-            bfps = fps_a[idx_c]
-            bebits = eb_a[idx_c]
+             succ_total, cand_total, err, disc, waves, rounds) = carry
+            with jax.named_scope("load"):
+                idx = head + jnp.arange(B, dtype=jnp.int64)
+                valid = idx < tail
+                idx_c = jnp.minimum(idx, ucap - 1)
+                # The arena stores PACKED rows; unpack the batch to real
+                # lanes at wave start (compute is layout-independent).
+                bstore = vecs_a[idx_c]
+                bvecs = bstore
+                if layout is not None:
+                    bvecs = layout.unpack(bstore)
+                bfps = fps_a[idx_c]
+                bebits = eb_a[idx_c]
 
             conds = eval_properties(prop_fns, bvecs)
             for i, prop in enumerate(properties):
@@ -257,6 +260,7 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                 terminal = valid & ~sflat.reshape(B, F).any(axis=1)
                 new_count = jnp.sum(new_mask, dtype=jnp.int32)
                 cand_count = jnp.sum(cand_mask, dtype=jnp.int32)
+                wave_rounds = (jnp.int32(0),) * 2  # not counted
             else:
                 succ_flat, sflat, succ_count, terminal = (
                     matmul_expand(dm, self._matmul_plan, bvecs, valid)
@@ -264,9 +268,8 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     else expand_frontier(dm, bvecs, valid))
                 dedup_fps, path_fps = fingerprint_successors(
                     dm, succ_flat, sflat, use_sym)
-                new_mask, new_count, cand_count, visited = dedup(
-                    dedup_fps, visited)
-            comp = compaction_order(new_mask)
+                new_mask, new_count, cand_count, visited, wave_rounds = (
+                    dedup(dedup_fps, visited))
 
             # Eventually bits: clear satisfied at the parent, then flag
             # terminal parents with leftover bits (bfs.rs:212-226,265-272).
@@ -289,41 +292,45 @@ class FusedTpuBfsChecker(TpuBfsChecker):
             # breaks XLA's in-place aliasing of the donated arena and
             # forces whole-arena copies per wave (measured ~2x wall on
             # the CPU backend), which dwarfs the bytes saved.
-            parent_rows = comp // F
-            # Megakernel rows arrive already packed for storage; the
-            # ladder packs after the gather as before.
-            new_vecs = (succ_store[comp] if mega is not None
-                        else succ_flat[comp])
-            new_fps = path_fps[comp]
-            new_parent = bfps[parent_rows]
-            new_ebits = cleared[parent_rows]
-            if err_lane is not None:
-                # On packed rows, extract just the error lane (the
-                # sharded-fused precedent); unpacked rows index it.
-                err_col = (layout.lane(new_vecs, err_lane)
-                           if mega is not None and layout is not None
-                           else new_vecs[:, err_lane])
-                err = err | jnp.any((err_col != 0)
-                                    & (jnp.arange(S) < new_count))
-            if mega is None and layout is not None:
-                new_vecs = layout.pack(new_vecs)
-            start = (tail,)
-            vecs_a = jax.lax.dynamic_update_slice(vecs_a, new_vecs,
-                                                  (tail, jnp.int64(0)))
-            fps_a = jax.lax.dynamic_update_slice(fps_a, new_fps, start)
-            par_a = jax.lax.dynamic_update_slice(par_a, new_parent, start)
-            eb_a = jax.lax.dynamic_update_slice(eb_a, new_ebits, start)
+            with jax.named_scope("store"):
+                comp = compaction_order(new_mask)
+                parent_rows = comp // F
+                # Megakernel rows arrive already packed for storage; the
+                # ladder packs after the gather as before.
+                new_vecs = (succ_store[comp] if mega is not None
+                            else succ_flat[comp])
+                new_fps = path_fps[comp]
+                new_parent = bfps[parent_rows]
+                new_ebits = cleared[parent_rows]
+                if err_lane is not None:
+                    # On packed rows, extract just the error lane (the
+                    # sharded-fused precedent); unpacked rows index it.
+                    err_col = (layout.lane(new_vecs, err_lane)
+                               if mega is not None and layout is not None
+                               else new_vecs[:, err_lane])
+                    err = err | jnp.any((err_col != 0)
+                                        & (jnp.arange(S) < new_count))
+                if mega is None and layout is not None:
+                    new_vecs = layout.pack(new_vecs)
+                start = (tail,)
+                vecs_a = jax.lax.dynamic_update_slice(
+                    vecs_a, new_vecs, (tail, jnp.int64(0)))
+                fps_a = jax.lax.dynamic_update_slice(fps_a, new_fps, start)
+                par_a = jax.lax.dynamic_update_slice(par_a, new_parent,
+                                                     start)
+                eb_a = jax.lax.dynamic_update_slice(eb_a, new_ebits, start)
 
             nc = new_count.astype(jnp.int64)
             return (vecs_a, fps_a, par_a, eb_a, visited,
                     jnp.minimum(head + B, tail), tail + nc, occ + nc,
                     succ_total + succ_count,
                     cand_total + cand_count.astype(jnp.int64), err, disc,
-                    waves + 1)
+                    waves + 1,
+                    tuple(r + w for r, w in zip(rounds, wave_rounds)))
 
         def cond(carry):
             (_, _, _, _, _, head, tail, occ, succ_total, _cand, err,
-             disc, waves, target) = carry
+             disc, waves, _rounds, target) = carry
             more = (waves < K) & (head < tail) & ~err
             more = more & (tail + S <= ucap)
             more = more & (occ + S <= capacity // 2)
@@ -344,15 +351,18 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                                       ST_SUCC, ST_CAND, ST_TARGET))
             carry = (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
                      succ_total, cand_total, stats_in[ST_ERR] != 0, disc,
-                     jnp.zeros((), jnp.int64), target)
+                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2, target)
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
-             succ_total, cand_total, err, disc, waves,
+             succ_total, cand_total, err, disc, waves, rounds,
              _) = jax.lax.while_loop(cond, wave_t, carry)
             # Discovery slots ride in the stats vector (bitcast, so the
             # SENTINEL survives) — one host fetch per dispatch, not two.
+            local_rounds, probe_rounds = (r.astype(jnp.int64)
+                                          for r in rounds)
             stats = jnp.concatenate([
                 jnp.stack([head, tail, occ, succ_total, cand_total,
-                           target, err.astype(jnp.int64), waves]),
+                           target, err.astype(jnp.int64), waves,
+                           probe_rounds, local_rounds]),
                 jax.lax.bitcast_convert_type(disc, jnp.int64)])
             return vecs_a, fps_a, par_a, eb_a, visited, disc, stats
 
@@ -541,12 +551,17 @@ class FusedTpuBfsChecker(TpuBfsChecker):
         stats_dev = jnp.asarray(stats_np)
 
         from collections import deque
-        inflight: deque = deque()  # (stats_dev, meta), oldest first
+        # (stats_dev, meta, launch seconds), oldest first
+        inflight: deque = deque()
 
         def process(entry) -> None:
             """Materializes one dispatch's stats (the only blocking
             read) and applies them; absolute values make processing a
             no-op dispatch harmless."""
+            with self._tracer.span("fused.process"):
+                apply(entry, time.monotonic())
+
+        def apply(entry, t_proc: float) -> None:
             nonlocal head, tail, occ, succ_total, cand_seen
             if self._faults.active:
                 # Before any count/arena bookkeeping: the dispatch's
@@ -555,8 +570,13 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                 # checkpoint resume repairs it.
                 self._faults.crash("wave_crash", self._tracer,
                                    wave=len(self.dispatch_log))
-            stats_out, meta = entry
-            stats_h = np.asarray(stats_out)
+            stats_out, meta, launch_s = entry
+            t_wait = time.monotonic()
+            with self._tracer.span("fused.stats_wait"):
+                stats_h = np.asarray(stats_out)
+            waited = time.monotonic() - t_wait
+            # The loops' rounds are counted on the XLA path only.
+            counted = meta["kernel_path"].startswith("xla")
             succ_prev = succ_total
             head_prev = head
             head, tail, occ, succ_total = (
@@ -589,6 +609,14 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     compiled=self._take_compile(),
                     successors=succ_total - succ_prev,
                     candidates=cand_total - cand_prev, novel=novel,
+                    # v15: the table-probe and local-dedup loops'
+                    # rounds, and the host's own time on this dispatch
+                    # (its launch and processing, less the stats wait).
+                    probe_rounds=(int(stats_h[ST_PROBE_ROUNDS])
+                                  if counted else None),
+                    dedup_rounds=(int(stats_h[ST_DEDUP_ROUNDS])
+                                  if counted else None),
+                    host_s=launch_s + (now - t_proc) - waited,
                     # Frontier rows this dispatch consumed (the head
                     # advance) — the kernel-occupancy numerator.
                     rows=head - head_prev,
@@ -672,102 +700,104 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                 process(inflight.popleft())
                 continue
             if growth:
-                # Growth at rest, before the table/arena can fill.
-                # The jitted programs chain on the device queue; the
-                # old buffers are donated + released (_releasing). An
-                # allocation failure (real or the injected grow_oom
-                # fault) sheds the top batch bucket instead of killing
-                # the run — the loop top re-derives the bucket and the
-                # headroom requirement from the shrunken ladder, so a
-                # narrower dispatch may no longer need the growth at
-                # all (OOM graceful degradation).
-                try:
-                    self._grow_requested = (
-                        self._capacity * 2 if occ + S_b
-                        > self._capacity // 2 else self._capacity)
-                    if self._faults.active:
-                        self._faults.crash("grow_oom", self._tracer)
-                    while occ + S_b > self._capacity // 2:
-                        new_cap = self._capacity * 2
-                        if self._tracer.enabled:
-                            self._tracer.event(
-                                "grow", kind="table",
-                                old=self._capacity, new=new_cap)
-                        visited = self._rehash_fn(self._capacity,
-                                                  new_cap)(visited)
-                        self._capacity = new_cap
-                        self._visited = visited
-                    while tail + S_b > ucap:
-                        budget = self._store.device_budget \
-                            if self._store.active else None
-                        over = (budget is not None
-                                and 2 * ucap * self._arena_row_bytes()
-                                + self._capacity * 8 > budget)
-                        if over and head > 0:
-                            # Arena-span spill (tiered store): the
-                            # expanded prefix [0, head) is only ever
-                            # read by the parent-log sync, so sync it
-                            # to the host and shift the live window
-                            # down — headroom without growing past the
-                            # device budget. Bit-identical: the wave
-                            # reads the same [head, tail) rows in the
-                            # same order, just at a new base.
-                            self._fetch_parents(head)
-                            shift = head
-                            sh = jnp.int64(shift)
-                            vecs_a = self._roll_fn(
-                                ucap, jnp.uint32, W)(vecs_a, sh)
-                            fps_a = self._roll_fn(
-                                ucap, jnp.uint64)(fps_a, sh)
-                            par_a = self._roll_fn(
-                                ucap, jnp.uint64)(par_a, sh)
-                            eb_a = self._roll_fn(
-                                ucap, jnp.uint32)(eb_a, sh)
+                with self._tracer.span("fused.grow"):
+                    # Growth at rest, before the table/arena can fill.
+                    # The jitted programs chain on the device queue; the
+                    # old buffers are donated + released (_releasing). An
+                    # allocation failure (real or the injected grow_oom
+                    # fault) sheds the top batch bucket instead of killing
+                    # the run — the loop top re-derives the bucket and the
+                    # headroom requirement from the shrunken ladder, so a
+                    # narrower dispatch may no longer need the growth at
+                    # all (OOM graceful degradation).
+                    try:
+                        self._grow_requested = (
+                            self._capacity * 2 if occ + S_b
+                            > self._capacity // 2 else self._capacity)
+                        if self._faults.active:
+                            self._faults.crash("grow_oom", self._tracer)
+                        while occ + S_b > self._capacity // 2:
+                            new_cap = self._capacity * 2
+                            if self._tracer.enabled:
+                                self._tracer.event(
+                                    "grow", kind="table",
+                                    old=self._capacity, new=new_cap)
+                            visited = self._rehash_fn(self._capacity,
+                                                      new_cap)(visited)
+                            self._capacity = new_cap
+                            self._visited = visited
+                        while tail + S_b > ucap:
+                            budget = self._store.device_budget \
+                                if self._store.active else None
+                            over = (budget is not None
+                                    and 2 * ucap * self._arena_row_bytes()
+                                    + self._capacity * 8 > budget)
+                            if over and head > 0:
+                                # Arena-span spill (tiered store): the
+                                # expanded prefix [0, head) is only ever
+                                # read by the parent-log sync, so sync it
+                                # to the host and shift the live window
+                                # down — headroom without growing past the
+                                # device budget. Bit-identical: the wave
+                                # reads the same [head, tail) rows in the
+                                # same order, just at a new base.
+                                self._fetch_parents(head)
+                                shift = head
+                                sh = jnp.int64(shift)
+                                vecs_a = self._roll_fn(
+                                    ucap, jnp.uint32, W)(vecs_a, sh)
+                                fps_a = self._roll_fn(
+                                    ucap, jnp.uint64)(fps_a, sh)
+                                par_a = self._roll_fn(
+                                    ucap, jnp.uint64)(par_a, sh)
+                                eb_a = self._roll_fn(
+                                    ucap, jnp.uint32)(eb_a, sh)
+                                self._arena = (vecs_a, fps_a, par_a, eb_a)
+                                head, tail = 0, tail - shift
+                                with self._lock:
+                                    self._head, self._arena_tail = head, tail
+                                    self._synced_rows -= shift
+                                self._store.note_arena_span(
+                                    shift, shift * self._arena_row_bytes())
+                                # The chained stats carry the OLD window;
+                                # rebuild them at rest (discovery slots are
+                                # outputs only — the dispatch takes disc
+                                # separately).
+                                st = np.zeros(L, np.int64)
+                                st[ST_HEAD], st[ST_TAIL] = head, tail
+                                st[ST_OCC], st[ST_SUCC] = occ, succ_total
+                                st[ST_CAND] = cand_seen
+                                st[ST_TARGET] = target_eff
+                                stats_dev = jnp.asarray(st)
+                                continue
+                            if over and self._store.active:
+                                # Nothing left to shift: the device tier
+                                # must exceed its budget — recorded, not
+                                # fatal.
+                                self._store.note_device_pressure(
+                                    2 * ucap * self._arena_row_bytes()
+                                    + self._capacity * 8, budget)
+                            new_ucap = ucap * 2
+                            if self._tracer.enabled:
+                                self._tracer.event("grow", kind="arena",
+                                                   old=ucap, new=new_ucap)
+                            vecs_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint32, W)(vecs_a)
+                            fps_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint64)(fps_a)
+                            par_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint64)(par_a)
+                            eb_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint32)(eb_a)
+                            ucap = new_ucap
+                            self._slice_cache.clear()
                             self._arena = (vecs_a, fps_a, par_a, eb_a)
-                            head, tail = 0, tail - shift
-                            with self._lock:
-                                self._head, self._arena_tail = head, tail
-                                self._synced_rows -= shift
-                            self._store.note_arena_span(
-                                shift, shift * self._arena_row_bytes())
-                            # The chained stats carry the OLD window;
-                            # rebuild them at rest (discovery slots are
-                            # outputs only — the dispatch takes disc
-                            # separately).
-                            st = np.zeros(L, np.int64)
-                            st[ST_HEAD], st[ST_TAIL] = head, tail
-                            st[ST_OCC], st[ST_SUCC] = occ, succ_total
-                            st[ST_CAND] = cand_seen
-                            st[ST_TARGET] = target_eff
-                            stats_dev = jnp.asarray(st)
-                            continue
-                        if over and self._store.active:
-                            # Nothing left to shift: the device tier
-                            # must exceed its budget — recorded, not
-                            # fatal.
-                            self._store.note_device_pressure(
-                                2 * ucap * self._arena_row_bytes()
-                                + self._capacity * 8, budget)
-                        new_ucap = ucap * 2
-                        if self._tracer.enabled:
-                            self._tracer.event("grow", kind="arena",
-                                               old=ucap, new=new_ucap)
-                        vecs_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint32, W)(vecs_a)
-                        fps_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint64)(fps_a)
-                        par_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint64)(par_a)
-                        eb_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint32)(eb_a)
-                        ucap = new_ucap
-                        self._slice_cache.clear()
-                        self._arena = (vecs_a, fps_a, par_a, eb_a)
-                except Exception as e:  # noqa: BLE001 — non-OOM re-raised
-                    self._handle_grow_failure(e)
+                    except Exception as e:  # noqa: BLE001 — non-OOM raised
+                        self._handle_grow_failure(e)
                 continue
             if ckpt_due:
-                self._write_checkpoint(self._ckpt_path)
+                with self._tracer.span("fused.checkpoint"):
+                    self._write_checkpoint(self._ckpt_path)
                 last_ckpt_states = self._unique_count
                 continue
 
@@ -777,10 +807,13 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     ("dispatch", bucket, self._capacity, ucap, self._K))
                 if self._prof.should_sample(pkey):
                     t0 = time.monotonic()
-            (vecs_a, fps_a, par_a, eb_a, visited, disc,
-             stats_dev) = self._dispatch_fn(
-                bucket, self._capacity, ucap)(
-                vecs_a, fps_a, par_a, eb_a, visited, disc, stats_dev)
+            t_launch = time.monotonic()
+            with self._tracer.span("fused.launch"):
+                (vecs_a, fps_a, par_a, eb_a, visited, disc,
+                 stats_dev) = self._dispatch_fn(
+                    bucket, self._capacity, ucap)(
+                    vecs_a, fps_a, par_a, eb_a, visited, disc, stats_dev)
+            launch_s = time.monotonic() - t_launch
             if t0 is not None:
                 # Rest-point timing (obs/prof.py): draining the
                 # multi-dispatch pipeline for this one sample is the
@@ -800,7 +833,7 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                 meta["_prof_key"] = pkey
                 if prof_s is not None:
                     meta["_prof_s"] = prof_s
-            inflight.append((stats_dev, meta))
+            inflight.append((stats_dev, meta, launch_s))
             if len(inflight) >= self._depth:
                 process(inflight.popleft())
         # Retire every launched dispatch (normal exit): their table
@@ -832,9 +865,10 @@ class FusedTpuBfsChecker(TpuBfsChecker):
         a waiter must wake even when there was nothing new to fetch."""
         lo = self._synced_rows
         if tail > lo:
-            _, fps_a, par_a, _ = self._arena
-            child = self._fetch_rows(fps_a, lo, tail - lo)
-            parent = self._fetch_rows(par_a, lo, tail - lo)
+            with self._tracer.span("fused.parent_sync"):
+                _, fps_a, par_a, _ = self._arena
+                child = self._fetch_rows(fps_a, lo, tail - lo)
+                parent = self._fetch_rows(par_a, lo, tail - lo)
             with self._lock:
                 self._parent_log.append((child, parent))
                 self._synced_rows = tail

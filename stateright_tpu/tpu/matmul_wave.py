@@ -477,6 +477,7 @@ def _classify(dm) -> MatmulClassification:
         f"{plan.matmul_ops} macs/row)", plan)
 
 
+@jax.named_scope("expand")
 def matmul_expand(dm, plan: MatmulPlan, vecs, valid, tables=None):
     """Drop-in replacement for ``engine.expand_frontier`` on a regular
     model: same signature, same returns (``succ_flat [B*F, W]``,

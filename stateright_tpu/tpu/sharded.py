@@ -365,7 +365,7 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
             # insert against this shard's table slice, then the ladder's
             # K-row compaction; the full novelty mask and the overflow
             # flag ship so a truncated wave regathers losslessly.
-            new_mask, new_count, cand_count, merged = dedup(
+            new_mask, new_count, cand_count, merged, _ = dedup(
                 recv_dedup, visited)
             comp = compaction_order(new_mask)[:K]
             new_vecs = recv_vecs[comp]

@@ -54,8 +54,8 @@ from .engine import (compaction_order, dedup_and_insert, dedup_impl,
                      host_table_insert, matmul_expand, pick_bucket,
                      sender_kernel_impl)
 from .fused import (FusedTpuBfsChecker, ST_CAND, ST_DISC, ST_ERR, ST_HEAD,
-                    ST_OCC, ST_SUCC, ST_TAIL, ST_TARGET, ST_WAVES, _pow2,
-                    _releasing)
+                    ST_OCC, ST_PROBE_ROUNDS, ST_SUCC, ST_TAIL, ST_TARGET,
+                    ST_WAVES, _pow2, _releasing)
 from .hashing import SENTINEL
 
 __all__ = ["ShardedFusedTpuBfsChecker"]
@@ -311,7 +311,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             recv_ebits = a2a(scatter(child_ebits, 0).reshape(
                 n, CAP)).reshape(R)
 
-            new_mask, new_count, cand_count, visited = dedup(
+            new_mask, new_count, cand_count, visited, _ = dedup(
                 recv_dedup, visited)
             comp = compaction_order(new_mask)
 
@@ -383,9 +383,12 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
              _) = jax.lax.while_loop(cond, wave, carry)
             # Discovery slots (replicated) ride in each shard's stats row
             # so the host reads one packed array per dispatch.
+            # The loop-round slots stay 0: this engine does not count
+            # them.
             stats = jnp.concatenate([
                 jnp.stack([head, tail, occ, succ_total, cand_total,
                            target, err.astype(jnp.int64), waves]),
+                jnp.zeros(ST_DISC - ST_PROBE_ROUNDS, jnp.int64),
                 jax.lax.bitcast_convert_type(disc, jnp.int64)])[None]
             return vecs_a, fps_a, par_a, eb_a, visited, disc, stats
 

@@ -327,7 +327,11 @@ def _worker_wave(worker, seq, run="rw", **kw):
                    # v13 profiler cost fields (null when the program's
                    # cost model was never captured).
                    "cost_flops": None, "cost_bytes": None,
-                   "cost_ratio": None})
+                   "cost_ratio": None,
+                   # v15 loop rounds and host seconds (null where not
+                   # counted).
+                   "probe_rounds": None, "dedup_rounds": None,
+                   "host_s": None})
     fields.update(kw)
     return json.dumps(fields)
 
@@ -362,7 +366,8 @@ def test_lint_elastic_wave_requires_attribution():
                 "tier_disk_rows", "tier_disk_bytes",
                 "kernel_path", "rows", "job_id", "jobs_in_wave",
                 "io_stall_s", "expand_impl",
-                "cost_flops", "cost_bytes", "cost_ratio"):
+                "cost_flops", "cost_bytes", "cost_ratio",
+                "probe_rounds", "dedup_rounds", "host_s"):
         old.pop(key, None)
     _, errors = trace_lint.lint_lines([json.dumps(old)])
     assert not errors, errors

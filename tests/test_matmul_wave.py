@@ -385,13 +385,3 @@ def test_service_allowlists_wave_matmul_knob():
     from stateright_tpu.service.jobs import _KNOBS
 
     assert _KNOBS.get("wave_matmul") is bool
-
-
-def test_profiling_times_matmul_expand_for_regular_model():
-    """The first-class profiling stage: nonzero on a regular model
-    (the irregular-model zero is pinned in test_profiling.py)."""
-    from stateright_tpu.tpu.profiling import measure_wave_breakdown
-
-    out = measure_wave_breakdown(TwoPhaseSys(3), batch_size=64,
-                                 max_waves=3, table_capacity=1 << 14)
-    assert out["stages_sec"]["matmul_expand"] > 0

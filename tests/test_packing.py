@@ -447,20 +447,3 @@ def test_schema_v1_wave_still_validates_and_v3_rejected():
     newer = dict(v1_wave, schema_version=SCHEMA_VERSION + 1)
     errs = validate_event(newer)
     assert len(errs) == 1 and "newer than this validator" in errs[0]
-
-
-def test_profiling_breakdown_stages_pack_codec():
-    """The staged breakdown attributes pack/unpack as first-class
-    stages and the codec stays a small share of the staged wave (the
-    <5%-of-wave-time amortization proof runs on real hardware; on the
-    CPU backend we gate that the stages exist and are sane)."""
-    from stateright_tpu.tpu.profiling import measure_wave_breakdown
-
-    bd = measure_wave_breakdown(TwoPhaseSys(4), batch_size=64,
-                                table_capacity=1 << 14, max_waves=4)
-    assert "unpack" in bd["stages_sec"] and "pack" in bd["stages_sec"]
-    assert bd["waves"] >= 1
-    # The codec must not dominate: well under half the staged total
-    # even on the CPU backend (the real gate is the hardware A/B).
-    codec = bd["stages_share"]["unpack"] + bd["stages_share"]["pack"]
-    assert codec < 0.5, bd["stages_share"]

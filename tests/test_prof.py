@@ -137,9 +137,9 @@ def test_lint_rejects_bad_snapshots(tmp_path, bad, expect):
 def test_v12_wave_capture_still_validates():
     """A pre-profiler capture (schema 12, no cost fields) must keep
     linting clean — and a v13 wave must carry the cost fields."""
-    from stateright_tpu.obs.schema import WAVE_FIELDS, WAVE_FIELDS_V12
+    from stateright_tpu.obs.schema import WAVE_FIELDS_V12, WAVE_FIELDS_V14
 
-    v13 = {k: None for k in WAVE_FIELDS}
+    v13 = {k: None for k in WAVE_FIELDS_V14}  # the v13-v14 field set
     v13.update({"schema_version": 13, "engine": "classic", "run": "r",
                 "type": "wave", "t": 1.0, "wave": 0, "states": 1,
                 "unique": 1, "bucket": 64, "waves": 1, "inflight": 0,
