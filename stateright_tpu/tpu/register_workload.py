@@ -461,15 +461,23 @@ class RegisterWorkloadDevice(ActorDeviceModel):
 
     # -- Server lane helpers ----------------------------------------------
 
+    # Both helpers select over the S static server rows instead of
+    # indexing at a traced offset: under ``vmap`` a dynamic slice or
+    # update becomes a batched gather / scatter, which the TPU compiler
+    # lowers to a serial loop over the successor rows. S <= 7 (the
+    # 3-bit actor field), so the unrolled select stays small.
+
     def gather_server(self, vec, dst):
         """All lanes of the (traced) ``dst`` server: ``uint32[n_lanes]``.
         A client ``dst`` clips to server S-1; callers select the client
         branch away via ``is_server``."""
-        import jax
-
         nsl = len(self.SERVER_LANES)
-        start = jnp.clip(dst, 0, self.S - 1).astype(jnp.int32) * nsl
-        return jax.lax.dynamic_slice(vec, (start,), (nsl,))
+        rows = vec[:self.S * nsl].reshape(self.S, nsl)
+        d = jnp.clip(dst, 0, self.S - 1)
+        lanes = rows[self.S - 1]
+        for i in range(self.S - 1):
+            lanes = jnp.where(d == i, rows[i], lanes)
+        return lanes
 
     def lane(self, lanes, name: str):
         return lanes[self._lane_idx[name]]
@@ -479,12 +487,13 @@ class RegisterWorkloadDevice(ActorDeviceModel):
 
     def scatter_server(self, vec, dst, lanes):
         """Writes a server's lanes back at (traced) index ``dst`` (clipped
-        like :meth:`gather_server`; the caller discards the client case)."""
-        import jax
-
+        like :meth:`gather_server`; the caller discards the client case).
+        ``vec`` is the ``[S * n_lanes]`` server block of a state."""
         nsl = len(self.SERVER_LANES)
-        start = jnp.clip(dst, 0, self.S - 1).astype(jnp.int32) * nsl
-        return jax.lax.dynamic_update_slice(vec, lanes, (start,))
+        d = jnp.clip(dst, 0, self.S - 1)
+        hit = jnp.arange(self.S, dtype=d.dtype)[:, None] == d
+        return jnp.where(hit, lanes[None, :],
+                         vec.reshape(self.S, nsl)).reshape(self.S * nsl)
 
     # -- Subclass surface -------------------------------------------------
 

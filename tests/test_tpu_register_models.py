@@ -109,3 +109,90 @@ def test_abd_device_step_differential(abd):
                 seen.add(fp)
                 queue.append(ns)
     assert len(seen) == 544
+
+
+def _old_gather_server(dm, vec, dst):
+    """The traced-offset slice: the reference the select form must
+    equal."""
+    import jax
+    import jax.numpy as jnp
+
+    nsl = len(dm.SERVER_LANES)
+    start = jnp.clip(dst, 0, dm.S - 1).astype(jnp.int32) * nsl
+    return jax.lax.dynamic_slice(vec, (start,), (nsl,))
+
+
+def _old_scatter_server(dm, vec, dst, lanes):
+    import jax
+    import jax.numpy as jnp
+
+    nsl = len(dm.SERVER_LANES)
+    start = jnp.clip(dst, 0, dm.S - 1).astype(jnp.int32) * nsl
+    return jax.lax.dynamic_update_slice(vec, lanes, (start,))
+
+
+def _register_model(name):
+    if name == "paxos-3":
+        from paxos import PaxosModelCfg
+
+        return PaxosModelCfg(3, 3).into_model()
+    if name == "abd":
+        from linearizable_register import AbdModelCfg
+
+        return AbdModelCfg(2, 2).into_model()
+    from single_copy_register import SingleCopyModelCfg
+
+    return SingleCopyModelCfg(2, 3).into_model()
+
+
+@pytest.mark.parametrize("name", ["paxos-3", "abd", "single-copy"])
+def test_server_select_equals_traced_offset_forms(name, monkeypatch):
+    """The select-over-servers gather/scatter equal the dynamic
+    slice/update forms for every destination, servers and clients (a
+    client clips to server S-1), and the batched step is bit-identical
+    with either pair on reachable states."""
+    from collections import deque
+
+    import jax
+    import jax.numpy as jnp
+
+    from stateright_tpu.fingerprint import fingerprint
+
+    model = _register_model(name)
+    dm = model.device_model()
+    nsl = len(dm.SERVER_LANES)
+    rng = np.random.default_rng(24)
+    for _ in range(4):
+        vec = jnp.asarray(rng.integers(0, 1 << 32, dm.state_width,
+                                       dtype=np.uint64).astype(np.uint32))
+        lanes = jnp.asarray(rng.integers(0, 1 << 32, nsl,
+                                         dtype=np.uint64).astype(np.uint32))
+        servers = vec[:dm.phase_off]
+        for d in range(dm.S + dm.C):
+            dst = jnp.uint32(d)
+            np.testing.assert_array_equal(
+                dm.gather_server(vec, dst), _old_gather_server(dm, vec, dst))
+            np.testing.assert_array_equal(
+                dm.scatter_server(servers, dst, lanes),
+                _old_scatter_server(dm, servers, dst, lanes))
+
+    # A batch of reachable states, breadth first from the initial ones.
+    states, seen, queue = [], set(), deque(model.init_states())
+    while queue and len(states) < 48:
+        s = queue.popleft()
+        fp = fingerprint(s)
+        if fp in seen:
+            continue
+        seen.add(fp)
+        states.append(dm.encode(s))
+        queue.extend(ns for _, ns in model.next_steps(s))
+    batch = jnp.asarray(np.stack(states))
+    succ, valid = jax.jit(jax.vmap(dm.step))(batch)
+    assert bool(valid.any())
+    monkeypatch.setattr(dm, "gather_server",
+                        lambda v, d: _old_gather_server(dm, v, d))
+    monkeypatch.setattr(dm, "scatter_server",
+                        lambda v, d, l: _old_scatter_server(dm, v, d, l))
+    old_succ, old_valid = jax.jit(jax.vmap(dm.step))(batch)
+    np.testing.assert_array_equal(valid, old_valid)
+    np.testing.assert_array_equal(succ, old_succ)
