@@ -200,7 +200,10 @@ class FlightRecorder:
                     "cost_flops", "cost_bytes", "cost_ratio",
                     # v15 loop rounds and host seconds: null where
                     # not counted.
-                    "probe_rounds", "dedup_rounds", "host_s"):
+                    "probe_rounds", "dedup_rounds", "host_s",
+                    # v16 shard-exchange counts: null on producers
+                    # without an exchange.
+                    "exchange_rows", "exchange_slots"):
             out.setdefault(key, None)
         return out
 

@@ -205,7 +205,10 @@ class RunTracer:
                     "cost_flops", "cost_bytes", "cost_ratio",
                     # v15 loop rounds and host seconds: null where
                     # not counted.
-                    "probe_rounds", "dedup_rounds", "host_s"):
+                    "probe_rounds", "dedup_rounds", "host_s",
+                    # v16 shard-exchange counts: null on producers
+                    # without an exchange.
+                    "exchange_rows", "exchange_slots"):
             evt.setdefault(key, None)
         self._write(evt, number_wave=True)
 

@@ -1886,8 +1886,8 @@ class TpuBfsChecker(Checker):
         ``self.preempted`` True. The run is NOT failed: ``join()``
         returns normally and a later run resumes from the checkpoint
         bit-identically. Idempotent; a no-op once the run finished.
-        (The single-process sharded engines don't poll the flag — the
-        job service only schedules onto the classic/fused engines.)"""
+        (The classic sharded engine does not poll the flag; the job
+        service schedules only onto the classic and fused engines.)"""
         self._preempt_evt.set()
 
     def join(self) -> "TpuBfsChecker":
@@ -2405,6 +2405,14 @@ def first_occurrence_candidates(dedup_fps):
 def first_occurrence_counted(dedup_fps):
     """``first_occurrence_candidates`` plus its loop's round count
     (int32): ``(first, rounds)``."""
+    return first_occurrence_unscoped(dedup_fps)
+
+
+def first_occurrence_unscoped(dedup_fps):
+    """``first_occurrence_counted`` outside the ``local_dedup`` scope,
+    for a caller whose scope names the pass: the sharded wave's sender
+    side runs it under ``exchange``, so that each wave has one
+    ``local_dedup`` loop, the owner's."""
     n = dedup_fps.shape[0]
     m = 1 << max(int(n - 1).bit_length() + 1, 4)  # >= 2n, power of two
     shift = jnp.uint64(64 - (m.bit_length() - 1))

@@ -925,6 +925,34 @@ class FusedTpuBfsChecker(TpuBfsChecker):
         with self._sync_cond:
             self._sync_requested = False
 
+    # -- What the check admitted -------------------------------------------
+
+    def arena_rows(self) -> list:
+        """The states a finished or preempted check admitted, as its
+        device arena holds them: one dict per shard (one on a single
+        chip) with ``lanes`` (uint32 ``[rows, W]``, the model's state
+        lanes, unpacked), ``fps`` and ``parents`` (the rows' and their
+        parents' fingerprints; an initial state's parent is
+        ``SENTINEL``), and ``head``: rows ``[0, head)`` were expanded.
+        Rows that an arena-span spill moved off the device are not in
+        it. Call after ``join()``."""
+        if not self._done.is_set():
+            raise RuntimeError("arena_rows() reads a finished check; "
+                               "join() it first")
+        if not hasattr(self, "_arena"):
+            return []
+        vecs_a, fps_a, par_a, _ = self._arena
+        return [{"lanes": self._unpack_np(
+                    self._fetch_rows(vecs_a, base, tail, self._Wrow)),
+                 "fps": self._fetch_rows(fps_a, base, tail),
+                 "parents": self._fetch_rows(par_a, base, tail),
+                 "head": head}
+                for base, head, tail in self._arena_spans()]
+
+    def _arena_spans(self) -> list:
+        """``(first row, head, tail)`` of each shard's arena slice."""
+        return [(0, self._head, self._arena_tail)]
+
     # -- Checkpoint hooks --------------------------------------------------
 
     def _pending_blocks(self) -> list:

@@ -30,6 +30,14 @@ shard* and runs up to ``waves_per_dispatch`` waves per dispatch:
 
 Host-per-dispatch traffic is one packed per-shard stats array; parent
 rows are fetched lazily, as in the single-chip fused engine.
+
+The wave names its stages as the single-chip wave does (``load``,
+``properties``, ``expand``, ``fingerprint``, ``local_dedup``,
+``probe``, ``store``), plus ``exchange``: the sender-side duplicate
+collapse, the owner bucketing and the all-to-alls. Each dispatch counts
+the mesh's slowest shard's loop rounds per wave, and the successor rows
+it sent to another shard (``exchange_rows``); the host loop opens the
+same ``fused.*`` spans.
 """
 
 from __future__ import annotations
@@ -50,15 +58,21 @@ from ..model import Expectation
 from ..resilience.membership import EpochOwnership, OwnerMap
 from .engine import (compaction_order, dedup_and_insert, dedup_impl,
                      eval_properties, expand_frontier,
-                     fingerprint_successors, first_occurrence_candidates,
+                     fingerprint_successors, first_occurrence_unscoped,
                      host_table_insert, matmul_expand, pick_bucket,
                      sender_kernel_impl)
-from .fused import (FusedTpuBfsChecker, ST_CAND, ST_DISC, ST_ERR, ST_HEAD,
-                    ST_OCC, ST_PROBE_ROUNDS, ST_SUCC, ST_TAIL, ST_TARGET,
-                    ST_WAVES, _pow2, _releasing)
+from .fused import (FusedTpuBfsChecker, ST_CAND, ST_DEDUP_ROUNDS, ST_DISC,
+                    ST_ERR, ST_HEAD, ST_OCC, ST_PROBE_ROUNDS, ST_SUCC,
+                    ST_TAIL, ST_TARGET, ST_WAVES, _pow2, _releasing)
 from .hashing import SENTINEL
 
 __all__ = ["ShardedFusedTpuBfsChecker"]
+
+# The stats row is the single-chip layout with one more slot before the
+# discovery fingerprints: the successor rows the dispatch's waves sent
+# to another shard, summed over the mesh.
+ST_EXCHANGE_ROWS = ST_DISC
+SH_DISC = ST_DISC + 1
 
 
 class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
@@ -217,18 +231,20 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
 
         def wave(carry):
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
-             succ_total, cand_total, err, disc, waves, target) = carry
-            # Local frontier slice (scalars head/tail are per shard).
-            idx = head + jnp.arange(B, dtype=jnp.int64)
-            valid = idx < tail
-            idx_c = jnp.minimum(idx, ucap - 1)
-            # Per-shard arenas store PACKED rows; unpack for compute.
-            bstore = vecs_a[idx_c]
-            bvecs = bstore
-            if layout is not None:
-                bvecs = layout.unpack(bstore)
-            bfps = fps_a[idx_c]
-            bebits = eb_a[idx_c]
+             succ_total, cand_total, sent_total, err, disc, waves, rounds,
+             target) = carry
+            with jax.named_scope("load"):
+                # Local frontier slice (scalars head/tail are per shard).
+                idx = head + jnp.arange(B, dtype=jnp.int64)
+                valid = idx < tail
+                idx_c = jnp.minimum(idx, ucap - 1)
+                # Per-shard arenas store PACKED rows; unpack for compute.
+                bstore = vecs_a[idx_c]
+                bvecs = bstore
+                if layout is not None:
+                    bvecs = layout.unpack(bstore)
+                bfps = fps_a[idx_c]
+                bebits = eb_a[idx_c]
 
             conds = eval_properties(prop_fns, bvecs)
             for i, prop in enumerate(properties):
@@ -253,7 +269,6 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                     else expand_frontier(dm, bvecs, valid))
                 dedup_fps, path_fps = fingerprint_successors(
                     dm, succ_flat, sflat, use_sym)
-            parent_fps = jnp.repeat(bfps, F)
 
             cleared = bebits
             for i, prop in enumerate(properties):
@@ -266,86 +281,99 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                                               ).astype(bool)
                     disc = disc.at[i].set(
                         combine_first(disc[i], *propose_first(hit, bfps)))
-            child_ebits = jnp.repeat(cleared, F)
+
+            # Pack before the in-loop exchange: the ICI moves Wr words
+            # per state, and the owner appends the received rows to its
+            # arena without ever unpacking them. (The sender megakernel
+            # already emitted storage rows.)
+            if sender is None:
+                with jax.named_scope("store"):
+                    succ_store = (succ_flat if layout is None
+                                  else layout.pack(succ_flat))
 
             # Bucket successors by owner and route them home (one ICI
             # all-to-all per wave, as in the unfused engine). With
             # exchange_novel_only, sender-side local dedup thins the
             # candidate stream first (same-shard later duplicates could
             # never win the owner's first-occurrence rule anyway).
-            if sender is None:
-                if exchange_novel:
-                    send_mask = first_occurrence_candidates(dedup_fps)
-                else:
-                    send_mask = sflat
-            part = (dedup_fps % n).astype(jnp.int32)
-            dest = part if assign is None else assign[part]
-            owner = jnp.where(send_mask, dest, n)
-            order = jnp.argsort(owner, stable=True)
-            so = owner[order]
-            starts = jnp.searchsorted(so, jnp.arange(n + 1))
-            rank = jnp.arange(S) - starts[jnp.clip(so, 0, n)]
-            slot = so * CAP + rank   # invalid bucket rows drop
+            with jax.named_scope("exchange"):
+                parent_fps = jnp.repeat(bfps, F)
+                child_ebits = jnp.repeat(cleared, F)
+                if sender is None:
+                    if exchange_novel:
+                        send_mask = first_occurrence_unscoped(dedup_fps)[0]
+                    else:
+                        send_mask = sflat
+                part = (dedup_fps % n).astype(jnp.int32)
+                dest = part if assign is None else assign[part]
+                # Successor rows that leave this shard.
+                sent = jnp.sum(send_mask & (dest != jax.lax.axis_index(
+                    "shard")), dtype=jnp.int64)
+                owner = jnp.where(send_mask, dest, n)
+                order = jnp.argsort(owner, stable=True)
+                so = owner[order]
+                starts = jnp.searchsorted(so, jnp.arange(n + 1))
+                rank = jnp.arange(S) - starts[jnp.clip(so, 0, n)]
+                slot = so * CAP + rank   # invalid bucket rows drop
 
-            def scatter(x, fill):
-                out = jnp.full((n * CAP,) + x.shape[1:], fill, x.dtype)
-                return out.at[slot].set(x[order], mode="drop")
+                def scatter(x, fill):
+                    out = jnp.full((n * CAP,) + x.shape[1:], fill, x.dtype)
+                    return out.at[slot].set(x[order], mode="drop")
 
-            a2a = partial(jax.lax.all_to_all, axis_name="shard",
-                          split_axis=0, concat_axis=0, tiled=True)
-            # Pack before the in-loop exchange: the ICI moves Wr words
-            # per state, and the owner appends the received rows to its
-            # arena without ever unpacking them. (The sender megakernel
-            # already emitted storage rows.)
-            if sender is None:
-                succ_store = (succ_flat if layout is None
-                              else layout.pack(succ_flat))
-            recv_vecs = a2a(scatter(succ_store, 0).reshape(
-                n, CAP, Wr)).reshape(R, Wr)
-            recv_dedup = a2a(scatter(dedup_fps, sentinel).reshape(
-                n, CAP)).reshape(R)
-            recv_path = a2a(scatter(path_fps, sentinel).reshape(
-                n, CAP)).reshape(R)
-            recv_parent = a2a(scatter(parent_fps, sentinel).reshape(
-                n, CAP)).reshape(R)
-            recv_ebits = a2a(scatter(child_ebits, 0).reshape(
-                n, CAP)).reshape(R)
+                a2a = partial(jax.lax.all_to_all, axis_name="shard",
+                              split_axis=0, concat_axis=0, tiled=True)
+                recv_vecs = a2a(scatter(succ_store, 0).reshape(
+                    n, CAP, Wr)).reshape(R, Wr)
+                recv_dedup = a2a(scatter(dedup_fps, sentinel).reshape(
+                    n, CAP)).reshape(R)
+                recv_path = a2a(scatter(path_fps, sentinel).reshape(
+                    n, CAP)).reshape(R)
+                recv_parent = a2a(scatter(parent_fps, sentinel).reshape(
+                    n, CAP)).reshape(R)
+                recv_ebits = a2a(scatter(child_ebits, 0).reshape(
+                    n, CAP)).reshape(R)
 
-            new_mask, new_count, cand_count, visited, _ = dedup(
+            new_mask, new_count, cand_count, visited, wave_rounds = dedup(
                 recv_dedup, visited)
-            comp = compaction_order(new_mask)
 
             # Full-window append on purpose: a cond-narrowed window
             # breaks the donated arena's in-place aliasing (see the
             # single-chip fused wave).
-            new_vecs = recv_vecs[comp]
-            if err_lane is not None:
-                # Rows are packed here; extract just the error lane
-                # from the packed words (no full unpack).
-                err_col = (new_vecs[:, err_lane] if layout is None
-                           else layout.lane(new_vecs, err_lane))
-                err = err | jnp.any((err_col != 0)
-                                    & (jnp.arange(R) < new_count))
-            vecs_a = jax.lax.dynamic_update_slice(
-                vecs_a, new_vecs, (tail, jnp.int64(0)))
-            fps_a = jax.lax.dynamic_update_slice(
-                fps_a, recv_path[comp], (tail,))
-            par_a = jax.lax.dynamic_update_slice(
-                par_a, recv_parent[comp], (tail,))
-            eb_a = jax.lax.dynamic_update_slice(
-                eb_a, recv_ebits[comp], (tail,))
+            with jax.named_scope("store"):
+                comp = compaction_order(new_mask)
+                new_vecs = recv_vecs[comp]
+                if err_lane is not None:
+                    # Rows are packed here; extract just the error lane
+                    # from the packed words (no full unpack).
+                    err_col = (new_vecs[:, err_lane] if layout is None
+                               else layout.lane(new_vecs, err_lane))
+                    err = err | jnp.any((err_col != 0)
+                                        & (jnp.arange(R) < new_count))
+                vecs_a = jax.lax.dynamic_update_slice(
+                    vecs_a, new_vecs, (tail, jnp.int64(0)))
+                fps_a = jax.lax.dynamic_update_slice(
+                    fps_a, recv_path[comp], (tail,))
+                par_a = jax.lax.dynamic_update_slice(
+                    par_a, recv_parent[comp], (tail,))
+                eb_a = jax.lax.dynamic_update_slice(
+                    eb_a, recv_ebits[comp], (tail,))
 
             nc = new_count.astype(jnp.int64)
-            succ_all = jax.lax.psum(succ_count, "shard")
-            cand_all = jax.lax.psum(cand_count.astype(jnp.int64), "shard")
+            succ_all, cand_all, sent_all = jax.lax.psum(jnp.stack(
+                [succ_count, cand_count.astype(jnp.int64), sent]), "shard")
+            # A wave waits for its slowest shard at the next exchange:
+            # the mesh's rounds are each loop's most on any shard.
+            wave_rounds = jax.lax.pmax(jnp.stack(wave_rounds), "shard")
             return (vecs_a, fps_a, par_a, eb_a, visited,
                     jnp.minimum(head + B, tail), tail + nc, occ + nc,
-                    succ_total + succ_all, cand_total + cand_all, err,
-                    disc, waves + 1, target)
+                    succ_total + succ_all, cand_total + cand_all,
+                    sent_total + sent_all, err, disc, waves + 1,
+                    tuple(r + wave_rounds[i] for i, r in enumerate(rounds)),
+                    target)
 
         def cond(carry):
-            (_, _, _, _, _, head, tail, occ, succ_total, _cand, err,
-             disc, waves, target) = carry
+            (_, _, _, _, _, head, tail, occ, succ_total, _cand, _sent, err,
+             disc, waves, _rounds, target) = carry
             # Every operand is either replicated (succ_total, disc,
             # waves, target) or globally reduced, so all shards agree.
             # The TPU lowers 64-bit all-reduces for sums only, so the
@@ -374,21 +402,22 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             succ_total = stats_in[0, ST_SUCC]
             cand_total = stats_in[0, ST_CAND]
             target = stats_in[0, ST_TARGET]
+            # Waves, loop rounds and rows sent count per dispatch.
             carry = (vecs_a, fps_a, par_a, eb_a, visited, head, tail,
-                     occ, succ_total, cand_total,
+                     occ, succ_total, cand_total, jnp.zeros((), jnp.int64),
                      stats_in[0, ST_ERR] != 0, disc,
-                     jnp.zeros((), jnp.int64), target)
+                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2, target)
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
-             succ_total, cand_total, err, disc, waves,
+             succ_total, cand_total, sent, err, disc, waves, rounds,
              _) = jax.lax.while_loop(cond, wave, carry)
+            local_rounds, probe_rounds = (r.astype(jnp.int64)
+                                          for r in rounds)
             # Discovery slots (replicated) ride in each shard's stats row
             # so the host reads one packed array per dispatch.
-            # The loop-round slots stay 0: this engine does not count
-            # them.
             stats = jnp.concatenate([
                 jnp.stack([head, tail, occ, succ_total, cand_total,
-                           target, err.astype(jnp.int64), waves]),
-                jnp.zeros(ST_DISC - ST_PROBE_ROUNDS, jnp.int64),
+                           target, err.astype(jnp.int64), waves,
+                           probe_rounds, local_rounds, sent]),
                 jax.lax.bitcast_convert_type(disc, jnp.int64)])[None]
             return vecs_a, fps_a, par_a, eb_a, visited, disc, stats
 
@@ -408,7 +437,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
         def sds(shape, dtype, sharding=spec):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-        L = ST_DISC + max(Pn, 1)
+        L = SH_DISC + max(Pn, 1)
         jitted = self._aot(jitted, (
             sds((n * ucap, Wr), jnp.uint32), sds((n * ucap,), jnp.uint64),
             sds((n * ucap,), jnp.uint64), sds((n * ucap,), jnp.uint32),
@@ -486,7 +515,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
         R_max = n * self._B_max * F
         properties = self._properties
         Pn = len(properties)
-        L = ST_DISC + max(Pn, 1)
+        L = SH_DISC + max(Pn, 1)
 
         # Split the pending blocks into per-shard seeds by ownership.
         blocks = list(self._pending)
@@ -558,9 +587,14 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
         stats_dev = jax.device_put(stats_np, self._shard_spec())
 
         from collections import deque
-        inflight: deque = deque()  # (stats_dev, meta), oldest first
+        # (stats_dev, meta, launch seconds), oldest first
+        inflight: deque = deque()
 
         def process(entry) -> None:
+            with self._tracer.span("fused.process"):
+                apply(entry, time.monotonic())
+
+        def apply(entry, t_proc: float) -> None:
             nonlocal occs, succ_total, cand_seen, arena_total
             if self._faults.active:
                 # Same placement rationale as the single-chip fused
@@ -568,8 +602,11 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 # worst case.
                 self._faults.crash("wave_crash", self._tracer,
                                    wave=len(self.dispatch_log))
-            stats_out, meta = entry
-            stats_h = np.asarray(stats_out)      # [n, L]
+            stats_out, meta, launch_s = entry
+            t_wait = time.monotonic()
+            with self._tracer.span("fused.stats_wait"):
+                stats_h = np.asarray(stats_out)      # [n, L]
+            waited = time.monotonic() - t_wait
             heads_prev = self._shard_heads
             heads = stats_h[:, ST_HEAD].copy()
             tails = stats_h[:, ST_TAIL].copy()
@@ -596,16 +633,30 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 arena_total = new_total
                 now = time.monotonic()
                 self.wave_log.append((now, self._state_count))
+                waves = int(stats_h[0, ST_WAVES])
                 # Unified wave event (obs schema): deltas vs the last
                 # processed dispatch; load factor is the fullest
                 # shard's table slice (the growth-gating quantity).
                 wave_evt = dict(
                     meta, t=now, states=self._state_count,
                     unique=self._unique_count,
-                    waves=int(stats_h[0, ST_WAVES]),
+                    waves=waves,
                     compiled=self._take_compile(),
                     successors=succ_total - succ_prev,
                     candidates=cand_total - cand_prev, novel=novel,
+                    # v15: the slowest shard's loop rounds per wave,
+                    # summed over the dispatch, and the host's own time
+                    # on it (its launch and processing, less the stats
+                    # wait).
+                    probe_rounds=int(stats_h[0, ST_PROBE_ROUNDS]),
+                    dedup_rounds=int(stats_h[0, ST_DEDUP_ROUNDS]),
+                    host_s=launch_s + (now - t_proc) - waited,
+                    # v16: successor rows sent to another shard, and the
+                    # rows the all-to-alls carry between shards (each
+                    # shard's n-1 off-shard buckets of B*F rows a wave).
+                    exchange_rows=int(stats_h[0, ST_EXCHANGE_ROWS]),
+                    exchange_slots=waves * n * (n - 1) * meta["bucket"]
+                    * F,
                     # Frontier rows consumed across every shard (the
                     # kernel-occupancy numerator).
                     rows=int((heads - heads_prev).sum()),
@@ -645,7 +696,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                     self._flight.record(wave_evt)
                 if Pn:
                     disc_h = np.ascontiguousarray(
-                        stats_h[0, ST_DISC:ST_DISC + Pn]).view(np.uint64)
+                        stats_h[0, SH_DISC:SH_DISC + Pn]).view(np.uint64)
                     for i, prop in enumerate(properties):
                         fp = int(disc_h[i])
                         if (fp != int(SENTINEL)
@@ -658,6 +709,12 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             self._service_sync(None)
 
         while True:
+            if self._preempt_evt.is_set():
+                # Preemption: the epilogue retires every in-flight
+                # dispatch and syncs the parent log (see the single-chip
+                # fused loop).
+                self.preempted = True
+                break
             with self._lock:
                 # Vacuously true with zero properties (bfs.rs:117).
                 done = (len(self._discoveries) == Pn
@@ -682,104 +739,106 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 process(inflight.popleft())
                 continue
             if growth:
-                # Wrapped for OOM graceful degradation like the
-                # single-chip fused engine: shed the top batch bucket
-                # and re-evaluate at the loop top.
-                try:
-                    self._grow_requested = (
-                        self._capacity * 2 if int(occs.max()) + R_b
-                        > self._capacity // 2 else self._capacity)
-                    if self._faults.active:
-                        self._faults.crash("grow_oom", self._tracer)
-                    while int(occs.max()) + R_b > self._capacity // 2:
-                        new_cap = self._capacity * 2
-                        if self._tracer.enabled:
-                            self._tracer.event(
-                                "grow", kind="table",
-                                old=self._capacity, new=new_cap)
-                        visited = self._rehash_fn(self._capacity,
-                                                  new_cap)(visited)
-                        self._capacity = new_cap
-                        self._visited = visited
-                    while int(self._shard_tails.max()) + R_b > ucap:
-                        budget = self._store.device_budget \
-                            if self._store.active else None
-                        over = (budget is not None
-                                and 2 * n * ucap * self._arena_row_bytes()
-                                + n * self._capacity * 8 > budget)
-                        if over and int(self._shard_heads.max()) > 0:
-                            # Per-shard arena-span spill (tiered
-                            # store): parent-sync every shard, then
-                            # shift each shard's live window down by
-                            # its own head — headroom without growing
-                            # past the device budget. Bit-identical:
-                            # each shard's [head_i, tail_i) rows are
-                            # unchanged, just re-based.
-                            self._fetch_parents(None)
-                            shifts = self._shard_heads.copy()
-                            sh = jax.device_put(shifts.astype(np.int64),
-                                                self._shard_spec())
-                            vecs_a = self._roll_fn(
-                                ucap, jnp.uint32, W)(vecs_a, sh)
-                            fps_a = self._roll_fn(
-                                ucap, jnp.uint64)(fps_a, sh)
-                            par_a = self._roll_fn(
-                                ucap, jnp.uint64)(par_a, sh)
-                            eb_a = self._roll_fn(
-                                ucap, jnp.uint32)(eb_a, sh)
+                with self._tracer.span("fused.grow"):
+                    # Wrapped for OOM graceful degradation like the
+                    # single-chip fused engine: shed the top batch bucket
+                    # and re-evaluate at the loop top.
+                    try:
+                        self._grow_requested = (
+                            self._capacity * 2 if int(occs.max()) + R_b
+                            > self._capacity // 2 else self._capacity)
+                        if self._faults.active:
+                            self._faults.crash("grow_oom", self._tracer)
+                        while int(occs.max()) + R_b > self._capacity // 2:
+                            new_cap = self._capacity * 2
+                            if self._tracer.enabled:
+                                self._tracer.event(
+                                    "grow", kind="table",
+                                    old=self._capacity, new=new_cap)
+                            visited = self._rehash_fn(self._capacity,
+                                                      new_cap)(visited)
+                            self._capacity = new_cap
+                            self._visited = visited
+                        while int(self._shard_tails.max()) + R_b > ucap:
+                            budget = self._store.device_budget \
+                                if self._store.active else None
+                            over = (budget is not None
+                                    and 2 * n * ucap * self._arena_row_bytes()
+                                    + n * self._capacity * 8 > budget)
+                            if over and int(self._shard_heads.max()) > 0:
+                                # Per-shard arena-span spill (tiered
+                                # store): parent-sync every shard, then
+                                # shift each shard's live window down by
+                                # its own head — headroom without growing
+                                # past the device budget. Bit-identical:
+                                # each shard's [head_i, tail_i) rows are
+                                # unchanged, just re-based.
+                                self._fetch_parents(None)
+                                shifts = self._shard_heads.copy()
+                                sh = jax.device_put(shifts.astype(np.int64),
+                                                    self._shard_spec())
+                                vecs_a = self._roll_fn(
+                                    ucap, jnp.uint32, W)(vecs_a, sh)
+                                fps_a = self._roll_fn(
+                                    ucap, jnp.uint64)(fps_a, sh)
+                                par_a = self._roll_fn(
+                                    ucap, jnp.uint64)(par_a, sh)
+                                eb_a = self._roll_fn(
+                                    ucap, jnp.uint32)(eb_a, sh)
+                                self._arena = (vecs_a, fps_a, par_a, eb_a)
+                                with self._lock:
+                                    self._shard_tails = \
+                                        self._shard_tails - shifts
+                                    self._shard_heads = np.zeros(
+                                        n, np.int64)
+                                    self._shard_synced = \
+                                        self._shard_synced - shifts
+                                rows = int(shifts.sum())
+                                # Re-base the novel-count baseline: novel
+                                # is the next dispatch's tails.sum() minus
+                                # this, and every tail just moved down by
+                                # its shard's shift.
+                                arena_total -= rows
+                                self._store.note_arena_span(
+                                    rows, rows * self._arena_row_bytes())
+                                # Rebuild the chained per-shard stats at
+                                # rest (discovery slots are outputs only).
+                                st = np.zeros((n, L), np.int64)
+                                st[:, ST_HEAD] = 0
+                                st[:, ST_TAIL] = self._shard_tails
+                                st[:, ST_OCC] = occs
+                                st[:, ST_SUCC] = succ_total
+                                st[:, ST_CAND] = cand_seen
+                                st[:, ST_TARGET] = target_eff
+                                stats_dev = jax.device_put(
+                                    st, self._shard_spec())
+                                continue
+                            if over and self._store.active:
+                                self._store.note_device_pressure(
+                                    2 * n * ucap * self._arena_row_bytes()
+                                    + n * self._capacity * 8, budget)
+                            new_ucap = ucap * 2
+                            if self._tracer.enabled:
+                                self._tracer.event("grow", kind="arena",
+                                                   old=ucap, new=new_ucap)
+                            vecs_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint32, W)(vecs_a)
+                            fps_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint64)(fps_a)
+                            par_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint64)(par_a)
+                            eb_a = self._grow_fn(
+                                ucap, new_ucap, jnp.uint32)(eb_a)
+                            ucap = new_ucap
+                            self._ucap = ucap
+                            self._slice_cache.clear()
                             self._arena = (vecs_a, fps_a, par_a, eb_a)
-                            with self._lock:
-                                self._shard_tails = \
-                                    self._shard_tails - shifts
-                                self._shard_heads = np.zeros(
-                                    n, np.int64)
-                                self._shard_synced = \
-                                    self._shard_synced - shifts
-                            rows = int(shifts.sum())
-                            # Re-base the novel-count baseline: novel
-                            # is the next dispatch's tails.sum() minus
-                            # this, and every tail just moved down by
-                            # its shard's shift.
-                            arena_total -= rows
-                            self._store.note_arena_span(
-                                rows, rows * self._arena_row_bytes())
-                            # Rebuild the chained per-shard stats at
-                            # rest (discovery slots are outputs only).
-                            st = np.zeros((n, L), np.int64)
-                            st[:, ST_HEAD] = 0
-                            st[:, ST_TAIL] = self._shard_tails
-                            st[:, ST_OCC] = occs
-                            st[:, ST_SUCC] = succ_total
-                            st[:, ST_CAND] = cand_seen
-                            st[:, ST_TARGET] = target_eff
-                            stats_dev = jax.device_put(
-                                st, self._shard_spec())
-                            continue
-                        if over and self._store.active:
-                            self._store.note_device_pressure(
-                                2 * n * ucap * self._arena_row_bytes()
-                                + n * self._capacity * 8, budget)
-                        new_ucap = ucap * 2
-                        if self._tracer.enabled:
-                            self._tracer.event("grow", kind="arena",
-                                               old=ucap, new=new_ucap)
-                        vecs_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint32, W)(vecs_a)
-                        fps_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint64)(fps_a)
-                        par_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint64)(par_a)
-                        eb_a = self._grow_fn(
-                            ucap, new_ucap, jnp.uint32)(eb_a)
-                        ucap = new_ucap
-                        self._ucap = ucap
-                        self._slice_cache.clear()
-                        self._arena = (vecs_a, fps_a, par_a, eb_a)
-                except Exception as e:  # noqa: BLE001 — non-OOM re-raised
-                    self._handle_grow_failure(e)
+                    except Exception as e:  # noqa: BLE001 — non-OOM raised
+                        self._handle_grow_failure(e)
                 continue
             if ckpt_due:
-                self._write_checkpoint(self._ckpt_path)
+                with self._tracer.span("fused.checkpoint"):
+                    self._write_checkpoint(self._ckpt_path)
                 last_ckpt_states = self._unique_count
                 continue
 
@@ -790,10 +849,13 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                      self._owner_map.epoch))
                 if self._prof.should_sample(pkey):
                     t0 = time.monotonic()
-            (vecs_a, fps_a, par_a, eb_a, visited, disc,
-             stats_dev) = self._dispatch_fn(
-                bucket, self._capacity, ucap)(
-                vecs_a, fps_a, par_a, eb_a, visited, disc, stats_dev)
+            t_launch = time.monotonic()
+            with self._tracer.span("fused.launch"):
+                (vecs_a, fps_a, par_a, eb_a, visited, disc,
+                 stats_dev) = self._dispatch_fn(
+                    bucket, self._capacity, ucap)(
+                    vecs_a, fps_a, par_a, eb_a, visited, disc, stats_dev)
+            launch_s = time.monotonic() - t_launch
             if t0 is not None:
                 # Rest-point timing (obs/prof.py): draining the
                 # multi-dispatch pipeline for this one sample is the
@@ -813,7 +875,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 meta["_prof_key"] = pkey
                 if prof_s is not None:
                     meta["_prof_s"] = prof_s
-            inflight.append((stats_dev, meta))
+            inflight.append((stats_dev, meta, launch_s))
             if len(inflight) >= self._depth:
                 process(inflight.popleft())
         # Retire every launched dispatch (normal exit); see the
@@ -831,20 +893,27 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
 
     # -- Parent log / checkpoint (per-shard arenas) ------------------------
 
+    def _arena_spans(self) -> list:
+        u = self._ucap
+        return [(i * u, int(h), int(t)) for i, (h, t) in enumerate(
+            zip(self._shard_heads, self._shard_tails))]
+
     def _fetch_parents(self, _tail=None) -> None:
-        if hasattr(self, "_arena"):
-            _, fps_a, par_a, _ = self._arena
-            u = self._ucap
-            for i in range(self._n):
-                lo = int(self._shard_synced[i])
-                hi = int(self._shard_tails[i])
-                if hi <= lo:
-                    continue
-                child = self._fetch_rows(fps_a, i * u + lo, hi - lo)
-                parent = self._fetch_rows(par_a, i * u + lo, hi - lo)
-                with self._lock:
-                    self._parent_log.append((child, parent))
-                self._shard_synced[i] = hi
+        if hasattr(self, "_arena") and any(
+                self._shard_tails > self._shard_synced):
+            with self._tracer.span("fused.parent_sync"):
+                _, fps_a, par_a, _ = self._arena
+                u = self._ucap
+                for i in range(self._n):
+                    lo = int(self._shard_synced[i])
+                    hi = int(self._shard_tails[i])
+                    if hi <= lo:
+                        continue
+                    child = self._fetch_rows(fps_a, i * u + lo, hi - lo)
+                    parent = self._fetch_rows(par_a, i * u + lo, hi - lo)
+                    with self._lock:
+                        self._parent_log.append((child, parent))
+                    self._shard_synced[i] = hi
         with self._sync_cond:
             self._sync_generation += 1
             self._sync_cond.notify_all()

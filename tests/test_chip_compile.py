@@ -10,7 +10,9 @@ a TPU (the CPU default is off). The topology is described inside a
 fixture only, never at import (one process at a time may load libtpu).
 """
 
+import hashlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -20,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, SingleDeviceSharding
 
 import chip_smoke
+from benchmark.trace_stages import STAGES, stage_of
 from two_phase_commit import TwoPhaseSys
 
 
@@ -77,21 +80,64 @@ def test_classic_wave_paxos3_compiles(one_chip):
     assert prog.memory_analysis().temp_size_in_bytes > 0
 
 
+#: the single-chip fused dispatch programs, at the sizes their cells
+#: run (paxos3-check, twopc10-check) or at a small size (twopc3)
+FUSED = {"paxos3": (chip_smoke.paxos3, (4096, 1 << 22, 1 << 21)),
+         "twopc3": (lambda: TwoPhaseSys(3), (1024, 1 << 16, 1 << 16)),
+         "twopc10": (lambda: TwoPhaseSys(10), (4096, 1 << 27, 1 << 26))}
+
+#: sha256 of each cell's fused dispatch compiled for one described v5e
+#: chip, its text without metadata (``_hlo_digest``): the program as the
+#: sharded engine's telemetry found it. A change to the single-chip wave
+#: changes these on purpose.
+FUSED_HLO_SHA256 = {
+    "paxos3": ("e16fabd3fd7f48dec4f99eaf5f3b4617"
+                "460770d3fb703efdc6589f49aa35ea5f"),
+    "twopc10": ("4076037a45cbc28c979c155fff8c6627"
+                "49b3cad790281344cb813d1924ee6ff5"),
+}
+
+#: the stack-frame tables at the head of a compiled module's text
+_STACK_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                 "StackFrames")
+
+
+def _hlo_digest(text: str) -> str:
+    """A compiled module's text without its metadata (op names, source
+    lines, stack frames), hashed."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = "\n\n".join(b for b in text.split("\n\n")
+                       if not b.startswith(_STACK_TABLES))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.fixture(scope="module")
+def fused_programs(one_chip):
+    """Each FUSED dispatch compiled for the described chip (compiled
+    once here: the larger ones take 20-30 s)."""
+    out = {}
+    for name, (make, shape) in FUSED.items():
+        c = _engine(make(), fused=True, batch_size=64)
+        c._aot = _for_chip(one_chip)
+        out[name] = c._build_dispatch_fn(*shape)
+    return out
+
+
 @pytest.mark.parametrize("name", ["paxos3", "twopc3"])
-def test_fused_dispatch_compiles(one_chip, name):
-    if name == "paxos3":
-        model, sizes = chip_smoke.paxos3(), chip_smoke.PAXOS3_ONE_CHIP
-        shape = (sizes["batch_size"], sizes["table_capacity"],
-                 sizes["arena_capacity"])
-    else:
-        model, shape = TwoPhaseSys(3), (1024, 1 << 16, 1 << 16)
-    c = _engine(model, fused=True, batch_size=64)
-    c._aot = _for_chip(one_chip)
-    prog = c._build_dispatch_fn(*shape)
-    mem = prog.memory_analysis()
+def test_fused_dispatch_compiles(fused_programs, name):
+    mem = fused_programs[name].memory_analysis()
     # The arena and table are donated: the compiler aliases them.
     assert mem.alias_size_in_bytes > 0
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("name", sorted(FUSED_HLO_SHA256))
+def test_fused_dispatch_hlo_is_unchanged(fused_programs, name):
+    """The single-chip cells' programs, modulo metadata, are the ones
+    the sharded engine's stage scopes and counters found: naming the
+    mesh wave's stages took nothing from the single-chip wave."""
+    assert _hlo_digest(fused_programs[name].as_text()) == (
+        FUSED_HLO_SHA256[name])
 
 
 def test_sharded_fused_dispatch_compiles_on_four_chips(topo):
@@ -105,6 +151,63 @@ def test_sharded_fused_dispatch_compiles_on_four_chips(topo):
     prog = c._dispatch_fn(256, 1 << 16, 1 << 16)
     text = prog.as_text()
     assert "all-to-all" in text and "all-reduce" in text
+
+
+#: 2pc-11 on four chips at the twopc11-check-4chip cell's per-shard
+#: sizes (benchmark/configs/2pc-11.json): batch, table, arena
+TWOPC11_SHARD = (4096, 1 << 28, 1 << 27)
+
+
+@pytest.fixture(scope="module")
+def twopc11_mesh_program(topo):
+    """The cell's sharded-fused dispatch, compiled for the described
+    2x2 mesh (about 40 s here)."""
+    c = _engine(TwoPhaseSys(11), fused=True, batch_size=32,
+                mesh=Mesh(np.array(jax.devices()[:4]), ("shard",)))
+    c._mesh = Mesh(np.array(topo.devices), ("shard",))
+    c._wave_cache.clear()
+    c._aot = _for_chip(None)
+    return c._dispatch_fn(*TWOPC11_SHARD)
+
+
+def _ops(text: str, kind: str) -> list:
+    """The name-scope path of each ``kind`` instruction of a compiled
+    module's text."""
+    return re.findall(r"= [^\n]*? " + kind
+                      + r'\([^\n]*?metadata=\{op_name="([^"]*)"', text)
+
+
+def test_twopc11_mesh_dispatch_fits_a_chip(twopc11_mesh_program):
+    mem = twopc11_mesh_program.memory_analysis()
+    # per chip: a 2^28-slot table slice and a 2^27-row arena slice
+    assert mem.argument_size_in_bytes > 5e9
+    assert mem.alias_size_in_bytes > 5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_twopc11_mesh_dispatch_names_its_stages(twopc11_mesh_program):
+    text = twopc11_mesh_program.as_text()
+    parts = {p for path in re.findall(r'op_name="([^"]*)"', text)
+             for p in path.split("/")}
+    assert set(STAGES) | {"exchange"} <= parts
+
+
+def test_twopc11_mesh_wave_has_one_local_dedup_loop(twopc11_mesh_program):
+    """A wave's edge in a trace is its one top-level ``while`` in scope
+    ``local_dedup`` (``benchmark/trace_stages.py``): the owner's. The
+    sender side's duplicate collapse runs under ``exchange``."""
+    loops = _ops(twopc11_mesh_program.as_text(), "while")
+    assert [p for p in loops if stage_of(p) == "local_dedup"] == [
+        "jit(local)/shard_map/while/body/local_dedup/while"]
+    assert any("exchange" in p.split("/") and stage_of(p) is None
+               for p in loops)
+
+
+def test_twopc11_mesh_all_to_alls_sit_in_exchange(twopc11_mesh_program):
+    paths = _ops(twopc11_mesh_program.as_text(), "all-to-all")
+    assert len(paths) >= 5
+    assert all("exchange" in p.split("/") and stage_of(p) is None
+               for p in paths)
 
 
 def test_pallas_table_kernel_is_refused_on_tpu(one_chip, monkeypatch):

@@ -331,7 +331,10 @@ def _worker_wave(worker, seq, run="rw", **kw):
                    # v15 loop rounds and host seconds (null where not
                    # counted).
                    "probe_rounds": None, "dedup_rounds": None,
-                   "host_s": None})
+                   "host_s": None,
+                   # v16 shard-exchange counts (null without an
+                   # exchange).
+                   "exchange_rows": None, "exchange_slots": None})
     fields.update(kw)
     return json.dumps(fields)
 
@@ -367,7 +370,8 @@ def test_lint_elastic_wave_requires_attribution():
                 "kernel_path", "rows", "job_id", "jobs_in_wave",
                 "io_stall_s", "expand_impl",
                 "cost_flops", "cost_bytes", "cost_ratio",
-                "probe_rounds", "dedup_rounds", "host_s"):
+                "probe_rounds", "dedup_rounds", "host_s",
+                "exchange_rows", "exchange_slots"):
         old.pop(key, None)
     _, errors = trace_lint.lint_lines([json.dumps(old)])
     assert not errors, errors

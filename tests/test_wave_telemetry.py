@@ -10,6 +10,9 @@
 - **Host spans**: the host loop's launch, processing and stats wait are
   profiler annotations, on the host plane of a profiler session; with
   no session and ``STpu_TRACE`` unset they record nothing.
+- **The sharded-fused engine** names the same stages plus ``exchange``,
+  counts its slowest shard's rounds and the rows it sends between
+  shards, and opens the same spans, with results unchanged.
 """
 
 import glob
@@ -24,6 +27,7 @@ sys.path.insert(0, os.path.join(
     "examples"))
 
 from stateright_tpu.obs import NULL_TRACER  # noqa: E402
+from stateright_tpu.tpu.hashing import SENTINEL  # noqa: E402
 from two_phase_commit import TwoPhaseSys  # noqa: E402
 
 STAGES = ("load", "properties", "expand", "fingerprint", "local_dedup",
@@ -94,6 +98,133 @@ def test_host_spans_on_the_profiler_host_plane(tmp_path):
                 names.update(ev.name for ev in line.events
                              if ev.name.startswith("fused."))
     assert {"fused.launch", "fused.process", "fused.stats_wait"} <= names
+
+
+def _mesh_check(**kw):
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("shard",))
+    return TwoPhaseSys(4).checker().spawn_tpu_bfs(
+        **dict(dict(fused=True, mesh=mesh, batch_size=16), **kw))
+
+
+@pytest.fixture(scope="module")
+def mesh_pair():
+    """A sharded-fused 2pc-4 check on four devices and the classic
+    sharded engine's on the same mesh."""
+    fused = _mesh_check().join()
+    classic = _mesh_check(fused=False).join()
+    return fused, classic
+
+
+@pytest.mark.parametrize("scope", STAGES + ("exchange",))
+def test_sharded_dispatch_ops_carry_stage_scope(mesh_pair, scope):
+    c, _ = mesh_pair
+    programs = [p for k, p in c._wave_cache.items()
+                if k[0] == "sharded-dispatch"]
+    assert programs
+    parts = {part for prog in programs
+             for path in re.findall(r'op_name="([^"]*)"', prog.as_text())
+             for part in path.split("/")}
+    assert scope in parts
+
+
+def test_sharded_dispatch_counts_rounds_and_exchange(mesh_pair):
+    c, _ = mesh_pair
+    assert c.unique_state_count() == 1568
+    n = 4
+    entries = [e for e in c.dispatch_log if e["waves"]]
+    assert entries
+    for e in entries:
+        # a wave waits for its slowest shard: at least one round each
+        for key in ("probe_rounds", "dedup_rounds"):
+            assert e["waves"] <= e[key] <= e["candidates"], (key, e)
+        assert e["exchange_slots"] == (e["waves"] * n * (n - 1)
+                                       * e["bucket"] * c._F)
+        # a shard sends at most its B*F successors into (n-1)*B*F slots
+        assert 0 < e["exchange_rows"] <= e["exchange_slots"] // (n - 1)
+        assert e["exchange_rows"] <= e["candidates"]
+    assert all(e["probe_rounds"] == e["dedup_rounds"]
+               == e["exchange_rows"] == e["exchange_slots"] == 0
+               for e in c.dispatch_log if not e["waves"])
+    assert all(e["host_s"] >= 0 for e in c.dispatch_log)
+
+
+def test_sharded_counts_leave_the_results_as_the_classic_engine(mesh_pair):
+    fused, classic = mesh_pair
+    assert fused.unique_state_count() == classic.unique_state_count()
+    assert fused.state_count() == classic.state_count()
+    assert set(fused.discoveries()) == set(classic.discoveries())
+    for name in fused.discoveries():
+        assert (fused.discovery(name).encode()
+                == classic.discovery(name).encode())
+
+
+def test_sharded_arena_rows_are_the_admitted_states(mesh_pair):
+    c, _ = mesh_pair
+    shards = c.arena_rows()
+    assert len(shards) == 4
+    fps = [int(f) for s in shards for f in s["fps"]]
+    assert len(fps) == len(set(fps)) == c.unique_state_count()
+    # every shard holds its own fingerprints, all expanded at the end
+    for i, s in enumerate(shards):
+        assert all(int(f) % 4 == i for f in s["fps"])
+        assert s["head"] == len(s["fps"]) == len(s["lanes"])
+    parents = {int(p) for s in shards for p in s["parents"]}
+    assert parents - set(fps) == {int(SENTINEL)}
+
+
+def test_fused_arena_rows_unpack_to_the_admitted_states():
+    """On one chip, from packed storage rows: each row's lanes hash to
+    its own fingerprint, every admitted state once."""
+    import numpy as np
+
+    from stateright_tpu.tpu.hashing import host_fp64
+
+    c = TwoPhaseSys(3).checker().spawn_tpu_bfs(
+        fused=True, batch_size=8, pack_arena=True).join()
+    assert c._pack_on
+    (rows,) = c.arena_rows()
+    assert rows["head"] == len(rows["fps"]) == c.unique_state_count()
+    assert [host_fp64(np.asarray(v)) for v in rows["lanes"]] == [
+        int(f) for f in rows["fps"]]
+
+
+def test_sharded_preempt_stops_at_a_dispatch_boundary():
+    import time
+
+    c = _mesh_check(batch_size=4, waves_per_dispatch=2)
+    while not c.dispatch_log:
+        time.sleep(0.01)
+    c.preempt()
+    c.join()
+    assert c.preempted
+    heads = sum(s["head"] for s in c.arena_rows())
+    assert heads == sum(e["rows"] for e in c.dispatch_log)
+    assert c.unique_state_count() < 1568
+
+
+def test_sharded_host_spans_on_the_profiler_host_plane(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    _mesh_check().join()  # compiles outside the session
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _mesh_check().join()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events
+                             if ev.name.startswith("fused."))
+    assert {"fused.launch", "fused.process", "fused.stats_wait",
+            "fused.parent_sync"} <= names
 
 
 def test_no_session_no_trace_writes_nothing(tmp_path, monkeypatch):
