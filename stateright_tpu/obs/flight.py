@@ -203,7 +203,9 @@ class FlightRecorder:
                     "probe_rounds", "dedup_rounds", "host_s",
                     # v16 shard-exchange counts: null on producers
                     # without an exchange.
-                    "exchange_rows", "exchange_slots"):
+                    "exchange_rows", "exchange_slots",
+                    # v17 probe slots: null where the rounds are.
+                    "probe_slots"):
             out.setdefault(key, None)
         return out
 

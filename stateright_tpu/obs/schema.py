@@ -35,7 +35,7 @@ __all__ = [
     "WAVE_FIELDS", "WAVE_FIELDS_V1", "WAVE_FIELDS_V2",
     "WAVE_FIELDS_V5", "WAVE_FIELDS_V6", "WAVE_FIELDS_V8",
     "WAVE_FIELDS_V9", "WAVE_FIELDS_V11", "WAVE_FIELDS_V12",
-    "WAVE_FIELDS_V14", "WAVE_FIELDS_V15",
+    "WAVE_FIELDS_V14", "WAVE_FIELDS_V15", "WAVE_FIELDS_V16",
     "validate_event", "validate_line",
 ]
 
@@ -212,10 +212,15 @@ SHED_REASONS = ("slo_burn", "brownout", "retry_budget", "queue_full")
 #: (the rows the all-to-alls carried between shards, padding
 #: included). ``null`` on producers without an exchange. The
 #: sharded-fused engine fills the v15 keys and spans too.
-#: v1-v15 streams still validate (against their version's field set);
+#: v17: fused dispatches count the rows their probe loop carried —
+#: wave events gained ``probe_slots`` (each probe round's rows, summed
+#: over the dispatch's waves; on a mesh the slowest shard's per wave).
+#: Σ``candidates`` / Σ``probe_slots`` is how full the probe's rounds
+#: run. ``null`` where ``probe_rounds`` is.
+#: v1-v16 streams still validate (against their version's field set);
 #: streams NEWER than this validator are rejected with a clear
 #: upgrade message instead of a cascade of field-set mismatches.
-SCHEMA_VERSION = 16
+SCHEMA_VERSION = 17
 
 #: Environment knob: set to a file path to stream JSONL events there.
 #: Unset means the null tracer — the hot loop pays one attribute check.
@@ -344,6 +349,9 @@ WAVE_FIELDS: Dict[str, tuple] = {
     # there is none).
     "exchange_rows": _INT + (_NULL,),
     "exchange_slots": _INT + (_NULL,),
+    # v17: the rows the probe's rounds carried (null where the rounds
+    # are not counted).
+    "probe_slots": _INT + (_NULL,),
 }
 
 #: v5 attribution keys (absent from v2-v4 wave events).
@@ -375,6 +383,9 @@ _WAVE_V15_KEYS = ("probe_rounds", "dedup_rounds", "host_s")
 #: v16 shard-exchange keys (absent from v1-v15 wave events).
 _WAVE_V16_KEYS = ("exchange_rows", "exchange_slots")
 
+#: v17 probe-slot key (absent from v1-v16 wave events).
+_WAVE_V17_KEYS = ("probe_slots",)
+
 #: The v1 wave field set (no bandwidth gauges) — v1 captures validate
 #: against this exactly.
 WAVE_FIELDS_V1: Dict[str, tuple] = {
@@ -382,59 +393,66 @@ WAVE_FIELDS_V1: Dict[str, tuple] = {
     if k not in ("bytes_per_state", "arena_bytes", "table_bytes")
     + _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
     + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
-    + _WAVE_V15_KEYS + _WAVE_V16_KEYS}
+    + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
 
 #: The v2-v4 wave field set (bandwidth gauges, no attribution keys).
 WAVE_FIELDS_V2: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS
     + _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
-    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS}
+    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
 
 #: The v5 wave field set (attribution keys, no tier gauges).
 WAVE_FIELDS_V5: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
     + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS
-    + _WAVE_V16_KEYS}
+    + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
 
 #: The v6-v7 wave field set (tier gauges, no kernel-path keys).
 WAVE_FIELDS_V6: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V8_KEYS + _WAVE_V9_KEYS + _WAVE_V10_KEYS
-    + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS}
+    + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS
+    + _WAVE_V17_KEYS}
 
 #: The v8 wave field set (kernel-path keys, no mux attribution).
 WAVE_FIELDS_V8: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
-    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS}
+    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
 
 #: The v9 wave field set (mux attribution, no async-I/O gauge).
 WAVE_FIELDS_V9: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
-    + _WAVE_V15_KEYS + _WAVE_V16_KEYS}
+    + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
 
 #: The v10-v11 wave field set (async-I/O gauge, no expand_impl).
 WAVE_FIELDS_V11: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS
-    + _WAVE_V16_KEYS}
+    + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
 
 #: The v12 wave field set (expand_impl, no cost attribution).
 WAVE_FIELDS_V12: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS}
+    if k not in _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS
+    + _WAVE_V17_KEYS}
 
 #: The v13-v14 wave field set (cost attribution, no loop rounds).
 WAVE_FIELDS_V14: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V15_KEYS + _WAVE_V16_KEYS}
+    if k not in _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
 
 #: The v15 wave field set (loop rounds, no shard-exchange counts).
 WAVE_FIELDS_V15: Dict[str, tuple] = {
-    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V16_KEYS}
+    k: v for k, v in WAVE_FIELDS.items()
+    if k not in _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+
+#: The v16 wave field set (shard-exchange counts, no probe slots).
+WAVE_FIELDS_V16: Dict[str, tuple] = {
+    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V17_KEYS}
 
 _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            3: WAVE_FIELDS_V2, 4: WAVE_FIELDS_V2,
@@ -447,7 +465,8 @@ _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            # v14 added event types only; its wave
                            # field set matches v13.
                            13: WAVE_FIELDS_V14, 14: WAVE_FIELDS_V14,
-                           15: WAVE_FIELDS_V15, 16: WAVE_FIELDS}
+                           15: WAVE_FIELDS_V15, 16: WAVE_FIELDS_V16,
+                           17: WAVE_FIELDS}
 
 #: Required fields per trace event type (beyond the stamped
 #: schema_version/engine/run/t, which every event carries).

@@ -208,7 +208,9 @@ class RunTracer:
                     "probe_rounds", "dedup_rounds", "host_s",
                     # v16 shard-exchange counts: null on producers
                     # without an exchange.
-                    "exchange_rows", "exchange_slots"):
+                    "exchange_rows", "exchange_slots",
+                    # v17 probe slots: null where the rounds are.
+                    "probe_slots"):
             evt.setdefault(key, None)
         self._write(evt, number_wave=True)
 

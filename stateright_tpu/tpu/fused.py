@@ -49,7 +49,7 @@ from ..model import Expectation
 from .engine import (TpuBfsChecker, compaction_order, dedup_and_insert,
                      dedup_impl, eval_properties, expand_frontier,
                      fingerprint_successors, matmul_expand, pick_bucket,
-                     wave_kernel_impl)
+                     probe_chunk, wave_kernel_impl)
 from .hashing import SENTINEL
 
 __all__ = ["FusedTpuBfsChecker", "FusedUnsupported"]
@@ -616,6 +616,11 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                                   if counted else None),
                     dedup_rounds=(int(stats_h[ST_DEDUP_ROUNDS])
                                   if counted else None),
+                    # v17: the rows the probe loop's rounds carried,
+                    # each round over a chunk of the wave's B*F rows.
+                    probe_slots=(int(stats_h[ST_PROBE_ROUNDS])
+                                 * probe_chunk(meta["bucket"] * self._F)
+                                 if counted else None),
                     host_s=launch_s + (now - t_proc) - waited,
                     # Frontier rows this dispatch consumed (the head
                     # advance) — the kernel-occupancy numerator.

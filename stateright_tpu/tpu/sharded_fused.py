@@ -60,7 +60,7 @@ from .engine import (compaction_order, dedup_and_insert, dedup_impl,
                      eval_properties, expand_frontier,
                      fingerprint_successors, first_occurrence_unscoped,
                      host_table_insert, matmul_expand, pick_bucket,
-                     sender_kernel_impl)
+                     probe_chunk, sender_kernel_impl)
 from .fused import (FusedTpuBfsChecker, ST_CAND, ST_DEDUP_ROUNDS, ST_DISC,
                     ST_ERR, ST_HEAD, ST_OCC, ST_PROBE_ROUNDS, ST_SUCC,
                     ST_TAIL, ST_TARGET, ST_WAVES, _pow2, _releasing)
@@ -650,6 +650,10 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                     # wait).
                     probe_rounds=int(stats_h[0, ST_PROBE_ROUNDS]),
                     dedup_rounds=int(stats_h[0, ST_DEDUP_ROUNDS]),
+                    # v17: the rows those rounds carried, each round
+                    # over a chunk of a shard's n*B*F received rows.
+                    probe_slots=int(stats_h[0, ST_PROBE_ROUNDS])
+                    * probe_chunk(n * meta["bucket"] * F),
                     host_s=launch_s + (now - t_proc) - waited,
                     # v16: successor rows sent to another shard, and the
                     # rows the all-to-alls carry between shards (each

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 import chip_smoke
 from benchmark.trace_stages import STAGES, stage_of
+from stateright_tpu.tpu import engine
 from two_phase_commit import TwoPhaseSys
 
 
@@ -88,13 +89,13 @@ FUSED = {"paxos3": (chip_smoke.paxos3, (4096, 1 << 22, 1 << 21)),
 
 #: sha256 of each cell's fused dispatch compiled for one described v5e
 #: chip, its text without metadata (``_hlo_digest``): the program as the
-#: sharded engine's telemetry found it. A change to the single-chip wave
-#: changes these on purpose.
+#: chunked probe left it. A change to the single-chip wave changes
+#: these on purpose.
 FUSED_HLO_SHA256 = {
-    "paxos3": ("e16fabd3fd7f48dec4f99eaf5f3b4617"
-                "460770d3fb703efdc6589f49aa35ea5f"),
-    "twopc10": ("4076037a45cbc28c979c155fff8c6627"
-                "49b3cad790281344cb813d1924ee6ff5"),
+    "paxos3": ("7fe0aeeed73766fad593b9e7f0941ebf"
+                "f0e6aa079a241b2bff47419b1448b64b"),
+    "twopc10": ("627d1e29c014eba15882a4b72148feaa"
+                "3fd2d4a42b62a3b54ff8274d60b588a9"),
 }
 
 #: the stack-frame tables at the head of a compiled module's text
@@ -134,8 +135,8 @@ def test_fused_dispatch_compiles(fused_programs, name):
 @pytest.mark.parametrize("name", sorted(FUSED_HLO_SHA256))
 def test_fused_dispatch_hlo_is_unchanged(fused_programs, name):
     """The single-chip cells' programs, modulo metadata, are the ones
-    the sharded engine's stage scopes and counters found: naming the
-    mesh wave's stages took nothing from the single-chip wave."""
+    the chunked probe made: a later change that was meant to leave the
+    single-chip wave alone shows here."""
     assert _hlo_digest(fused_programs[name].as_text()) == (
         FUSED_HLO_SHA256[name])
 
@@ -177,6 +178,22 @@ def _ops(text: str, kind: str) -> list:
                       + r'\([^\n]*?metadata=\{op_name="([^"]*)"', text)
 
 
+def _probe_loop_gather_rows(text: str) -> set:
+    """The leading dimension of every gather inside the ``probe``
+    scope's loops."""
+    return {int(rows) for rows, path in re.findall(
+        r"= [a-z0-9]+\[(\d+)[^\n]*? gather\([^\n]*?"
+        r'metadata=\{op_name="([^"]*)"', text)
+        if "probe/while" in path}
+
+
+def test_fused_probe_loop_carries_one_chunk(fused_programs):
+    """The twopc10-check wave's probe rounds gather a chunk of compacted
+    candidates, not the wave's B*F rows."""
+    assert _probe_loop_gather_rows(fused_programs["twopc10"].as_text()) == {
+        engine.PROBE_CHUNK}
+
+
 def test_twopc11_mesh_dispatch_fits_a_chip(twopc11_mesh_program):
     mem = twopc11_mesh_program.memory_analysis()
     # per chip: a 2^28-slot table slice and a 2^27-row arena slice
@@ -201,6 +218,13 @@ def test_twopc11_mesh_wave_has_one_local_dedup_loop(twopc11_mesh_program):
         "jit(local)/shard_map/while/body/local_dedup/while"]
     assert any("exchange" in p.split("/") and stage_of(p) is None
                for p in loops)
+
+
+def test_twopc11_mesh_probe_loop_carries_one_chunk(twopc11_mesh_program):
+    """An owner's probe rounds gather a chunk of its received rows'
+    candidates, not all n*B*F of them."""
+    assert _probe_loop_gather_rows(twopc11_mesh_program.as_text()) == {
+        engine.PROBE_CHUNK}
 
 
 def test_twopc11_mesh_all_to_alls_sit_in_exchange(twopc11_mesh_program):

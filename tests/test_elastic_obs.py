@@ -334,7 +334,9 @@ def _worker_wave(worker, seq, run="rw", **kw):
                    "host_s": None,
                    # v16 shard-exchange counts (null without an
                    # exchange).
-                   "exchange_rows": None, "exchange_slots": None})
+                   "exchange_rows": None, "exchange_slots": None,
+                   # v17 probe slots (null where the rounds are).
+                   "probe_slots": None})
     fields.update(kw)
     return json.dumps(fields)
 
@@ -371,9 +373,30 @@ def test_lint_elastic_wave_requires_attribution():
                 "io_stall_s", "expand_impl",
                 "cost_flops", "cost_bytes", "cost_ratio",
                 "probe_rounds", "dedup_rounds", "host_s",
-                "exchange_rows", "exchange_slots"):
+                "exchange_rows", "exchange_slots", "probe_slots"):
         old.pop(key, None)
     _, errors = trace_lint.lint_lines([json.dumps(old)])
+    assert not errors, errors
+
+
+@pytest.mark.parametrize("rounds,slots,ok", [
+    (None, None, True), (3, 3 * 64, True), (0, 0, True),
+    (3, None, False), (None, 64, False), (3, 2, False)])
+def test_lint_probe_slots_match_rounds(rounds, slots, ok):
+    """v17: probe slots are counted where the probe's rounds are, and
+    every counted round carries at least one row."""
+    line = _worker_wave("w0", 1, probe_rounds=rounds, probe_slots=slots)
+    _, errors = trace_lint.lint_lines([line])
+    assert (not errors) == ok, errors
+
+
+def test_lint_v16_wave_has_no_probe_slots():
+    line = json.loads(_worker_wave("w0", 1))
+    line["schema_version"] = 16
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
+    assert any("probe_slots" in e for e in errors), errors
+    del line["probe_slots"]
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert not errors, errors
 
 

@@ -27,6 +27,7 @@ sys.path.insert(0, os.path.join(
     "examples"))
 
 from stateright_tpu.obs import NULL_TRACER  # noqa: E402
+from stateright_tpu.tpu.engine import probe_chunk  # noqa: E402
 from stateright_tpu.tpu.hashing import SENTINEL  # noqa: E402
 from two_phase_commit import TwoPhaseSys  # noqa: E402
 
@@ -66,9 +67,13 @@ def test_dispatch_counts_loop_rounds():
     for e in entries:
         for key in ("probe_rounds", "dedup_rounds"):
             assert 1 <= e[key] <= e["candidates"], (key, e)
+        # every probe round carries one chunk of the wave's rows
+        assert e["probe_slots"] == e["probe_rounds"] * probe_chunk(
+            e["bucket"] * c._F), e
+        assert e["candidates"] <= e["probe_slots"], e
     # a no-op dispatch launched past a rest point ran no loop
-    assert all(e["probe_rounds"] == e["dedup_rounds"] == 0
-               for e in c.dispatch_log if not e["waves"])
+    assert all(e["probe_rounds"] == e["dedup_rounds"] == e["probe_slots"]
+               == 0 for e in c.dispatch_log if not e["waves"])
     assert all(e["host_s"] >= 0 for e in c.dispatch_log)
 
 
@@ -146,7 +151,11 @@ def test_sharded_dispatch_counts_rounds_and_exchange(mesh_pair):
         # a shard sends at most its B*F successors into (n-1)*B*F slots
         assert 0 < e["exchange_rows"] <= e["exchange_slots"] // (n - 1)
         assert e["exchange_rows"] <= e["candidates"]
-    assert all(e["probe_rounds"] == e["dedup_rounds"]
+        # the slowest shard's rounds, each over a chunk of its n*B*F
+        # received rows
+        assert e["probe_slots"] == e["probe_rounds"] * probe_chunk(
+            n * e["bucket"] * c._F), e
+    assert all(e["probe_rounds"] == e["dedup_rounds"] == e["probe_slots"]
                == e["exchange_rows"] == e["exchange_slots"] == 0
                for e in c.dispatch_log if not e["waves"])
     assert all(e["host_s"] >= 0 for e in c.dispatch_log)

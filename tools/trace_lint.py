@@ -114,6 +114,10 @@ level-triggered spam), with ``kept`` equal to the ``rung`` actually
 reported and never exceeding ``requested`` (the round-10
 requested/kept honesty rule applied to degradation steps).
 
+Schema v17 (the chunked probe) adds ``probe_slots`` to wave events,
+null exactly where ``probe_rounds`` is and never below it: every
+counted round carries at least one row.
+
 Schema v6 (the tiered state store) adds three more: every FRONTIER
 ``spill`` is eventually followed by a ``page_in`` or the producing
 run's end (a stream that stops with paged-out frontier blocks
@@ -582,6 +586,19 @@ def lint_lines(lines) -> Tuple[Dict[str, int], List[str]]:
                             errors.append(
                                 f"line {lineno}: elastic coordinator "
                                 f"wave without {field!r}")
+            # v17: the probe's slots are counted where its rounds are,
+            # and every round carries at least one row.
+            rounds, slots = obj.get("probe_rounds"), obj.get("probe_slots")
+            if (isinstance(obj.get("schema_version"), int)
+                    and obj["schema_version"] >= 17
+                    and ((rounds is None) != (slots is None)
+                         or (isinstance(rounds, int)
+                             and isinstance(slots, int)
+                             and slots < rounds))):
+                errors.append(
+                    f"line {lineno}: wave probe_slots {slots!r} against "
+                    f"probe_rounds {rounds!r}: each counted round "
+                    "carries at least one row")
             # v9 attribution window (wave multiplexing): a TOTAL mux
             # wave (job_id null, jobs_in_wave set) opens a window that
             # exactly jobs_in_wave attributed lines must close, their
