@@ -57,25 +57,6 @@ Env knobs:
                        (with BENCH_CLIENTS=4 + BENCH_LIVENESS=1 this is
                        BASELINE.json config 5; the native baseline
                        switches to the symmetry-capable compiled DFS)
-  BENCH_WAVE_KERNEL    1 runs the single-kernel wave megakernel
-                       (expand->fingerprint->dedup->insert fused into
-                       one pallas_call per wave; interpret mode on
-                       CPU), 0 forces the XLA ladder; unset follows
-                       the engine default. RESULT records the active
-                       kernel_path + waves_per_round_trip either way.
-  BENCH_TABLE_IMPL     visited-table impl: xla (default) | pallas
-                       (the VMEM-staged probe kernel, pallas_table.py —
-                       the on-TPU A/B of the round-5 plan)
-  BENCH_WAVE_MATMUL    1 compiles the headline model's successor
-                       generation to matmul form (tpu/matmul_wave.py;
-                       irregular models gate to the step path and the
-                       RESULT wave_matmul block says why), 0 forces the
-                       vmapped step; unset follows the engine default
-  BENCH_MATMUL_AB      1 adds the matmul-wave A/B stage: interleaved
-                       knob-on/knob-off runs of a regular 2pc workload
-                       GATED on counts/discoveries/checkpoint-bytes
-                       identity, with per-arm expand wall clock and
-                       kernel_path attribution under RESULT["matmul_ab"]
   BENCH_PROF           1 arms the continuous wave profiler
                        (STpu_PROF=1) for every engine the bench spawns
                        — XLA cost-model capture per compiled program
@@ -495,7 +476,6 @@ def _tpu_bfs(model, batch, table_capacity, cap=None, deadline=None,
             max_batch_size=max_batch,
             table_capacity=table_capacity,
             arena_capacity=table_capacity // 2,
-            table_impl=os.environ.get("BENCH_TABLE_IMPL", "xla"),
             checkpoint_path=checkpoint_path,
             checkpoint_every_waves=int(
                 os.environ.get("BENCH_CKPT_EVERY", "64")),
@@ -505,19 +485,6 @@ def _tpu_bfs(model, batch, table_capacity, cap=None, deadline=None,
             # the CPU fallback); 1/0 force either arm.
             pack_arena=(None if "BENCH_PACK_ARENA" not in os.environ
                         else os.environ["BENCH_PACK_ARENA"] != "0"),
-            # Single-kernel wave A/B knob (round 15): unset follows the
-            # engine default (STpu_WAVE_KERNEL env, else off); 1/0
-            # force either arm. Bit-identical either way — the parity
-            # gate holds whichever arm the headline ran.
-            wave_kernel=(None if "BENCH_WAVE_KERNEL" not in os.environ
-                         else os.environ["BENCH_WAVE_KERNEL"] != "0"),
-            # Matmul-form expansion A/B knob (round 19): unset follows
-            # the engine default (STpu_WAVE_MATMUL env, else off); 1/0
-            # force either arm. Irregular models gate back to the step
-            # path with identical results — the RESULT wave_matmul
-            # block records which implementation actually ran.
-            wave_matmul=(None if "BENCH_WAVE_MATMUL" not in os.environ
-                         else os.environ["BENCH_WAVE_MATMUL"] != "0"),
             fused=fused)
 
     def run(checker):
@@ -645,23 +612,6 @@ def _hoist_succ_telemetry(scheduler: dict) -> None:
         RESULT["tier_store"] = store
         RESULT["tier_spill_bytes"] = store.get("spill_bytes")
         RESULT["tier_resident_ratio"] = store.get("resident_ratio")
-    wk = scheduler.get("wave_kernel")
-    if isinstance(wk, dict):
-        # Single-kernel wave (ISSUE 10): the active successor-path
-        # implementation and the device-loop cadence, hoisted so every
-        # A/B run is attributable to the path it actually executed
-        # (megakernel / pallas_probe / xla / interpret).
-        RESULT["wave_kernel"] = wk
-        RESULT["kernel_path"] = wk.get("path")
-        RESULT["waves_per_round_trip"] = wk.get("waves_per_round_trip")
-    wm = scheduler.get("wave_matmul")
-    if isinstance(wm, dict):
-        # Matmul-form expansion (ISSUE 15): which expand implementation
-        # the wave programs embedded (matmul vs vmapped step), the gate
-        # reason, and the compiled plan's static MAC count — hoisted so
-        # every A/B run is attributable without digging.
-        RESULT["wave_matmul"] = wm
-        RESULT["expand_impl"] = wm.get("expand_impl")
     pr = scheduler.get("prof")
     if isinstance(pr, dict):
         # Continuous profiler (ISSUE 18, BENCH_PROF=1): the headline
@@ -832,98 +782,6 @@ def _stage_async_io(platform):
         "tier", tier_device_bytes=40_000, tier_host_bytes=4096,
         tier_dir=seg_dir)
     RESULT["async_io"] = out
-
-
-def _stage_matmul_ab(platform):
-    """The matmul-wave A/B arm (``BENCH_MATMUL_AB=1``): interleaved
-    knob-on/knob-off full enumerations of a regular 2pc workload,
-    GATING on counts/discoveries/parent-map/checkpoint BYTES identity
-    across arms and reporting per-arm wall clock with kernel_path
-    attribution proving which expand implementation each arm actually
-    executed. Interleaved (on, off, on, off, ...) so both arms sample
-    the same thermal/cache drift — the 2-core-box noise discipline
-    every A/B in this bench follows. Fills ``RESULT["matmul_ab"]``; a
-    mismatch sets ``parity_failed``."""
-    import hashlib
-    import tempfile
-
-    from two_phase_commit import TwoPhaseSys
-
-    rms = int(os.environ.get("BENCH_MATMUL_AB_RMS", "5"))
-    reps = int(os.environ.get("BENCH_MATMUL_AB_REPS", "3"))
-    batch = int(os.environ.get("BENCH_MATMUL_AB_BATCH", "512"))
-    model = TwoPhaseSys(rms)
-    work = tempfile.mkdtemp(prefix="stpu-matmul-ab-")
-
-    def run(arm, on):
-        path = os.path.join(work, f"{arm}.ckpt")
-        for stale in (path, path + ".prev"):
-            if os.path.exists(stale):
-                os.remove(stale)
-        t0 = time.monotonic()
-        c = model.checker().spawn_tpu_bfs(
-            batch_size=batch, table_capacity=1 << 16, fused=True,
-            wave_matmul=on, checkpoint_path=path)
-        c.join()
-        wall = time.monotonic() - t0
-        digest = hashlib.sha256(open(path, "rb").read()).hexdigest()
-        ident = (c.state_count(), c.unique_state_count(),
-                 tuple(sorted(c.discoveries())), digest)
-        return ident, wall, c.scheduler_stats()["wave_matmul"], \
-            c.kernel_path(), _steady_rate(c)
-
-    walls = {True: [], False: []}
-    rates = {True: [], False: []}
-    idents = {}
-    stats_by_arm = {}
-    for _ in range(max(1, reps)):
-        for on in (True, False):
-            ident, wall, wm, path, rate = run(
-                "on" if on else "off", on)
-            walls[on].append(wall)
-            rates[on].append(rate)
-            stats_by_arm[on] = (wm, path)
-            prev = idents.setdefault(on, ident)
-            if prev != ident:
-                raise AssertionError(
-                    f"matmul_ab: non-deterministic arm "
-                    f"(wave_matmul={on})")
-    # Attribution: recorded == executed. The on-arm must have actually
-    # run the compiled plan (2pc IS regular) and say so everywhere.
-    wm_on, path_on = stats_by_arm[True]
-    wm_off, path_off = stats_by_arm[False]
-    assert wm_on["active"] and wm_on["expand_impl"] == "matmul", wm_on
-    assert path_on.endswith("+matmul"), path_on
-    assert not wm_off["enabled"] and not path_off.endswith("+matmul")
-    out = {"workload": f"2pc check {rms}", "reps": reps,
-           "batch": batch}
-    # Checkpoint digests embed the table (identical), not timestamps;
-    # dropping it from the reported tuple keeps the json lean.
-    if idents[True] != idents[False]:
-        _PARITY["status"] = "failed"
-        RESULT["parity_failed"] = True
-        RESULT["matmul_ab"] = dict(out, match=False)
-        raise AssertionError(
-            f"matmul_ab mismatch: on={idents[True][:3]} "
-            f"off={idents[False][:3]} ckpt_sha "
-            f"on={idents[True][3][:12]} off={idents[False][3][:12]}")
-    for on in (True, False):
-        arm = "matmul" if on else "step"
-        out[arm] = {
-            "wall_s": round(min(walls[on]), 3),
-            "states_per_sec": round(max(rates[on]), 1),
-            "kernel_path": stats_by_arm[on][1],
-        }
-    out.update({
-        "match": True,
-        "states": idents[True][0],
-        "unique": idents[True][1],
-        "matmul_ops_per_row": wm_on["matmul_ops"],
-        "reason": wm_on["reason"],
-        "speedup": round(out["matmul"]["states_per_sec"]
-                         / max(out["step"]["states_per_sec"], 1e-9), 3),
-    })
-    RESULT["matmul_ab"] = out
 
 
 def _stage_headline(platform):
@@ -1426,8 +1284,6 @@ def main() -> None:
         stages = stages + (_stage_tier_drill,)
     if os.environ.get("BENCH_ASYNC_IO") == "1":
         stages = stages + (_stage_async_io,)
-    if os.environ.get("BENCH_MATMUL_AB") == "1":
-        stages = stages + (_stage_matmul_ab,)
     if int(os.environ.get("BENCH_SERVICE_JOBS", "0") or 0) > 0:
         stages = stages + (_stage_service,)
     if int(os.environ.get("BENCH_SOAK_JOBS", "0") or 0) > 0:

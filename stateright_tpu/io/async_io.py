@@ -39,7 +39,7 @@ import threading
 import time
 from typing import Callable, Dict, Optional
 
-#: env knob (wave_kernel precedent): unset/""/"0" = off, anything else on.
+#: env knob: unset/""/"0" = off, anything else on.
 ASYNC_IO_ENV = "STpu_ASYNC_IO"
 
 
@@ -49,7 +49,7 @@ def async_io_from_env() -> bool:
 
 
 def resolve_async_io(knob: Optional[bool]) -> bool:
-    """kwarg > env (wave_kernel-knob precedent)."""
+    """kwarg > env."""
     return async_io_from_env() if knob is None else bool(knob)
 
 

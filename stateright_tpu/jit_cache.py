@@ -47,10 +47,10 @@ class WaveProgramCache:
     (the corpus registry name + canonical params) — two engines may
     share a program only when their device models are semantically
     identical, which is exactly what a registry key certifies. Ad-hoc
-    models (no registry key) never reach this cache. Path-selection
-    knobs ride in the key too (``table_impl``, ``pack_arena``,
-    ``wave_kernel``): a megakernel program and an XLA-ladder program
-    are different executables even at identical shapes.
+    models (no registry key) never reach this cache. The knobs that
+    change the traced program ride in the key too (``pack_arena`` and
+    symmetry): a packed-row program and a raw-row program are different
+    executables even at identical shapes.
 
     ``get_or_build`` holds a per-key lock across the build, so N
     concurrent same-model jobs pay ONE compile and N-1 hits instead of

@@ -44,7 +44,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional, Tuple
 
-from .schema import SCHEMA_VERSION
+from .schema import SCHEMA_VERSION, WAVE_NULL_DEFAULTS
 
 __all__ = ["RelayTracer", "TraceCollector"]
 
@@ -162,31 +162,9 @@ class RelayTracer:
 
     def wave(self, fields: dict) -> None:
         evt = dict(fields, type="wave")
-        for key in ("epoch", "round",
-                    # v6 tier gauges: null outside a tiered-store run.
-                    "tier_device_rows", "tier_device_bytes",
-                    "tier_host_rows", "tier_host_bytes",
-                    "tier_disk_rows", "tier_disk_bytes",
-                    "kernel_path", "rows",
-                    # v9 mux attribution: null outside a mux group.
-                    "job_id", "jobs_in_wave",
-                    # v10 async-I/O stall gauge: null where not tracked.
-                    "io_stall_s",
-                    # v12 expand-stage attribution: null on producers
-                    # without a device wave.
-                    "expand_impl",
-                    # v13 cost attribution: null when the profiler is
-                    # disarmed / the program has no cost model /
-                    # the dispatch was not sampled.
-                    "cost_flops", "cost_bytes", "cost_ratio",
-                    # v15 loop rounds and host seconds: null where
-                    # not counted.
-                    "probe_rounds", "dedup_rounds", "host_s",
-                    # v16 shard-exchange counts: null on producers
-                    # without an exchange.
-                    "exchange_rows", "exchange_slots",
-                    # v17 probe slots: null where the rounds are.
-                    "probe_slots"):
+        for key in WAVE_NULL_DEFAULTS:
+            if key in ("worker", "seq"):
+                continue  # _push stamps them
             evt.setdefault(key, None)
         self._push(evt, number_wave=True)
 

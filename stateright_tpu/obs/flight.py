@@ -49,7 +49,7 @@ import weakref
 from collections import deque
 from typing import Optional
 
-from .schema import SCHEMA_VERSION
+from .schema import SCHEMA_VERSION, WAVE_NULL_DEFAULTS
 
 __all__ = [
     "FLIGHT_ENV", "FLIGHT_DIR_ENV", "FLIGHT_CAPACITY", "FlightRecorder",
@@ -181,31 +181,7 @@ class FlightRecorder:
                "engine": "flight", "run": f"flight-{self.name}",
                "wave": i}
         out.update(evt)
-        for key in ("worker", "seq", "epoch", "round",
-                    # v6 tier gauges: null outside a tiered-store run.
-                    "tier_device_rows", "tier_device_bytes",
-                    "tier_host_rows", "tier_host_bytes",
-                    "tier_disk_rows", "tier_disk_bytes",
-                    "kernel_path", "rows",
-                    # v9 mux attribution: null outside a mux group.
-                    "job_id", "jobs_in_wave",
-                    # v10 async-I/O stall gauge: null where not tracked.
-                    "io_stall_s",
-                    # v12 expand-stage attribution: null on producers
-                    # without a device wave.
-                    "expand_impl",
-                    # v13 cost attribution: null when the profiler is
-                    # disarmed / the program has no cost model /
-                    # the dispatch was not sampled.
-                    "cost_flops", "cost_bytes", "cost_ratio",
-                    # v15 loop rounds and host seconds: null where
-                    # not counted.
-                    "probe_rounds", "dedup_rounds", "host_s",
-                    # v16 shard-exchange counts: null on producers
-                    # without an exchange.
-                    "exchange_rows", "exchange_slots",
-                    # v17 probe slots: null where the rounds are.
-                    "probe_slots"):
+        for key in WAVE_NULL_DEFAULTS:
             out.setdefault(key, None)
         return out
 

@@ -2,9 +2,7 @@
 
 The obs stack through round 19 can say *how fast* a run went (wave
 events, latency histograms, SLOs) but not *why*: no compiled program
-records its FLOP/byte cost, so the matmul-vs-step question at the heart
-of ROADMAP item 2 can only be answered by hand. This module closes the
-gap in three parts:
+records its FLOP/byte cost. This module closes the gap in three parts:
 
 1. **Static cost capture.** Every program built through the engines'
    ``_cached_program`` funnel records its XLA cost model at compile

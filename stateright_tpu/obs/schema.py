@@ -36,7 +36,7 @@ __all__ = [
     "WAVE_FIELDS_V5", "WAVE_FIELDS_V6", "WAVE_FIELDS_V8",
     "WAVE_FIELDS_V9", "WAVE_FIELDS_V11", "WAVE_FIELDS_V12",
     "WAVE_FIELDS_V14", "WAVE_FIELDS_V15", "WAVE_FIELDS_V16",
-    "validate_event", "validate_line",
+    "WAVE_NULL_DEFAULTS", "validate_event", "validate_line",
 ]
 
 #: v14: the closed vocabulary a ``shed`` event's ``reason`` must come
@@ -309,9 +309,9 @@ WAVE_FIELDS: Dict[str, tuple] = {
     "tier_host_bytes": _INT + (_NULL,),
     "tier_disk_rows": _INT + (_NULL,),
     "tier_disk_bytes": _INT + (_NULL,),
-    # v8: single-kernel-wave attribution. ``kernel_path`` names the
-    # successor-path implementation the dispatch executed; ``rows`` is
-    # the valid frontier rows it consumed (occupancy numerator). Both
+    # v8: ``kernel_path`` names the successor path the dispatch ran,
+    # "xla" (the op ladder) on every device engine; ``rows`` is the
+    # valid frontier rows it consumed (occupancy numerator). Both
     # ``null`` on producers without a device wave.
     "kernel_path": _STR + (_NULL,),
     "rows": _INT + (_NULL,),
@@ -326,10 +326,9 @@ WAVE_FIELDS: Dict[str, tuple] = {
     # the background writer + synchronous write time). ``null`` where
     # not tracked (meta-producers, relayed historical streams).
     "io_stall_s": _NUM + (_NULL,),
-    # v12: which expand-stage implementation the dispatch's wave
-    # program embeds: "matmul" (the compiled transition-table form,
-    # ISSUE 15) or "step" (the vmapped DeviceModel.step). ``null`` on
-    # producers without a device wave.
+    # v12: the expand stage the dispatch's wave program embeds, "step"
+    # (the vmapped DeviceModel.step) on every device engine. ``null``
+    # on producers without a device wave.
     "expand_impl": _STR + (_NULL,),
     # v13: continuous-profiler cost attribution (obs/prof.py). The
     # executed program's static XLA cost model (``null`` when the
@@ -353,6 +352,38 @@ WAVE_FIELDS: Dict[str, tuple] = {
     # are not counted).
     "probe_slots": _INT + (_NULL,),
 }
+
+#: The wave keys a producer stamps ``null`` where its entry carries no
+#: value, in stamping order — one list for the three stamping sites
+#: (``RunTracer.wave``, ``RelayTracer.wave``, ``FlightRecorder``), so
+#: no engine needs a per-engine field set. A relay stamps ``worker``
+#: and ``seq`` itself.
+WAVE_NULL_DEFAULTS = (
+    # v5 attribution: null outside the elastic runtime.
+    "worker", "seq", "epoch", "round",
+    # v6 tier gauges: null outside a tiered-store run.
+    "tier_device_rows", "tier_device_bytes",
+    "tier_host_rows", "tier_host_bytes",
+    "tier_disk_rows", "tier_disk_bytes",
+    # v8 successor path and rows: null on producers without a device
+    # wave (host checkers, elastic coordinator).
+    "kernel_path", "rows",
+    # v9 mux attribution: null on solo-engine waves.
+    "job_id", "jobs_in_wave",
+    # v10 async-I/O stall gauge: null where not tracked.
+    "io_stall_s",
+    # v12 expand stage: null on producers without a device wave.
+    "expand_impl",
+    # v13 cost attribution: null when the profiler is disarmed / the
+    # program has no cost model / the dispatch was not sampled.
+    "cost_flops", "cost_bytes", "cost_ratio",
+    # v15 loop rounds and host seconds: null where not counted.
+    "probe_rounds", "dedup_rounds", "host_s",
+    # v16 shard-exchange counts: null on producers without an exchange.
+    "exchange_rows", "exchange_slots",
+    # v17 probe slots: null where the rounds are.
+    "probe_slots",
+)
 
 #: v5 attribution keys (absent from v2-v4 wave events).
 _WAVE_V5_KEYS = ("worker", "seq", "epoch", "round")

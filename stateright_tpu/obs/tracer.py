@@ -49,7 +49,7 @@ import time
 from contextlib import contextmanager
 from typing import Optional
 
-from .schema import SCHEMA_VERSION, TRACE_ENV
+from .schema import SCHEMA_VERSION, TRACE_ENV, WAVE_NULL_DEFAULTS
 
 __all__ = ["RunTracer", "NullTracer", "NULL_TRACER", "tracer_from_env"]
 
@@ -180,37 +180,10 @@ class RunTracer:
         """Emits one wave event. ``fields`` is the engine's unified
         dispatch-log entry (see ``schema.WAVE_FIELDS``); the tracer
         stamps type/version/engine/run, numbers the wave, and defaults
-        the v5 attribution keys — one stamping site instead of four
-        per-engine field-set edits (engines that HAVE a value, the
-        elastic runtime, set it in their entry)."""
+        ``schema.WAVE_NULL_DEFAULTS`` to null (engines that HAVE a
+        value, the elastic runtime, set it in their entry)."""
         evt = dict(fields, type="wave")
-        for key in ("worker", "seq", "epoch", "round",
-                    # v6 tier gauges: null outside a tiered-store run.
-                    "tier_device_rows", "tier_device_bytes",
-                    "tier_host_rows", "tier_host_bytes",
-                    "tier_disk_rows", "tier_disk_bytes",
-                    # v8 kernel-path keys: null on producers without a
-                    # device wave (host checkers, elastic coordinator).
-                    "kernel_path", "rows",
-                    # v9 mux attribution: null on solo-engine waves.
-                    "job_id", "jobs_in_wave",
-                    # v10 async-I/O stall gauge: null where not tracked.
-                    "io_stall_s",
-                    # v12 expand-stage attribution: null on producers
-                    # without a device wave.
-                    "expand_impl",
-                    # v13 cost attribution: null when the profiler is
-                    # disarmed / the program has no cost model /
-                    # the dispatch was not sampled.
-                    "cost_flops", "cost_bytes", "cost_ratio",
-                    # v15 loop rounds and host seconds: null where
-                    # not counted.
-                    "probe_rounds", "dedup_rounds", "host_s",
-                    # v16 shard-exchange counts: null on producers
-                    # without an exchange.
-                    "exchange_rows", "exchange_slots",
-                    # v17 probe slots: null where the rounds are.
-                    "probe_slots"):
+        for key in WAVE_NULL_DEFAULTS:
             evt.setdefault(key, None)
         self._write(evt, number_wave=True)
 

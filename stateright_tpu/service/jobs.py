@@ -67,22 +67,12 @@ _KNOBS = {
     "target_state_count": int,
     "checkpoint_every_waves": int,
     "waves_per_dispatch": int,
-    "table_impl": str,
     "pack_arena": bool,
     "succ_ladder": bool,
-    # Single-kernel wave (round 15): tenants may A/B the megakernel;
-    # bit-identical either way, and the shared program cache keys on
-    # it, so mixed-knob jobs never share the wrong executable.
-    "wave_kernel": bool,
     # Background host I/O (round 17): bit-identical either way; the
     # mux shape key includes it, so mixed-knob jobs never share a
     # group with the wrong writer policy.
     "async_io": bool,
-    # Matmul-form expand (round 19): tenants may A/B the compiled
-    # transition-table path; bit-identical either way (irregular
-    # models gate to the step path), and the shared program cache
-    # keys on the resolved plan.
-    "wave_matmul": bool,
 }
 
 _ENGINES = ("classic", "fused", "host")

@@ -89,15 +89,7 @@ class DeviceModel:
         widths are part of the encoding contract, like injectivity: a
         value beyond its lane's width would be silently truncated in
         the packed arena. ``None`` (the conservative default) means 32
-        bits per lane — the engines then store rows unpacked.
-
-        The declared widths also bound the matmul-wave transition
-        compiler (``tpu/matmul_wave.py``): lane domains come straight
-        from these bits, so only models with small plain-int lanes (no
-        sentinels, every lane within ``LANE_DOMAIN_CAP``) are
-        candidates for the compiled matmul expand path — the same
-        declaration feeds both the packed arena and the regularity
-        gate."""
+        bits per lane — the engines then store rows unpacked."""
         return None
 
     def boundary(self, vec) -> Optional[object]:

@@ -58,11 +58,10 @@ from ..resilience.faults import fault_plan_from_env, is_oom
 from ..store.tiered import FrontierRef, store_from_config
 from .device_model import DeviceModel
 from .hashing import SENTINEL, device_fp64, host_fp64
-from .matmul_wave import matmul_expand
 
 __all__ = ["TpuBfsChecker", "build_wave", "build_mux_wave",
            "build_regather", "batch_bucket_ladder", "pick_bucket",
-           "succ_bucket_ladder", "wave_kernel_impl"]
+           "succ_bucket_ladder"]
 
 
 def batch_bucket_ladder(base: int, max_batch: Optional[int]) -> tuple:
@@ -138,12 +137,6 @@ class TpuBfsChecker(Checker):
     #: in-place aliasing, see fused.py — and opt out).
     _SUCC_LADDER_CAPABLE = True
 
-    #: whether this engine's single-kernel wave is the table-less
-    #: SENDER megakernel (the sharded engines: the visited table is
-    #: partitioned across the mesh, so the probe stays owner-side and
-    #: the kernel-path gate drops the table term).
-    _SENDER_KERNEL = False
-
     #: whether jobs targeting this engine shape can be admitted into a
     #: shared multiplexed wave group (service/mux.py). Requires the
     #: per-wave host boundary: the mux splits every wave's outputs per
@@ -171,7 +164,6 @@ class TpuBfsChecker(Checker):
                  checkpoint_every_waves: int = 64,
                  resume_from: Optional[str] = None,
                  pipeline: Optional[bool] = None,
-                 table_impl: str = "xla",
                  max_batch_size: Optional[int] = None,
                  succ_ladder: Optional[bool] = None,
                  pack_arena: Optional[bool] = None,
@@ -182,8 +174,6 @@ class TpuBfsChecker(Checker):
                  program_cache=None,
                  program_key: Optional[tuple] = None,
                  trace_path: Optional[str] = None,
-                 wave_kernel: Optional[bool] = None,
-                 wave_matmul: Optional[bool] = None,
                  async_io: Optional[bool] = None):
         # Before this process's first compile: JAX decides once whether
         # the persistent cache is in use (jit_cache.py).
@@ -267,63 +257,6 @@ class TpuBfsChecker(Checker):
             pack_arena = jax.default_backend() != "cpu"
         self._pack_on = bool(pack_arena) and self._layout.packs
         self._Wrow = self._layout.packed_width if self._pack_on else self._W
-        if table_impl not in ("xla", "pallas"):
-            raise ValueError(f"table_impl must be 'xla' or 'pallas', "
-                             f"got {table_impl!r}")
-        self._table_impl = table_impl
-        # Single-kernel wave (ISSUE 10): run the whole successor path —
-        # unpack, expand, fingerprint, local dedup, global probe/claim,
-        # re-pack — as one Pallas megakernel per wave instead of the
-        # XLA op ladder. Unset follows the STpu_WAVE_KERNEL env knob;
-        # the VMEM budget gate is re-checked per wave-program build, so
-        # mid-run growth degrades to the XLA path (once-warned) instead
-        # of killing the run. Bit-identical either way (the kernel
-        # traces the same stage functions; tests/test_wave_kernel.py).
-        if wave_kernel is None:
-            wave_kernel = os.environ.get(
-                "STpu_WAVE_KERNEL", "") not in ("", "0")
-        self._wave_kernel_on = bool(wave_kernel)
-        if self._wave_kernel_on or table_impl == "pallas":
-            from .pallas_table import PALLAS_AVAILABLE, refuse_on_tpu
-
-            refuse_on_tpu("wave_kernel=True" if self._wave_kernel_on
-                          else "table_impl='pallas'")
-        if self._wave_kernel_on:
-            if not PALLAS_AVAILABLE:
-                warnings.warn(
-                    "wave_kernel requested but pallas is unavailable "
-                    "in this jax build; using the XLA wave path",
-                    RuntimeWarning)
-                self._wave_kernel_on = False
-        # MXU-shaped successor generation (ISSUE 15): compile a
-        # *regular* model's expand stage to one-hot x transition-table
-        # matmuls (tpu/matmul_wave.py) and swap it in wherever the wave
-        # programs call expand_frontier — including inside the
-        # megakernel. Unset follows the STpu_WAVE_MATMUL env knob. The
-        # capability gate keeps irregular models (undeclared lane_bits,
-        # sentinel lanes, oversized key domains) on the vmapped step
-        # path and reports why through scheduler_stats()["wave_matmul"].
-        # Bit-identical either way (tests/test_matmul_wave.py).
-        if wave_matmul is None:
-            wave_matmul = os.environ.get(
-                "STpu_WAVE_MATMUL", "") not in ("", "0")
-        self._wave_matmul_on = bool(wave_matmul)
-        self._matmul_plan = None
-        self._matmul_reason = None
-        if self._wave_matmul_on:
-            from .matmul_wave import classify as matmul_classify
-
-            cls = matmul_classify(device_model)
-            self._matmul_plan = cls.plan
-            self._matmul_reason = cls.reason
-            if not cls.regular:
-                key = type(device_model).__name__
-                if key not in _WAVE_MATMUL_GATE_WARNED:
-                    _WAVE_MATMUL_GATE_WARNED.add(key)
-                    warnings.warn(
-                        f"wave_matmul requested but {key} is not "
-                        f"matmul-regular ({cls.reason}); using the "
-                        "vmapped step path", RuntimeWarning)
         # Successor-side output ladder (classic per-wave engines only:
         # the fused engines keep full-window arena appends — see
         # _SUCC_LADDER_CAPABLE). Results are K-independent (overflowed
@@ -383,9 +316,8 @@ class TpuBfsChecker(Checker):
         # cold-segment spills share it, so the safe-point join rule
         # (`_write_checkpoint` joins before capturing the next
         # snapshot) covers every off-thread write at once. Unset
-        # follows the STpu_ASYNC_IO env knob (wave_kernel precedent);
-        # knob-off is the inline SyncWriter and every path behaves
-        # exactly as before.
+        # follows the STpu_ASYNC_IO env knob; knob-off is the inline
+        # SyncWriter and every path behaves exactly as before.
         from ..io.async_io import writer_from_config
 
         self._aio = writer_from_config(
@@ -487,15 +419,8 @@ class TpuBfsChecker(Checker):
             "batch_size": self._B,
             "bucket_ladder": list(self._buckets),
             "table_capacity": self._capacity,
-            "table_impl": self._table_impl,
             "max_fanout": self._F,
             "state_width": self._W})
-        if self._tracer.enabled and self._matmul_plan is not None:
-            # Static per-frontier-row MAC count of the compiled plan
-            # (obs schema v12) — one gauge at run start; the per-wave
-            # attribution rides as the wave events' expand_impl.
-            self._tracer.event("gauge", name="matmul_ops",
-                               value=float(self._matmul_plan.matmul_ops))
         #: fault-injection plan (resilience subsystem): the live
         #: ``STpu_FAULTS`` plan, or the shared disarmed NULL_PLAN —
         #: every hook is guarded by ``.active``, so the unarmed
@@ -847,14 +772,8 @@ class TpuBfsChecker(Checker):
         if cached is not None:
             return cached
         if self._prog_cache is not None:
-            # wave_kernel rides in the shared key: a megakernel program
-            # and an XLA-ladder program are different executables even
-            # at identical shapes (the service's cross-job sharing must
-            # never hand one job the other's path).
             shared_key = (self._prog_key, self._ENGINE_ID,
-                          self._table_impl, self._pack_on,
-                          self._use_symmetry, self._wave_kernel_on,
-                          self._matmul_plan is not None) + key
+                          self._pack_on, self._use_symmetry) + key
             prog, hit = self._prog_cache.get_or_build(shared_key, build)
             if hit:
                 self._prog_hits += 1
@@ -879,9 +798,7 @@ class TpuBfsChecker(Checker):
         the instance key. Process-stable, so every engine instance of
         one model/config derives the same string and shared-cache hits
         find the first builder's cost record."""
-        prefix = (self._prog_key, self._table_impl, self._pack_on,
-                  self._use_symmetry, self._wave_kernel_on,
-                  self._matmul_plan is not None)
+        prefix = (self._prog_key, self._pack_on, self._use_symmetry)
         digest = hashlib.blake2s(repr(prefix).encode(),
                                  digest_size=4).hexdigest()
         return f"{self._ENGINE_ID}|{digest}|{key!r}"
@@ -895,11 +812,8 @@ class TpuBfsChecker(Checker):
 
         def build():
             jitted = build_wave(self._dm, B, capacity, self._prop_fns,
-                                self._use_symmetry,
-                                table_impl=self._table_impl, out_rows=K,
-                                layout=self._wave_layout(),
-                                wave_kernel=self._wave_kernel_on,
-                                matmul_plan=self._matmul_plan)
+                                self._use_symmetry, out_rows=K,
+                                layout=self._wave_layout())
             sds = jax.ShapeDtypeStruct
             return self._aot(jitted, (
                 sds((B, self._Wrow), jnp.uint32), sds((B,), jnp.bool_),
@@ -912,54 +826,10 @@ class TpuBfsChecker(Checker):
         rung (per shard on the sharded engine, which overrides this)."""
         return B * self._F
 
-    def _kernel_path(self, capacity: int, batch: int) -> str:
-        """Which successor-path implementation a wave program at this
-        (batch, capacity) resolves to — built from the SAME gate
-        predicates the program builders call (``wave_kernel_impl`` /
-        ``sender_kernel_impl``), so the recorded path is the executed
-        path: ``megakernel`` (the single-kernel wave, TPU lowering),
-        ``interpret`` (the same kernel in Pallas interpret mode —
-        correct, not fast; the CPU parity arm), ``pallas_probe`` (the
-        round-7 VMEM table kernel only), or ``xla`` (the op ladder).
-        The sharded engines set ``_SENDER_KERNEL`` (their megakernel is
-        the table-less per-shard sender; the probe stays owner-side, so
-        the pallas probe table never applies there)."""
-        from .matmul_wave import plan_bytes
-        from .pallas_table import (PALLAS_AVAILABLE, default_interpret,
-                                   pallas_table_capacity_ok,
-                                   sender_kernel_ok, wave_kernel_ok)
-
-        # wave_matmul rides every path as a "+matmul" suffix: the
-        # expand stage swaps implementation inside whichever program
-        # the other gates pick, so attribution must carry both axes.
-        suffix = "+matmul" if self._matmul_plan is not None else ""
-        extra = plan_bytes(self._matmul_plan, batch)
-        if self._wave_kernel_on and PALLAS_AVAILABLE:
-            ok = (sender_kernel_ok(batch, self._F, self._W, self._Wrow,
-                                   extra_bytes=extra)
-                  if self._SENDER_KERNEL
-                  else wave_kernel_ok(capacity, batch, self._F,
-                                      self._W, self._Wrow,
-                                      extra_bytes=extra))
-            if ok:
-                return ("interpret" if default_interpret()
-                        else "megakernel") + suffix
-        if (not self._SENDER_KERNEL and self._table_impl == "pallas"
-                and pallas_table_capacity_ok(capacity)):
-            return "pallas_probe" + suffix
-        return "xla" + suffix
-
     def kernel_path(self) -> str:
-        """The active kernel path at the current capacity and widest
-        dispatch bucket (per-dispatch values ride the wave events)."""
-        return self._kernel_path(self._capacity, self._B_max)
-
-    def _expand_impl(self) -> str:
-        """Which expand-stage implementation the wave programs embed:
-        ``matmul`` (the compiled transition-table form) or ``step``
-        (the vmapped ``DeviceModel.step`` path — also what an
-        irregular model falls back to with the knob on)."""
-        return "matmul" if self._matmul_plan is not None else "step"
+        """The successor path the wave programs run: the XLA op
+        ladder, ``"xla"`` (the wave events' ``kernel_path``)."""
+        return "xla"
 
     def _pick_out_rows(self, B: int) -> int:
         """Picks the output rung for the next wave at batch bucket
@@ -992,8 +862,7 @@ class TpuBfsChecker(Checker):
         def build():
             jitted = build_regather(self._dm, batch, out_rows,
                                     self._use_symmetry,
-                                    layout=self._wave_layout(),
-                                    matmul_plan=self._matmul_plan)
+                                    layout=self._wave_layout())
             sds = jax.ShapeDtypeStruct
             return self._aot(jitted, (
                 sds((batch, self._Wrow), jnp.uint32),
@@ -1020,7 +889,7 @@ class TpuBfsChecker(Checker):
         corrupting the steady-rate attribution. The compile cost is
         accounted in ``compile_sec`` instead. Falls back to the lazy
         jitted callable (interval-flagged via ``_note_compile``) where
-        lowering is unsupported (e.g. some pallas paths)."""
+        lowering is unsupported."""
         t0 = time.monotonic()
         try:
             with warnings.catch_warnings():
@@ -1053,11 +922,11 @@ class TpuBfsChecker(Checker):
         overflows = sum(1 for e in log if e["overflow"])
         # Kernel occupancy: frontier rows actually processed vs the
         # padded rows the wave programs dispatched (bucket width x BFS
-        # levels) — the figure the ladder's K choice and the megakernel
-        # A/Bs are judged against (a half-empty wave pays full kernel
-        # time either way). A zero-wave entry (a pipelined fused
-        # dispatch that no-opped at a rest point) contributes nothing
-        # to either side — it ran no kernel.
+        # levels) — the figure the ladder's K choice is judged against
+        # (a half-empty wave pays full kernel time either way). A
+        # zero-wave entry (a pipelined fused dispatch that no-opped at
+        # a rest point) contributes nothing to either side — it ran no
+        # kernel.
         rows_total = sum(e.get("rows") or 0 for e in log)
         # bucket is PER SHARD on the sharded engines while rows counts
         # every shard's valid slots, so the padded denominator scales
@@ -1091,29 +960,6 @@ class TpuBfsChecker(Checker):
                 "overflow_redispatches": overflows,
                 "occupancy": (round(rows_total / padded_total, 4)
                               if padded_total else 0.0),
-            },
-            # Single-kernel wave telemetry (ISSUE 10): which successor-
-            # path implementation the run dispatches, and how many BFS
-            # levels one host round-trip covers (the fused engines'
-            # device-resident multi-wave loop; 1 on the per-wave
-            # engines). Occupancy lives under succ_ladder — one
-            # canonical key, shared numerator/denominator.
-            "wave_kernel": {
-                "enabled": self._wave_kernel_on,
-                "path": self.kernel_path(),
-                "waves_per_round_trip": int(getattr(self, "_K", 1)),
-            },
-            # Matmul-form expand telemetry (ISSUE 15): whether the
-            # transition compiler classified the model regular, which
-            # implementation the programs embed, and the per-row MXU
-            # work the compiled plan carries (0 on the step path).
-            "wave_matmul": {
-                "enabled": self._wave_matmul_on,
-                "active": self._matmul_plan is not None,
-                "expand_impl": self._expand_impl(),
-                "reason": self._matmul_reason,
-                "matmul_ops": (self._matmul_plan.matmul_ops
-                               if self._matmul_plan is not None else 0),
             },
             "local_dedup": {
                 "successors": succ_total,
@@ -1402,8 +1248,8 @@ class TpuBfsChecker(Checker):
          self._visited) = outs
         meta = {"bucket": B, "inflight": inflight, "out_rows": K,
                 "rows": n,
-                "kernel_path": self._kernel_path(self._capacity, B),
-                "expand_impl": self._expand_impl()}
+                "kernel_path": "xla",
+                "expand_impl": "step"}
         if pkey is not None:
             # Internal riders for _process_wave — popped there before
             # the entry reaches the schema'd streams.
@@ -1604,7 +1450,7 @@ class TpuBfsChecker(Checker):
                 > capacity // 2)
 
     def _simulate_grow_capacity(self) -> int:
-        """The capacity ``_grow_table_impl`` would grow to right now —
+        """The capacity ``_resize_table`` would grow to right now —
         what the spill-vs-grow decision budgets against, and what a
         failed growth's ``degrade`` event records as ``requested``."""
         cap = self._capacity
@@ -1799,14 +1645,14 @@ class TpuBfsChecker(Checker):
                 if self._spill_for_headroom() \
                         and not self._needs_growth():
                     return
-                self._grow_table_impl()
+                self._resize_table()
             except Exception as e:  # noqa: BLE001 — non-OOM re-raised
                 self._handle_grow_failure(e)
                 if self._needs_growth():
                     continue
             return
 
-    def _grow_table_impl(self) -> None:
+    def _resize_table(self) -> None:
         real = np.asarray(self._visited)
         real = real[real != SENTINEL]
         old = self._capacity
@@ -1900,137 +1746,9 @@ class TpuBfsChecker(Checker):
         return self._done.is_set()
 
 
-#: capacities whose pallas->XLA degrade has already been announced —
-#: the warning fires once per capacity, not once per compiled (B, K)
-#: wave program (the successor ladder multiplies program builds).
-_PALLAS_DEGRADE_WARNED: set = set()
-
-
-def dedup_impl(table_impl: str, capacity: int):
-    """Resolves the visited-table implementation for a wave program:
-    ``"xla"`` (the while_loop probe over the HBM-resident table) or
-    ``"pallas"`` (the VMEM-staged kernel, ``pallas_table.py``). A pallas
-    request a capacity can't satisfy degrades to XLA with a warning
-    (once per capacity, not per compiled wave program) — mid-run table
-    growth must not kill a checker.
-
-    The returned function runs BOTH dedup levels —
-    ``fn(fps, visited) -> (new_mask, new_count, cand_count, merged,
-    rounds)``:
-    the intra-wave local collapse (``first_occurrence_candidates``)
-    first, then the global probe (``global_insert``) over the distinct
-    survivors only, with ``cand_count`` (how many candidates reached
-    the global probe) surfaced for the collapse-ratio telemetry, and
-    ``rounds``, the int32 scalars ``(local dedup rounds, probe rounds)``
-    of the two XLA loops (the Pallas table counts neither and gives
-    zeros)."""
-    if table_impl == "pallas":
-        from .pallas_table import (dedup_and_insert_pallas,
-                                   pallas_table_capacity_ok)
-
-        if pallas_table_capacity_ok(capacity):
-            return lambda fps, visited: dedup_and_insert_pallas(
-                fps, visited, capacity) + ((jnp.int32(0),) * 2,)
-        if capacity not in _PALLAS_DEGRADE_WARNED:
-            _PALLAS_DEGRADE_WARNED.add(capacity)
-            warnings.warn(
-                f"pallas visited table unavailable at capacity "
-                f"{capacity} (VMEM budget or pallas missing); using "
-                "the XLA table", RuntimeWarning)
-
-    def xla(fps, visited):
-        candidate, local_rounds = first_occurrence_counted(fps)
-        cand_count = jnp.sum(candidate, dtype=jnp.int32)
-        new_mask, new_count, merged, probe_rounds = global_insert_counted(
-            fps, candidate, visited, capacity)
-        return (new_mask, new_count, cand_count, merged,
-                (local_rounds, probe_rounds))
-
-    return xla
-
-
-#: (batch, capacity) shapes whose megakernel->XLA degrade has already
-#: been announced — once per shape, not per compiled wave program.
-_WAVE_KERNEL_DEGRADE_WARNED: set = set()
-#: Device-model type names whose wave_matmul capability-gate rejection
-#: has already been announced — once per model type, not per spawn.
-_WAVE_MATMUL_GATE_WARNED: set = set()
-
-
-def wave_kernel_impl(wave_kernel: bool, dm: DeviceModel, batch: int,
-                     capacity: int, use_sym: bool, layout,
-                     matmul_plan=None):
-    """Resolves the single-kernel-wave implementation for one wave
-    program build: the Pallas megakernel when requested and the VMEM
-    working-set gate passes at this (batch, capacity), else ``None``
-    (the caller keeps the XLA op ladder). Degrades with a once-per-
-    shape warning — mid-run table growth must not kill a checker,
-    mirroring ``dedup_impl``'s pallas gate."""
-    if not wave_kernel:
-        return None
-    from .matmul_wave import plan_bytes
-    from .pallas_table import (PALLAS_AVAILABLE, build_wave_megakernel,
-                               wave_kernel_ok)
-
-    W = dm.state_width
-    Wr = layout.packed_width if layout is not None else W
-    if PALLAS_AVAILABLE and wave_kernel_ok(
-            capacity, batch, dm.max_fanout, W, Wr,
-            extra_bytes=plan_bytes(matmul_plan, batch)):
-        return build_wave_megakernel(dm, batch, capacity,
-                                     use_sym=use_sym, layout=layout,
-                                     matmul_plan=matmul_plan)
-    key = (batch, capacity)
-    if key not in _WAVE_KERNEL_DEGRADE_WARNED:
-        _WAVE_KERNEL_DEGRADE_WARNED.add(key)
-        warnings.warn(
-            f"wave megakernel unavailable at batch {batch} x capacity "
-            f"{capacity} (VMEM working-set budget or pallas missing); "
-            "using the XLA wave path", RuntimeWarning)
-    return None
-
-
-def sender_kernel_impl(wave_kernel: bool, dm: DeviceModel, batch: int,
-                       use_sym: bool, layout, local_dedup: bool,
-                       matmul_plan=None):
-    """The sharded engines' single-kernel-wave resolver: the table-less
-    SENDER megakernel (in-kernel unpack → expand → fingerprint →
-    sender-side local dedup → re-pack), run per shard under
-    ``shard_map``; the global probe/claim stays owner-side on the
-    partitioned XLA table after the all-to-all. Returns ``None`` (the
-    XLA path) when disabled or past the VMEM gate, with the same
-    once-per-shape degrade warning as ``wave_kernel_impl``."""
-    if not wave_kernel:
-        return None
-    from .matmul_wave import plan_bytes
-    from .pallas_table import (PALLAS_AVAILABLE,
-                               build_sender_megakernel,
-                               sender_kernel_ok)
-
-    W = dm.state_width
-    Wr = layout.packed_width if layout is not None else W
-    if PALLAS_AVAILABLE and sender_kernel_ok(
-            batch, dm.max_fanout, W, Wr,
-            extra_bytes=plan_bytes(matmul_plan, batch)):
-        return build_sender_megakernel(dm, batch, use_sym=use_sym,
-                                       layout=layout,
-                                       local_dedup=local_dedup,
-                                       matmul_plan=matmul_plan)
-    key = ("sender", batch)
-    if key not in _WAVE_KERNEL_DEGRADE_WARNED:
-        _WAVE_KERNEL_DEGRADE_WARNED.add(key)
-        warnings.warn(
-            f"sender wave megakernel unavailable at batch {batch} "
-            "(VMEM working-set budget or pallas missing); using the "
-            "XLA wave path", RuntimeWarning)
-    return None
-
-
 def build_wave(dm: DeviceModel, batch_size: int, capacity: int,
                prop_fns=(), use_sym: bool = False,
-               table_impl: str = "xla", out_rows: Optional[int] = None,
-               layout=None, wave_kernel: bool = False,
-               matmul_plan=None):
+               out_rows: Optional[int] = None, layout=None):
     """The single-device wave program (jitted): one BFS level expansion.
 
     Exposed as a standalone builder so the wave can be compiled and
@@ -2059,68 +1777,29 @@ def build_wave(dm: DeviceModel, batch_size: int, capacity: int,
     real lanes at wave start and re-packed after compaction — compute
     (step, properties, fingerprints, symmetry) always runs on the exact
     unpacked registers, so results are layout-independent.
-
-    ``wave_kernel`` (ISSUE 10) swaps the expand → fingerprint → local
-    dedup → probe/claim middle for ONE Pallas megakernel
-    (``pallas_table.build_wave_megakernel``) when the VMEM working-set
-    gate admits this (batch, capacity); property evaluation and the
-    ladder's K-row compaction stay XLA-side around it. The kernel
-    traces the same stage functions, so outputs are bit-identical to
-    the ladder (counts, discoveries, checkpoints — the test_wave_kernel
-    differential suite pins this).
-
-    ``matmul_plan`` (ISSUE 15, a compiled
-    :class:`~stateright_tpu.tpu.matmul_wave.MatmulPlan`) swaps the
-    expand stage for the one-hot x transition-table matmul form — in
-    the XLA ladder and inside the megakernel alike; everything
-    downstream of ``(succ, valid)`` is untouched, so outputs stay
-    bit-identical to the vmapped ``step`` path.
     """
     B, F, W = batch_size, dm.max_fanout, dm.state_width
     S = B * F
     K = S if out_rows is None else min(max(1, int(out_rows)), S)
     prop_fns = list(prop_fns)
-    dedup = dedup_impl(table_impl, capacity)
-    mega = wave_kernel_impl(wave_kernel, dm, B, capacity, use_sym,
-                            layout, matmul_plan=matmul_plan)
 
     def wave(vecs, valid, visited):
         reg = vecs if layout is None else layout.unpack(vecs)
         conds = eval_properties(prop_fns, reg)
-        if mega is not None:
-            # Single-kernel wave: the successor path runs as one
-            # pallas_call on the PACKED rows (in-kernel unpack); only
-            # the cheap reductions and the K-row compaction remain out
-            # here. succ_count/terminal derive from the kernel's
-            # validity mask exactly as expand_frontier derives them.
-            (succ_store, path_fps, sflat, new_mask, cand_mask,
-             merged) = mega(vecs, valid, visited)
-            succ_count = jnp.sum(sflat, dtype=jnp.int64)
-            terminal = valid & ~sflat.reshape(B, F).any(axis=1)
-            new_count = jnp.sum(new_mask, dtype=jnp.int32)
-            cand_count = jnp.sum(cand_mask, dtype=jnp.int32)
-            comp = compaction_order(new_mask)[:K]
-            # Successor rows leave the kernel already in storage form;
-            # the gather moves K packed rows, like the ladder's
-            # pack-after-gather moves K packed rows.
-            new_vecs = succ_store[comp]
-        else:
-            succ_flat, sflat, succ_count, terminal = (
-                matmul_expand(dm, matmul_plan, reg, valid)
-                if matmul_plan is not None
-                else expand_frontier(dm, reg, valid))
-            dedup_fps, path_fps = fingerprint_successors(
-                dm, succ_flat, sflat, use_sym)
-            new_mask, new_count, cand_count, merged, _ = dedup(
-                dedup_fps, visited)
-            # Compact new successors to the front, preserving (frontier
-            # row, action) order — the host enqueue order of bfs.rs:262
-            # — and gather only the ladder's K rows (packing AFTER the
-            # gather: only the K surviving rows pay the codec).
-            comp = compaction_order(new_mask)[:K]
-            new_vecs = succ_flat[comp]
-            if layout is not None:
-                new_vecs = layout.pack(new_vecs)
+        succ_flat, sflat, succ_count, terminal = expand_frontier(
+            dm, reg, valid)
+        dedup_fps, path_fps = fingerprint_successors(
+            dm, succ_flat, sflat, use_sym)
+        new_mask, new_count, cand_count, merged, _ = (
+            dedup_and_insert_counted(dedup_fps, visited, capacity))
+        # Compact new successors to the front, preserving (frontier
+        # row, action) order — the host enqueue order of bfs.rs:262 —
+        # and gather only the ladder's K rows (packing AFTER the
+        # gather: only the K surviving rows pay the codec).
+        comp = compaction_order(new_mask)[:K]
+        new_vecs = succ_flat[comp]
+        if layout is not None:
+            new_vecs = layout.pack(new_vecs)
         new_fps = path_fps[comp]
         new_parent = (comp // F).astype(jnp.int32)
         overflow = new_count > K
@@ -2173,7 +1852,7 @@ def build_mux_wave(dm: DeviceModel, batch_size: int, capacity: int,
     ``compaction_order`` is stable, so each tenant's novel rows come
     back in exactly the order its solo engine would have enqueued.
 
-    No successor ladder, no megakernel, no multi-wave pipelining here:
+    No successor ladder and no multi-wave pipelining here:
     the output rung is always the full ``B*F`` (an overflow path would
     complicate the per-tenant split for no gain at multiplexing's
     target shape — many SMALL frontiers sharing one dispatch)."""
@@ -2229,8 +1908,7 @@ def build_mux_wave(dm: DeviceModel, batch_size: int, capacity: int,
 
 
 def build_regather(dm: DeviceModel, batch_size: int, out_rows: int,
-                   use_sym: bool = False, layout=None,
-                   matmul_plan=None):
+                   use_sym: bool = False, layout=None):
     """The successor ladder's overflow recovery (jitted, pure): re-runs
     the deterministic expand + fingerprint of the SAME batch and
     compacts with the wave's own novelty mask at a rung that fits::
@@ -2250,10 +1928,7 @@ def build_regather(dm: DeviceModel, batch_size: int, out_rows: int,
     def regather(vecs, valid, new_mask):
         if layout is not None:
             vecs = layout.unpack(vecs)
-        succ_flat, sflat, _, _ = (
-            matmul_expand(dm, matmul_plan, vecs, valid)
-            if matmul_plan is not None
-            else expand_frontier(dm, vecs, valid))
+        succ_flat, sflat, _, _ = expand_frontier(dm, vecs, valid)
         _, path_fps = fingerprint_successors(dm, succ_flat, sflat,
                                              use_sym)
         comp = compaction_order(new_mask)[:K]
@@ -2385,8 +2060,7 @@ def host_table_insert(table: np.ndarray, fps: np.ndarray) -> None:
 def first_occurrence_candidates(dedup_fps):
     """Intra-wave dedup: True at the EARLIEST frontier-order occurrence
     of each non-sentinel fingerprint, preserving the host BFS enqueue
-    order of bfs.rs:262. Shared by the XLA and Pallas table paths —
-    their bit-identical-outputs contract starts here.
+    order of bfs.rs:262.
 
     Sort-free: a fingerprint's scratch slot is a function of the
     fingerprint alone, so same-fp candidates always collide — a
@@ -2447,12 +2121,26 @@ def dedup_and_insert(dedup_fps, visited, capacity: int):
     """First-occurrence + insert-or-test against the open-addressing
     table: the two-level composition of ``first_occurrence_candidates``
     (intra-wave local dedup) and ``global_insert`` (the table probe).
-    Returns ``(new_mask, new_count, visited)``. Kept as the reference
-    semantics every optimized path (the pallas kernel, the sharded
-    sender-side dedup, the ladder regather) is differentially gated
-    against; the table rehash programs also reuse it."""
+    Returns ``(new_mask, new_count, visited)``. The table rehash
+    programs use it."""
     candidate = first_occurrence_candidates(dedup_fps)
     return global_insert(dedup_fps, candidate, visited, capacity)
+
+
+def dedup_and_insert_counted(dedup_fps, visited, capacity: int):
+    """Both dedup levels of a wave program: the intra-wave local
+    collapse (``first_occurrence_counted``) first, then the global
+    probe (``global_insert_counted``) over the distinct survivors only.
+    Returns ``(new_mask, new_count, cand_count, merged, rounds)``:
+    ``cand_count`` (how many candidates reached the global probe) is
+    the collapse-ratio telemetry, and ``rounds`` the int32 scalars
+    ``(local dedup rounds, probe rounds)`` of the two loops."""
+    candidate, local_rounds = first_occurrence_counted(dedup_fps)
+    cand_count = jnp.sum(candidate, dtype=jnp.int32)
+    new_mask, new_count, merged, probe_rounds = global_insert_counted(
+        dedup_fps, candidate, visited, capacity)
+    return (new_mask, new_count, cand_count, merged,
+            (local_rounds, probe_rounds))
 
 
 def global_insert(dedup_fps, candidate, visited, capacity: int):

@@ -47,9 +47,9 @@ import jax.numpy as jnp
 
 from ..model import Expectation
 from .engine import (TpuBfsChecker, compaction_order, dedup_and_insert,
-                     dedup_impl, eval_properties, expand_frontier,
-                     fingerprint_successors, matmul_expand, pick_bucket,
-                     probe_chunk, wave_kernel_impl)
+                     dedup_and_insert_counted, eval_properties,
+                     expand_frontier, fingerprint_successors, pick_bucket,
+                     probe_chunk)
 from .hashing import SENTINEL
 
 __all__ = ["FusedTpuBfsChecker", "FusedUnsupported"]
@@ -133,7 +133,8 @@ class FusedTpuBfsChecker(TpuBfsChecker):
     # in-place aliasing — see the wave body), and its outputs never
     # cross the host boundary, so the successor output ladder has
     # nothing to bound here. Local dedup still runs (inside
-    # dedup_impl), and its collapse telemetry rides the ST_CAND slot.
+    # dedup_and_insert_counted), and its collapse telemetry rides the
+    # ST_CAND slot.
     _SUCC_LADDER_CAPABLE = False
 
     def __init__(self, builder, batch_size: int = 1024,
@@ -204,17 +205,6 @@ class FusedTpuBfsChecker(TpuBfsChecker):
         sentinel = jnp.uint64(SENTINEL)
         err_lane = dm.error_lane
         ebits_masks = [jnp.uint32(1 << i) for i in range(P)]
-        dedup = dedup_impl(self._table_impl, capacity)
-        # Single-kernel wave (ISSUE 10): with the megakernel resolved,
-        # each iteration of the device-resident multi-wave loop below
-        # runs its whole successor path as ONE pallas_call — K waves of
-        # fused kernel dispatches per host round-trip, stats vector
-        # chained exactly as before (the loop's rest-point predicates
-        # are untouched, so checkpoint/fault/spill hooks still fire at
-        # dispatch exits).
-        mega = wave_kernel_impl(self._wave_kernel_on, dm, B, capacity,
-                                use_sym, layout,
-                                matmul_plan=self._matmul_plan)
 
         def first_hit(disc_i, hit, bfps):
             """Keeps the first (frontier-order) hit's fingerprint, set
@@ -232,10 +222,9 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                 idx_c = jnp.minimum(idx, ucap - 1)
                 # The arena stores PACKED rows; unpack the batch to real
                 # lanes at wave start (compute is layout-independent).
-                bstore = vecs_a[idx_c]
-                bvecs = bstore
+                bvecs = vecs_a[idx_c]
                 if layout is not None:
-                    bvecs = layout.unpack(bstore)
+                    bvecs = layout.unpack(bvecs)
                 bfps = fps_a[idx_c]
                 bebits = eb_a[idx_c]
 
@@ -249,27 +238,12 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     continue
                 disc = disc.at[i].set(first_hit(disc[i], hit, bfps))
 
-            if mega is not None:
-                # Single-kernel wave: expand, fingerprint, local dedup,
-                # and the table probe/claim fused into one pallas_call
-                # on the PACKED batch rows; the reductions below derive
-                # the same quantities expand_frontier/dedup return.
-                (succ_store, path_fps, sflat, new_mask, cand_mask,
-                 visited) = mega(bstore, valid, visited)
-                succ_count = jnp.sum(sflat, dtype=jnp.int64)
-                terminal = valid & ~sflat.reshape(B, F).any(axis=1)
-                new_count = jnp.sum(new_mask, dtype=jnp.int32)
-                cand_count = jnp.sum(cand_mask, dtype=jnp.int32)
-                wave_rounds = (jnp.int32(0),) * 2  # not counted
-            else:
-                succ_flat, sflat, succ_count, terminal = (
-                    matmul_expand(dm, self._matmul_plan, bvecs, valid)
-                    if self._matmul_plan is not None
-                    else expand_frontier(dm, bvecs, valid))
-                dedup_fps, path_fps = fingerprint_successors(
-                    dm, succ_flat, sflat, use_sym)
-                new_mask, new_count, cand_count, visited, wave_rounds = (
-                    dedup(dedup_fps, visited))
+            succ_flat, sflat, succ_count, terminal = expand_frontier(
+                dm, bvecs, valid)
+            dedup_fps, path_fps = fingerprint_successors(
+                dm, succ_flat, sflat, use_sym)
+            new_mask, new_count, cand_count, visited, wave_rounds = (
+                dedup_and_insert_counted(dedup_fps, visited, capacity))
 
             # Eventually bits: clear satisfied at the parent, then flag
             # terminal parents with leftover bits (bfs.rs:212-226,265-272).
@@ -295,22 +269,14 @@ class FusedTpuBfsChecker(TpuBfsChecker):
             with jax.named_scope("store"):
                 comp = compaction_order(new_mask)
                 parent_rows = comp // F
-                # Megakernel rows arrive already packed for storage; the
-                # ladder packs after the gather as before.
-                new_vecs = (succ_store[comp] if mega is not None
-                            else succ_flat[comp])
+                new_vecs = succ_flat[comp]
                 new_fps = path_fps[comp]
                 new_parent = bfps[parent_rows]
                 new_ebits = cleared[parent_rows]
                 if err_lane is not None:
-                    # On packed rows, extract just the error lane (the
-                    # sharded-fused precedent); unpacked rows index it.
-                    err_col = (layout.lane(new_vecs, err_lane)
-                               if mega is not None and layout is not None
-                               else new_vecs[:, err_lane])
-                    err = err | jnp.any((err_col != 0)
+                    err = err | jnp.any((new_vecs[:, err_lane] != 0)
                                         & (jnp.arange(S) < new_count))
-                if mega is None and layout is not None:
+                if layout is not None:
                     new_vecs = layout.pack(new_vecs)
                 start = (tail,)
                 vecs_a = jax.lax.dynamic_update_slice(
@@ -575,8 +541,6 @@ class FusedTpuBfsChecker(TpuBfsChecker):
             with self._tracer.span("fused.stats_wait"):
                 stats_h = np.asarray(stats_out)
             waited = time.monotonic() - t_wait
-            # The loops' rounds are counted on the XLA path only.
-            counted = meta["kernel_path"].startswith("xla")
             succ_prev = succ_total
             head_prev = head
             head, tail, occ, succ_total = (
@@ -612,15 +576,12 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     # v15: the table-probe and local-dedup loops'
                     # rounds, and the host's own time on this dispatch
                     # (its launch and processing, less the stats wait).
-                    probe_rounds=(int(stats_h[ST_PROBE_ROUNDS])
-                                  if counted else None),
-                    dedup_rounds=(int(stats_h[ST_DEDUP_ROUNDS])
-                                  if counted else None),
+                    probe_rounds=int(stats_h[ST_PROBE_ROUNDS]),
+                    dedup_rounds=int(stats_h[ST_DEDUP_ROUNDS]),
                     # v17: the rows the probe loop's rounds carried,
                     # each round over a chunk of the wave's B*F rows.
                     probe_slots=(int(stats_h[ST_PROBE_ROUNDS])
-                                 * probe_chunk(meta["bucket"] * self._F)
-                                 if counted else None),
+                                 * probe_chunk(meta["bucket"] * self._F)),
                     host_s=launch_s + (now - t_proc) - waited,
                     # Frontier rows this dispatch consumed (the head
                     # advance) — the kernel-occupancy numerator.
@@ -829,9 +790,8 @@ class FusedTpuBfsChecker(TpuBfsChecker):
             self._visited = visited
             meta = {
                 "bucket": bucket, "inflight": len(inflight) + 1,
-                "kernel_path": self._kernel_path(self._capacity,
-                                                 bucket),
-                "expand_impl": self._expand_impl()}
+                "kernel_path": "xla",
+                "expand_impl": "step"}
             if pkey is not None:
                 # Internal riders for process() — popped there before
                 # the event reaches the schema'd streams.

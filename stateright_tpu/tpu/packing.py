@@ -44,25 +44,6 @@ slot index. The model lanes' placement, widths, and sentinel rules are
 byte-for-byte unchanged (the tenant lane starts on its own fresh word),
 so stripping the trailing word recovers exactly the solo storage row —
 which is how multiplexed checkpoints stay bit-identical to solo ones.
-
-**Matmul wave (round 19).** The same ``lane_bits`` declaration this
-module compiles is the lane-domain source for the matmul-wave
-transition compiler (``tpu/matmul_wave.py``): ``classify`` runs
-:func:`compile_layout` first and reads each lane's declared ``bits``
-(and sentinel status) off the resulting plan, so spec validation,
-domain sizing, and the regularity gate all share one parse — a model
-whose declaration is wrong fails here, at build time, for both
-consumers.
-
-**In-kernel use (round 15).** The jittable ``pack``/``unpack`` codecs
-are pure ``jnp`` shift/mask pipelines with every constant created
-in-trace, so they trace directly inside a Pallas kernel body: the wave
-megakernel (``pallas_table.build_wave_megakernel``) reads PACKED rows
-from HBM and unpacks the lanes the step function consumes entirely in
-VMEM, then re-packs the successor window before it leaves the kernel —
-registers never touch HBM. The ``packed_row_bytes`` /
-``unpacked_row_bytes`` attributes are the per-row figures the kernel's
-VMEM working-set gate (``pallas_table.wave_kernel_ok``) budgets.
 """
 
 from __future__ import annotations
@@ -138,12 +119,6 @@ class PackedLayout:
         self.total_bits = cursor
         self.packed_width = max(1, -(-cursor // 32))
         self.packs = self.packed_width < self.width
-        #: bytes per row in each form — the per-row figures the
-        #: megakernel's VMEM working-set accounting
-        #: (``pallas_table.wave_kernel_bytes``) is expressed in: packed
-        #: rows ride HBM, registers exist only in VMEM.
-        self.packed_row_bytes = 4 * self.packed_width
-        self.unpacked_row_bytes = 4 * self.width
         #: JSON-serializable form (checkpoint headers self-describe
         #: their layout with this).
         self.specs = [(l.bits if l.sentinel is None
@@ -170,7 +145,6 @@ class PackedLayout:
         out = PackedLayout(self.specs, self.width)
         out.tenant_lane = _Lane(int(bits), out.packed_width, 0, None)
         out.packed_width += 1
-        out.packed_row_bytes = 4 * out.packed_width
         return out
 
     # -- numpy codec (host cold paths) -----------------------------------

@@ -45,11 +45,11 @@ from ._compat import shard_map
 from ..resilience.faults import ExchangeIntegrityError
 from ..resilience.membership import EpochOwnership, OwnerMap
 from .device_model import DeviceModel
-from .engine import (TpuBfsChecker, compaction_order, dedup_impl,
-                     eval_properties, expand_frontier,
-                     fingerprint_successors, first_occurrence_candidates,
-                     host_table_insert, matmul_expand, pick_bucket,
-                     sender_kernel_impl, succ_bucket_ladder)
+from .engine import (TpuBfsChecker, compaction_order,
+                     dedup_and_insert_counted, eval_properties,
+                     expand_frontier, fingerprint_successors,
+                     first_occurrence_candidates, host_table_insert,
+                     pick_bucket, succ_bucket_ladder)
 from .hashing import SENTINEL
 
 __all__ = ["ShardedTpuBfsChecker"]
@@ -98,14 +98,6 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
                 "the sharded engine's wave loop is not software-pipelined "
                 "yet; drop pipeline=True (the all-to-all already overlaps "
                 "per-shard work)")
-        if kwargs.get("table_impl") == "pallas":
-            import warnings
-
-            warnings.warn(
-                "the sharded engines run the XLA visited table; "
-                "table_impl='pallas' is single-device for now",
-                RuntimeWarning, stacklevel=2)
-            kwargs["table_impl"] = "xla"
         super().__init__(builder, batch_size=batch_size,
                          device_model=device_model,
                          table_capacity=table_capacity,
@@ -155,7 +147,7 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
         sharding = jax.sharding.NamedSharding(self._mesh, P("shard"))
         return jax.device_put(table.reshape(n * cap), sharding)
 
-    def _grow_table_impl(self) -> None:
+    def _resize_table(self) -> None:
         # The base _grow_table wraps this with the OOM graceful
         # degradation (grow_oom fault hook + batch-bucket shedding).
         real = np.asarray(self._visited)
@@ -215,10 +207,6 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
         # A shard can receive every other shard's full fan-out.
         return self._n_shards * B * self._F
 
-    # The single-kernel wave here is the table-less per-shard sender
-    # megakernel; the base _kernel_path gates on this.
-    _SENDER_KERNEL = True
-
     def _route_fn(self, B: int):
         """Builds the sender side of the wave — expand, fingerprint,
         eventually-bit clearing, optional sender-side local dedup, and
@@ -249,33 +237,17 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
         eventually_device = [
             i for i, p in enumerate(self._properties)
             if p.expectation is Expectation.EVENTUALLY]
-        # Single-kernel wave (ISSUE 10): the sender megakernel runs the
-        # per-shard front half (unpack → expand → fingerprint → local
-        # dedup → re-pack) as one pallas_call; the partitioned table
-        # keeps the probe owner-side after the all-to-all.
-        sender = sender_kernel_impl(self._wave_kernel_on, dm, B,
-                                    use_sym, layout, exchange_novel,
-                                    matmul_plan=self._matmul_plan)
 
         def route(vecs, fps, valid, ebits):
             # Local views: vecs [B, Wr] (storage row format), fps [B],
             # valid [B], ebits [B]. Unpack to real lanes for compute.
-            store = vecs
             if layout is not None:
-                vecs = layout.unpack(store)
+                vecs = layout.unpack(vecs)
             conds = eval_properties(prop_fns, vecs)
-            if sender is not None:
-                (succ_store, dedup_fps, path_fps, sflat,
-                 send_mask) = sender(store, valid)
-                succ_count = jnp.sum(sflat, dtype=jnp.int64)
-                terminal = valid & ~sflat.reshape(B, F).any(axis=1)
-            else:
-                succ_flat, sflat, succ_count, terminal = (
-                    matmul_expand(dm, self._matmul_plan, vecs, valid)
-                    if self._matmul_plan is not None
-                    else expand_frontier(dm, vecs, valid))
-                dedup_fps, path_fps = fingerprint_successors(
-                    dm, succ_flat, sflat, use_sym)
+            succ_flat, sflat, succ_count, terminal = expand_frontier(
+                dm, vecs, valid)
+            dedup_fps, path_fps = fingerprint_successors(
+                dm, succ_flat, sflat, use_sym)
             parent_fps = jnp.repeat(fps, F)
             # Children inherit the parent's ebits *after* clearing bits for
             # eventually properties satisfied at the parent (bfs.rs:212-222)
@@ -286,18 +258,16 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
                     conds[i], jnp.uint32(1 << i), jnp.uint32(0))
             child_ebits = jnp.repeat(ebits_cleared, F)
 
-            if sender is None:
-                if exchange_novel:
-                    # Sender-side local dedup: only the first
-                    # occurrence of each distinct fingerprint enters
-                    # the exchange. A dropped row is a same-shard later
-                    # duplicate the owner's first-occurrence rule (over
-                    # the shard-major receive order) could never
-                    # select, so the surviving rows — and their
-                    # relative order — are unchanged.
-                    send_mask = first_occurrence_candidates(dedup_fps)
-                else:
-                    send_mask = sflat
+            if exchange_novel:
+                # Sender-side local dedup: only the first occurrence of
+                # each distinct fingerprint enters the exchange. A
+                # dropped row is a same-shard later duplicate the
+                # owner's first-occurrence rule (over the shard-major
+                # receive order) could never select, so the surviving
+                # rows — and their relative order — are unchanged.
+                send_mask = first_occurrence_candidates(dedup_fps)
+            else:
+                send_mask = sflat
 
             # Bucket successors by owner shard and all-to-all them home.
             part = (dedup_fps % n).astype(jnp.int32)
@@ -317,11 +287,9 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
             # all-to-all (stacking on the novelty routing above — the
             # interconnect now moves Wr words per state, not W), and the
             # owner side never unpacks: received rows flow packed
-            # through dedup compaction into its queue/arena. (The
-            # sender megakernel already emitted storage rows.)
-            if sender is None:
-                succ_store = (succ_flat if layout is None
-                              else layout.pack(succ_flat))
+            # through dedup compaction into its queue/arena.
+            succ_store = (succ_flat if layout is None
+                          else layout.pack(succ_flat))
             send_vecs = scatter(succ_store, 0).reshape(n, CAP, Wr)
             send_dedup = scatter(dedup_fps, sentinel).reshape(n, CAP)
             send_path = scatter(path_fps, sentinel).reshape(n, CAP)
@@ -354,7 +322,6 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
         mesh = self._mesh
         prop_fns = list(self._prop_fns)
         route = self._route_fn(B)
-        dedup = dedup_impl(self._table_impl, capacity)
 
         def wave_local(vecs, fps, valid, ebits, visited):
             (conds, succ_count, terminal, recv_vecs, recv_dedup,
@@ -365,8 +332,8 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
             # insert against this shard's table slice, then the ladder's
             # K-row compaction; the full novelty mask and the overflow
             # flag ship so a truncated wave regathers losslessly.
-            new_mask, new_count, cand_count, merged, _ = dedup(
-                recv_dedup, visited)
+            new_mask, new_count, cand_count, merged, _ = (
+                dedup_and_insert_counted(recv_dedup, visited, capacity))
             comp = compaction_order(new_mask)[:K]
             new_vecs = recv_vecs[comp]
             new_fps = recv_path[comp]
@@ -713,8 +680,8 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
                     # and the successor-path implementation this
                     # dispatch ran.
                     "rows": int(valid.sum()),
-                    "kernel_path": self._kernel_path(self._capacity, B),
-                    "expand_impl": self._expand_impl(),
+                    "kernel_path": "xla",
+                    "expand_impl": "step",
                     "successors": succ_sum, "candidates": cand_sum,
                     "novel": novel_sum, "capacity": self._capacity,
                     "load_factor": round(

@@ -56,11 +56,11 @@ from ._compat import shard_map
 
 from ..model import Expectation
 from ..resilience.membership import EpochOwnership, OwnerMap
-from .engine import (compaction_order, dedup_and_insert, dedup_impl,
-                     eval_properties, expand_frontier,
-                     fingerprint_successors, first_occurrence_unscoped,
-                     host_table_insert, matmul_expand, pick_bucket,
-                     probe_chunk, sender_kernel_impl)
+from .engine import (compaction_order, dedup_and_insert,
+                     dedup_and_insert_counted, eval_properties,
+                     expand_frontier, fingerprint_successors,
+                     first_occurrence_unscoped, host_table_insert,
+                     pick_bucket, probe_chunk)
 from .fused import (FusedTpuBfsChecker, ST_CAND, ST_DEDUP_ROUNDS, ST_DISC,
                     ST_ERR, ST_HEAD, ST_OCC, ST_PROBE_ROUNDS, ST_SUCC,
                     ST_TAIL, ST_TARGET, ST_WAVES, _pow2, _releasing)
@@ -99,14 +99,6 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
         self._owner_map = OwnerMap.identity(self._n)
         self._exchange_novel = (True if exchange_novel_only is None
                                 else bool(exchange_novel_only))
-        if kwargs.get("table_impl") == "pallas":
-            import warnings
-
-            warnings.warn(
-                "the sharded engines run the XLA visited table; "
-                "table_impl='pallas' is single-device for now",
-                RuntimeWarning, stacklevel=2)
-            kwargs["table_impl"] = "xla"
         super().__init__(builder, batch_size=batch_size, **kwargs)
 
     # -- Sharded device state ---------------------------------------------
@@ -141,10 +133,6 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
     def _table_bytes(self, capacity: int) -> int:
         # Capacity is PER SHARD; the device footprint is the mesh's.
         return self._n * capacity * 8
-
-    # The single-kernel wave here is the table-less per-shard sender
-    # megakernel; the base _kernel_path gates on this.
-    _SENDER_KERNEL = True
 
     def _roll_fn(self, ucap: int, dtype, width: int = 0):
         """Per-shard arena-span shift under ``shard_map``: each shard's
@@ -197,16 +185,6 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
         Pn = len(properties)
         sentinel = jnp.uint64(SENTINEL)
         err_lane = dm.error_lane
-        dedup = dedup_impl(self._table_impl, capacity)
-        # Single-kernel wave (ISSUE 10): the per-shard sender megakernel
-        # inside the device-resident multi-wave loop — each shard's
-        # front half (unpack → expand → fingerprint → sender-side local
-        # dedup → re-pack) is one pallas_call per wave; the owner-side
-        # probe stays on the partitioned XLA table after the in-loop
-        # all-to-all.
-        sender = sender_kernel_impl(self._wave_kernel_on, dm, B,
-                                    use_sym, layout, exchange_novel,
-                                    matmul_plan=self._matmul_plan)
         # Ownership assignment baked into the compiled dispatch (the
         # cache key carries the epoch); identity keeps the raw modulo.
         assign = (None if self._owner_map.is_identity
@@ -239,10 +217,9 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 valid = idx < tail
                 idx_c = jnp.minimum(idx, ucap - 1)
                 # Per-shard arenas store PACKED rows; unpack for compute.
-                bstore = vecs_a[idx_c]
-                bvecs = bstore
+                bvecs = vecs_a[idx_c]
                 if layout is not None:
-                    bvecs = layout.unpack(bstore)
+                    bvecs = layout.unpack(bvecs)
                 bfps = fps_a[idx_c]
                 bebits = eb_a[idx_c]
 
@@ -257,18 +234,10 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 disc = disc.at[i].set(
                     combine_first(disc[i], *propose_first(hit, bfps)))
 
-            if sender is not None:
-                (succ_store, dedup_fps, path_fps, sflat,
-                 send_mask) = sender(bstore, valid)
-                succ_count = jnp.sum(sflat, dtype=jnp.int64)
-                terminal = valid & ~sflat.reshape(B, F).any(axis=1)
-            else:
-                succ_flat, sflat, succ_count, terminal = (
-                    matmul_expand(dm, self._matmul_plan, bvecs, valid)
-                    if self._matmul_plan is not None
-                    else expand_frontier(dm, bvecs, valid))
-                dedup_fps, path_fps = fingerprint_successors(
-                    dm, succ_flat, sflat, use_sym)
+            succ_flat, sflat, succ_count, terminal = expand_frontier(
+                dm, bvecs, valid)
+            dedup_fps, path_fps = fingerprint_successors(
+                dm, succ_flat, sflat, use_sym)
 
             cleared = bebits
             for i, prop in enumerate(properties):
@@ -284,12 +253,10 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
 
             # Pack before the in-loop exchange: the ICI moves Wr words
             # per state, and the owner appends the received rows to its
-            # arena without ever unpacking them. (The sender megakernel
-            # already emitted storage rows.)
-            if sender is None:
-                with jax.named_scope("store"):
-                    succ_store = (succ_flat if layout is None
-                                  else layout.pack(succ_flat))
+            # arena without ever unpacking them.
+            with jax.named_scope("store"):
+                succ_store = (succ_flat if layout is None
+                              else layout.pack(succ_flat))
 
             # Bucket successors by owner and route them home (one ICI
             # all-to-all per wave, as in the unfused engine). With
@@ -299,11 +266,10 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             with jax.named_scope("exchange"):
                 parent_fps = jnp.repeat(bfps, F)
                 child_ebits = jnp.repeat(cleared, F)
-                if sender is None:
-                    if exchange_novel:
-                        send_mask = first_occurrence_unscoped(dedup_fps)[0]
-                    else:
-                        send_mask = sflat
+                if exchange_novel:
+                    send_mask = first_occurrence_unscoped(dedup_fps)[0]
+                else:
+                    send_mask = sflat
                 part = (dedup_fps % n).astype(jnp.int32)
                 dest = part if assign is None else assign[part]
                 # Successor rows that leave this shard.
@@ -333,8 +299,8 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 recv_ebits = a2a(scatter(child_ebits, 0).reshape(
                     n, CAP)).reshape(R)
 
-            new_mask, new_count, cand_count, visited, wave_rounds = dedup(
-                recv_dedup, visited)
+            new_mask, new_count, cand_count, visited, wave_rounds = (
+                dedup_and_insert_counted(recv_dedup, visited, capacity))
 
             # Full-window append on purpose: a cond-narrowed window
             # breaks the donated arena's in-place aliasing (see the
@@ -870,9 +836,8 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             self._visited = visited
             meta = {
                 "bucket": bucket, "inflight": len(inflight) + 1,
-                "kernel_path": self._kernel_path(self._capacity,
-                                                 bucket),
-                "expand_impl": self._expand_impl()}
+                "kernel_path": "xla",
+                "expand_impl": "step"}
             if pkey is not None:
                 # Internal riders for process() — popped there before
                 # the event reaches the schema'd streams.

@@ -194,6 +194,19 @@ def test_fused_probe_loop_carries_one_chunk(fused_programs):
         engine.PROBE_CHUNK}
 
 
+#: sha256 of the twopc11-check-4chip dispatch compiled for the
+#: described 2x2, its text without metadata (``_hlo_digest``)
+TWOPC11_MESH_HLO_SHA256 = ("c25c870d02fe0bcc55ace87b3c1e1935"
+                           "485515e9a4baacf815129ffaac3d8b7e")
+
+
+def test_twopc11_mesh_dispatch_hlo_is_unchanged(twopc11_mesh_program):
+    """The mesh cell's program, modulo metadata: a later change that was
+    meant to leave the sharded-fused wave alone shows here."""
+    assert _hlo_digest(twopc11_mesh_program.as_text()) == (
+        TWOPC11_MESH_HLO_SHA256)
+
+
 def test_twopc11_mesh_dispatch_fits_a_chip(twopc11_mesh_program):
     mem = twopc11_mesh_program.memory_analysis()
     # per chip: a 2^28-slot table slice and a 2^27-row arena slice
@@ -232,39 +245,6 @@ def test_twopc11_mesh_all_to_alls_sit_in_exchange(twopc11_mesh_program):
     assert len(paths) >= 5
     assert all("exchange" in p.split("/") and stage_of(p) is None
                for p in paths)
-
-
-def test_pallas_table_kernel_is_refused_on_tpu(one_chip, monkeypatch):
-    """The PR-21 verdict: Mosaic has no 64-bit vectors, so neither the
-    table kernel nor any kernel with uint64 operands compiles for the
-    chip, and the engines refuse the kernels on a TPU instead of
-    running interpret mode or switching to XLA."""
-    from jax.experimental import pallas as pl
-
-    from stateright_tpu.tpu import pallas_table
-
-    def spec(n):
-        return jax.ShapeDtypeStruct((n,), jnp.uint64, sharding=one_chip)
-
-    cap = 1 << 12
-    table = jax.jit(lambda fps, vis: pallas_table.dedup_and_insert_pallas(
-        fps, vis, cap, interpret=False))
-    with pytest.raises(Exception):
-        table.lower(spec(1024), spec(cap)).compile()
-
-    def add(x_ref, o_ref):
-        o_ref[:] = x_ref[:] + x_ref[:]
-
-    plain = jax.jit(lambda x: pl.pallas_call(
-        add, out_shape=jax.ShapeDtypeStruct((1024,), jnp.uint64))(x))
-    with pytest.raises(Exception, match="X64 element types"):
-        plain.lower(spec(1024)).compile()
-
-    monkeypatch.setattr(pallas_table, "_BACKEND_DECISION_CACHE", [False])
-    for knobs in ({"wave_kernel": True}, {"table_impl": "pallas"}):
-        with pytest.raises(NotImplementedError,
-                           match="cannot run on a TPU"):
-            TwoPhaseSys(3).checker().spawn_tpu_bfs(fused=True, **knobs)
 
 
 def test_paxos3_step_compiles_without_loops(one_chip):

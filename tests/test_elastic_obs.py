@@ -27,6 +27,7 @@ from stateright_tpu.obs import (FlightRecorder, NULL_RECORDER,  # noqa: E402
                                 RunTracer, SCHEMA_VERSION,
                                 TraceCollector, postmortem_path,
                                 recorder_from_env, validate_event)
+from stateright_tpu.obs.schema import WAVE_NULL_DEFAULTS  # noqa: E402
 
 import trace_export  # noqa: E402
 import trace_lint  # noqa: E402
@@ -314,29 +315,11 @@ def _worker_wave(worker, seq, run="rw", **kw):
     fields.update({"type": "wave", "schema_version": SCHEMA_VERSION,
                    "engine": "elastic_worker", "run": run,
                    "wave": kw.pop("wave", 0), "worker": worker,
-                   "seq": seq,
-                   # v6 tier gauges + v8 kernel-path keys (the tracer
-                   # stamps these for real producers; raw-JSON
-                   # builders stamp them here).
-                   "tier_device_rows": None, "tier_device_bytes": None,
-                   "tier_host_rows": None, "tier_host_bytes": None,
-                   "tier_disk_rows": None, "tier_disk_bytes": None,
-                   "kernel_path": None, "rows": None,
-                   "job_id": None, "jobs_in_wave": None,
-                   "io_stall_s": None, "expand_impl": None,
-                   # v13 profiler cost fields (null when the program's
-                   # cost model was never captured).
-                   "cost_flops": None, "cost_bytes": None,
-                   "cost_ratio": None,
-                   # v15 loop rounds and host seconds (null where not
-                   # counted).
-                   "probe_rounds": None, "dedup_rounds": None,
-                   "host_s": None,
-                   # v16 shard-exchange counts (null without an
-                   # exchange).
-                   "exchange_rows": None, "exchange_slots": None,
-                   # v17 probe slots (null where the rounds are).
-                   "probe_slots": None})
+                   "seq": seq})
+    # The tracer stamps these for real producers; raw-JSON builders
+    # stamp them here.
+    for key in WAVE_NULL_DEFAULTS:
+        fields.setdefault(key, None)
     fields.update(kw)
     return json.dumps(fields)
 
@@ -365,15 +348,7 @@ def test_lint_elastic_wave_requires_attribution():
     # v4 captures predate the keys: no retroactive failures.
     old = json.loads(_worker_wave("x", 1))
     old.update(engine="elastic", schema_version=4)
-    for key in ("worker", "seq", "epoch", "round",
-                "tier_device_rows", "tier_device_bytes",
-                "tier_host_rows", "tier_host_bytes",
-                "tier_disk_rows", "tier_disk_bytes",
-                "kernel_path", "rows", "job_id", "jobs_in_wave",
-                "io_stall_s", "expand_impl",
-                "cost_flops", "cost_bytes", "cost_ratio",
-                "probe_rounds", "dedup_rounds", "host_s",
-                "exchange_rows", "exchange_slots", "probe_slots"):
+    for key in WAVE_NULL_DEFAULTS:
         old.pop(key, None)
     _, errors = trace_lint.lint_lines([json.dumps(old)])
     assert not errors, errors

@@ -1,9 +1,9 @@
 """Oracle tests for the sort-free intra-wave dedup.
 
-``first_occurrence_candidates`` (engine.py) is where the XLA and Pallas
-table paths' bit-identical-outputs contract starts; since round 5 it is
-a scatter-min group-resolution loop instead of a stable argsort, so pin
-its exact semantics — True at the earliest frontier-order occurrence of
+``first_occurrence_candidates`` (engine.py) decides which of a wave's
+successors reach the table probe; since round 5 it is a scatter-min
+group-resolution loop instead of a stable argsort, so pin its exact
+semantics — True at the earliest frontier-order occurrence of
 each non-sentinel fingerprint — against a reference oracle, including
 the adversarial shapes that stress the loop (same-fp floods, shared
 probe steps, all-sentinel waves).

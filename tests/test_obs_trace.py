@@ -295,3 +295,53 @@ def test_overflow_and_grow_events(tmp_path, monkeypatch):
     assert stats["succ_ladder"]["overflow_redispatches"] == flagged
     assert any(e.get("type") == "grow" for e in events), \
         "2pc-4 at 2^12 must grow the table at least once"
+
+
+def test_schema_v6_field_map_excludes_v8_keys():
+    """A v6 wave with v8 riders is NOT valid, and a v8 wave missing
+    them is NOT valid — additions go through the version bump, one
+    schema per version."""
+    from stateright_tpu.obs.schema import (WAVE_FIELDS, WAVE_FIELDS_V6,
+                                           validate_event)
+
+    assert "kernel_path" not in WAVE_FIELDS_V6
+    assert "rows" not in WAVE_FIELDS_V6
+    base = {"type": "wave", "schema_version": 6, "engine": "classic",
+            "run": "x", "wave": 0, "t": 1.0}
+    for k in WAVE_FIELDS_V6:
+        base.setdefault(k, None)
+    base.update(states=1, unique=1, bucket=4, waves=1, inflight=0,
+                compiled=False, successors=0, candidates=0, novel=0,
+                overflow=False)
+    assert validate_event(base) == []
+    bad = dict(base, kernel_path="xla", rows=4)
+    assert any("unexpected" in e for e in validate_event(bad))
+    v8 = dict(base, schema_version=8)
+    assert any("missing field 'kernel_path'" in e
+               for e in validate_event(v8))
+    assert validate_event(dict(v8, kernel_path=None, rows=None)) == []
+
+
+def test_schema_v11_field_map_excludes_v12_keys():
+    """A v11 wave with the v12 rider is NOT valid, and a v12 wave
+    missing it is NOT valid — additions go through the version bump,
+    one schema per version."""
+    from stateright_tpu.obs.schema import (WAVE_FIELDS, WAVE_FIELDS_V11,
+                                           validate_event)
+
+    assert "expand_impl" not in WAVE_FIELDS_V11
+    assert "expand_impl" in WAVE_FIELDS
+    base = {"type": "wave", "schema_version": 11, "engine": "classic",
+            "run": "x", "wave": 0, "t": 1.0}
+    for k in WAVE_FIELDS_V11:
+        base.setdefault(k, None)
+    base.update(states=1, unique=1, bucket=4, waves=1, inflight=0,
+                compiled=False, successors=0, candidates=0, novel=0,
+                overflow=False)
+    assert validate_event(base) == []
+    bad = dict(base, expand_impl="step")
+    assert any("unexpected" in e for e in validate_event(bad))
+    v12 = dict(base, schema_version=12)
+    assert any("missing field 'expand_impl'" in e
+               for e in validate_event(v12))
+    assert validate_event(dict(v12, expand_impl=None)) == []

@@ -20,9 +20,6 @@ Contracts pinned here:
 - **Deterministic cadence**: ``should_sample`` is a pure function of
   the dispatch sequence — every Nth dispatch plus the first dispatch
   of each new program key.
-- **Per-arm A/B attribution**: the matmul-vs-step A/B captures a
-  distinct cost model per arm (the prof key prefix encodes the active
-  plan), with identical checking results.
 """
 
 import json
@@ -252,41 +249,3 @@ def test_sampling_cadence_deterministic():
     # at index 6).
     assert a == [i % 4 == 0 or i == 6 for i in range(len(seq))]
     assert pa.stats()["dispatches"] == len(seq)
-
-
-# -- Matmul-vs-step A/B: per-arm cost capture -------------------------------
-
-def _matmul_ab(model, engine):
-    arms = {}
-    for on in (True, False):
-        clear_program_records()
-        c = _spawn(model, engine, wave_matmul=on).join()
-        prof = c.scheduler_stats()["prof"]
-        assert prof is not None and prof["programs"], on
-        for key, snap in prof["programs"].items():
-            assert snap["flops"] and snap["flops"] > 0, (on, key)
-            assert snap["bytes"] and snap["bytes"] > 0, (on, key)
-            assert math.isfinite(snap["cost_ratio"]), (on, key)
-        arms[on] = (c.state_count(), c.unique_state_count(),
-                    tuple(sorted(c.discoveries())),
-                    frozenset(prof["programs"]))
-    # Identical results; DISTINCT cost models (the prof key prefix
-    # encodes whether the matmul plan was compiled in).
-    assert arms[True][:3] == arms[False][:3]
-    assert arms[True][3].isdisjoint(arms[False][3])
-
-
-def test_matmul_vs_step_ab_captures_both_arms(monkeypatch):
-    monkeypatch.setenv("STpu_PROF", "1")
-    monkeypatch.setenv("STpu_PROF_SAMPLE", "1")
-    _matmul_ab(TwoPhaseSys(3), "classic")
-
-
-@pytest.mark.slow
-def test_matmul_vs_step_ab_increment_fused(monkeypatch):
-    from increment import IncrementModel
-
-    monkeypatch.setenv("STpu_PROF", "1")
-    monkeypatch.setenv("STpu_PROF_SAMPLE", "1")
-    _matmul_ab(IncrementModel(3), "fused")
-    _matmul_ab(TwoPhaseSys(4), "fused")

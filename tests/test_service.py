@@ -75,6 +75,29 @@ def test_submit_validation():
         svc.close()
 
 
+@pytest.mark.parametrize("knob", ["table_impl", "wave_kernel",
+                                  "wave_matmul"])
+def test_removed_knob_is_refused_with_400(knob, tmp_path):
+    """Knobs the engines no longer take are unknown knobs: the HTTP API
+    answers 400, as for any other."""
+    from stateright_tpu.explorer import serve_service
+
+    service, server = serve_service(
+        addresses=("127.0.0.1", 0), block=False, workers=1,
+        data_dir=str(tmp_path))
+    host, port = server.server_address[:2]
+    try:
+        with pytest.raises(sc.ServiceError) as err:
+            sc.submit(f"http://{host}:{port}",
+                      dict(TWOPC, knobs={knob: "xla"}))
+        assert err.value.http_status == 400
+        assert f"unknown engine knob {knob!r}" in str(err.value)
+    finally:
+        server.shutdown()
+        server.server_close()
+        service.close()
+
+
 # -- Differential fuzz gate ------------------------------------------------
 
 
@@ -119,11 +142,12 @@ def test_diff_walk_catches_broken_property():
         diff_walk(model, WrongProperty(cfg, ppmod), seed=0, steps=10)
 
 
-@pytest.mark.slow
-def test_fuzz_gate_walks_twopc():
-    # Covered in spirit by the corpus-wide sweep below; kept as the
-    # single-model CLI-shaped arm.
-    result = fuzz_gate("twopc", seeds=(0,), steps=20, full=False)
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("name", default_registry().names())
+def test_fuzz_gate_walks(name, seed):
+    """Every corpus model's device step agrees with its host model on a
+    seeded random-schedule walk."""
+    result = fuzz_gate(name, seeds=(seed,), steps=15, full=False)
     assert result["walks"][0]["transitions"] > 0
 
 

@@ -332,3 +332,16 @@ def test_parity_gate_runs_device_in_process(monkeypatch):
     device["unique"] = 8831
     with pytest.raises(AssertionError, match="unique-state mismatch"):
         bench._stage_parity_gate("tpu")
+
+
+def test_scheduler_stats_occupancy_is_a_stream_view():
+    """succ_ladder occupancy recomputes exactly from the dispatch_log
+    — a view over the wave-event stream, no parallel bookkeeping (a
+    zero-wave no-op dispatch contributes to neither side)."""
+    model = TwoPhaseSys(2)
+    c = model.checker().spawn_tpu_bfs(batch_size=16, fused=False).join()
+    log = c.dispatch_log
+    want = (sum(e["rows"] for e in log)
+            / sum(e["bucket"] * e["waves"] for e in log))
+    assert c.scheduler_stats()["succ_ladder"]["occupancy"] \
+        == round(want, 4)
