@@ -36,7 +36,8 @@ __all__ = [
     "WAVE_FIELDS_V5", "WAVE_FIELDS_V6", "WAVE_FIELDS_V8",
     "WAVE_FIELDS_V9", "WAVE_FIELDS_V11", "WAVE_FIELDS_V12",
     "WAVE_FIELDS_V14", "WAVE_FIELDS_V15", "WAVE_FIELDS_V16",
-    "WAVE_NULL_DEFAULTS", "validate_event", "validate_line",
+    "WAVE_FIELDS_V17", "WAVE_NULL_DEFAULTS", "validate_event",
+    "validate_line",
 ]
 
 #: v14: the closed vocabulary a ``shed`` event's ``reason`` must come
@@ -217,10 +218,15 @@ SHED_REASONS = ("slo_burn", "brownout", "retry_budget", "queue_full")
 #: over the dispatch's waves; on a mesh the slowest shard's per wave).
 #: Σ``candidates`` / Σ``probe_slots`` is how full the probe's rounds
 #: run. ``null`` where ``probe_rounds`` is.
-#: v1-v16 streams still validate (against their version's field set);
+#: v18: sharded-fused dispatches send their exchange in rounds of
+#: balanced buckets — wave events gained ``exchange_rounds`` (the
+#: rounds every shard ran, summed over the dispatch's waves; at least
+#: one a wave), and ``exchange_slots`` counts the rows those rounds
+#: carried between shards. ``null`` where ``exchange_slots`` is.
+#: v1-v17 streams still validate (against their version's field set);
 #: streams NEWER than this validator are rejected with a clear
 #: upgrade message instead of a cascade of field-set mismatches.
-SCHEMA_VERSION = 17
+SCHEMA_VERSION = 18
 
 #: Environment knob: set to a file path to stream JSONL events there.
 #: Unset means the null tracer — the hot loop pays one attribute check.
@@ -351,6 +357,8 @@ WAVE_FIELDS: Dict[str, tuple] = {
     # v17: the rows the probe's rounds carried (null where the rounds
     # are not counted).
     "probe_slots": _INT + (_NULL,),
+    # v18: the shard exchange's rounds (null where there is none).
+    "exchange_rounds": _INT + (_NULL,),
 }
 
 #: The wave keys a producer stamps ``null`` where its entry carries no
@@ -383,6 +391,8 @@ WAVE_NULL_DEFAULTS = (
     "exchange_rows", "exchange_slots",
     # v17 probe slots: null where the rounds are.
     "probe_slots",
+    # v18 exchange rounds: null on producers without an exchange.
+    "exchange_rounds",
 )
 
 #: v5 attribution keys (absent from v2-v4 wave events).
@@ -417,6 +427,9 @@ _WAVE_V16_KEYS = ("exchange_rows", "exchange_slots")
 #: v17 probe-slot key (absent from v1-v16 wave events).
 _WAVE_V17_KEYS = ("probe_slots",)
 
+#: v18 exchange-round key (absent from v1-v17 wave events).
+_WAVE_V18_KEYS = ("exchange_rounds",)
+
 #: The v1 wave field set (no bandwidth gauges) — v1 captures validate
 #: against this exactly.
 WAVE_FIELDS_V1: Dict[str, tuple] = {
@@ -424,66 +437,74 @@ WAVE_FIELDS_V1: Dict[str, tuple] = {
     if k not in ("bytes_per_state", "arena_bytes", "table_bytes")
     + _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
     + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
-    + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS}
 
 #: The v2-v4 wave field set (bandwidth gauges, no attribution keys).
 WAVE_FIELDS_V2: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS
     + _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
-    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS
+    + _WAVE_V18_KEYS}
 
 #: The v5 wave field set (attribution keys, no tier gauges).
 WAVE_FIELDS_V5: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
     + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS
-    + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS}
 
 #: The v6-v7 wave field set (tier gauges, no kernel-path keys).
 WAVE_FIELDS_V6: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V8_KEYS + _WAVE_V9_KEYS + _WAVE_V10_KEYS
     + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS
-    + _WAVE_V17_KEYS}
+    + _WAVE_V17_KEYS + _WAVE_V18_KEYS}
 
 #: The v8 wave field set (kernel-path keys, no mux attribution).
 WAVE_FIELDS_V8: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
-    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS
+    + _WAVE_V18_KEYS}
 
 #: The v9 wave field set (mux attribution, no async-I/O gauge).
 WAVE_FIELDS_V9: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
-    + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS}
 
 #: The v10-v11 wave field set (async-I/O gauge, no expand_impl).
 WAVE_FIELDS_V11: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS
-    + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS}
 
 #: The v12 wave field set (expand_impl, no cost attribution).
 WAVE_FIELDS_V12: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS
-    + _WAVE_V17_KEYS}
+    + _WAVE_V17_KEYS + _WAVE_V18_KEYS}
 
 #: The v13-v14 wave field set (cost attribution, no loop rounds).
 WAVE_FIELDS_V14: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    if k not in _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS
+    + _WAVE_V18_KEYS}
 
 #: The v15 wave field set (loop rounds, no shard-exchange counts).
 WAVE_FIELDS_V15: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V16_KEYS + _WAVE_V17_KEYS}
+    if k not in _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS}
 
 #: The v16 wave field set (shard-exchange counts, no probe slots).
 WAVE_FIELDS_V16: Dict[str, tuple] = {
-    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V17_KEYS}
+    k: v for k, v in WAVE_FIELDS.items()
+    if k not in _WAVE_V17_KEYS + _WAVE_V18_KEYS}
+
+#: The v17 wave field set (probe slots, no exchange rounds).
+WAVE_FIELDS_V17: Dict[str, tuple] = {
+    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V18_KEYS}
 
 _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            3: WAVE_FIELDS_V2, 4: WAVE_FIELDS_V2,
@@ -497,7 +518,7 @@ _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            # field set matches v13.
                            13: WAVE_FIELDS_V14, 14: WAVE_FIELDS_V14,
                            15: WAVE_FIELDS_V15, 16: WAVE_FIELDS_V16,
-                           17: WAVE_FIELDS}
+                           17: WAVE_FIELDS_V17, 18: WAVE_FIELDS}
 
 #: Required fields per trace event type (beyond the stamped
 #: schema_version/engine/run/t, which every event carries).

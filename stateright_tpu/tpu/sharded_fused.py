@@ -12,8 +12,8 @@ shard* and runs up to ``waves_per_dispatch`` waves per dispatch:
   frontier share. Ownership doubles as load balancing, exactly like the
   unfused engine.
 - **In-loop shuffle**: each wave, every shard expands its share,
-  fingerprints successors, buckets them by owner, and one
-  ``lax.all_to_all`` (ICI on a TPU slice) routes them home, where the
+  fingerprints successors, buckets them by owner, and rounds of
+  ``lax.all_to_all`` (ICI on a TPU slice) route them home, where the
   owner dedups against its local table slice and appends survivors —
   all inside one ``lax.while_loop`` under ``shard_map``.
 - **Lockstep stop conditions**: every shard computes identical global
@@ -27,6 +27,12 @@ shard* and runs up to ``waves_per_dispatch`` waves per dispatch:
   derives on the host from its concatenated batch, preserved here so the
   two engines are discovery-identical (and, like the reference's
   multithreaded BFS, not guaranteed shortest: `checker.rs:115-118`).
+- **Sized buckets**: an exchange round sends each owner a bucket of
+  ``CAP = exchange_bucket_rows(B*F, n)`` rows, a sender's balanced
+  share, so an owner receives ``n*CAP`` rows a round, not the ``n*B*F``
+  a wave could send it. A wave takes as many rounds as the fullest
+  bucket on any shard needs (a ``pmax``: at least one, at most ``n``),
+  and each round's owner dedups, probes and appends what it received.
 
 Host-per-dispatch traffic is one packed per-shard stats array; parent
 rows are fetched lazily, as in the single-chip fused engine.
@@ -35,9 +41,9 @@ The wave names its stages as the single-chip wave does (``load``,
 ``properties``, ``expand``, ``fingerprint``, ``local_dedup``,
 ``probe``, ``store``), plus ``exchange``: the sender-side duplicate
 collapse, the owner bucketing and the all-to-alls. Each dispatch counts
-the mesh's slowest shard's loop rounds per wave, and the successor rows
-it sent to another shard (``exchange_rows``); the host loop opens the
-same ``fused.*`` spans.
+the mesh's slowest shard's loop rounds per wave, the exchange rounds
+(``exchange_rounds``) and the successor rows sent to another shard
+(``exchange_rows``); the host loop opens the same ``fused.*`` spans.
 """
 
 from __future__ import annotations
@@ -68,11 +74,33 @@ from .hashing import SENTINEL
 
 __all__ = ["ShardedFusedTpuBfsChecker"]
 
-# The stats row is the single-chip layout with one more slot before the
-# discovery fingerprints: the successor rows the dispatch's waves sent
-# to another shard, summed over the mesh.
+# The stats row is the single-chip layout with two more slots before
+# the discovery fingerprints: the successor rows the dispatch's waves
+# sent to another shard, summed over the mesh, and the exchange rounds
+# they took.
 ST_EXCHANGE_ROWS = ST_DISC
-SH_DISC = ST_DISC + 1
+ST_EXCHANGE_ROUNDS = ST_DISC + 1
+SH_DISC = ST_DISC + 2
+
+
+def exchange_bucket_rows(rows: int, shards: int) -> int:
+    """Rows of each owner's bucket in one exchange round, for senders of
+    ``rows`` successor rows a wave: the balanced share
+    ``ceil(rows / shards)``, rounded up to a multiple of 8. A sender
+    whose rows spread evenly over the owners sends them in one round."""
+    share = -(-rows // shards)
+    return -(-share // 8) * 8
+
+
+def exchange_window_rows(rows: int, shards: int) -> int:
+    """Arena rows past a shard's tail that one wave's appends may
+    write. Round ``r`` appends a window of ``shards * CAP`` rows after
+    what the rounds before it admitted (at most ``shards * r * CAP``),
+    and a wave takes at most ``ceil(rows / CAP)`` rounds: so
+    ``shards * ceil(rows / CAP) * CAP``, which is ``shards * rows``
+    where ``CAP`` divides ``rows``."""
+    cap = exchange_bucket_rows(rows, shards)
+    return shards * -(-rows // cap) * cap
 
 
 class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
@@ -176,8 +204,10 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
         Wr = self._Wrow
         layout = self._wave_layout()
         S = B * F        # successors produced per shard per wave
-        CAP = S          # per-destination bucket capacity (worst case)
-        R = n * CAP      # rows a shard can receive per wave
+        CAP = exchange_bucket_rows(S, n)  # rows per owner a round
+        RR = n * CAP     # rows a shard receives per round
+        R = n * S        # rows a shard can admit per wave
+        WIN = exchange_window_rows(S, n)  # arena rows a wave may write
         prop_fns = list(self._prop_fns)
         use_sym = self._use_symmetry
         exchange_novel = self._exchange_novel
@@ -208,13 +238,21 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                              all_fp[winner], disc_i)
 
         def wave(carry):
+            """Exchange round ``r`` of the current wave. Each round
+            expands the same frontier rows (those below the tail its
+            wave began with), so every round derives the same
+            owner-sorted successors and sends the next bucket of them;
+            the last round moves the head on. A wave of one round is the
+            whole wave."""
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
              succ_total, cand_total, sent_total, err, disc, waves, rounds,
-             target) = carry
+             x_rounds, r, wave_tail, target) = carry
+            first = r == 0
+            wave_tail = jnp.where(first, tail, wave_tail)
             with jax.named_scope("load"):
                 # Local frontier slice (scalars head/tail are per shard).
                 idx = head + jnp.arange(B, dtype=jnp.int64)
-                valid = idx < tail
+                valid = idx < wave_tail
                 idx_c = jnp.minimum(idx, ucap - 1)
                 # Per-shard arenas store PACKED rows; unpack for compute.
                 bvecs = vecs_a[idx_c]
@@ -258,8 +296,8 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 succ_store = (succ_flat if layout is None
                               else layout.pack(succ_flat))
 
-            # Bucket successors by owner and route them home (one ICI
-            # all-to-all per wave, as in the unfused engine). With
+            # Bucket successors by owner and route bucket r of each home
+            # (ICI all-to-alls, as in the unfused engine). With
             # exchange_novel_only, sender-side local dedup thins the
             # candidate stream first (same-shard later duplicates could
             # never win the owner's first-occurrence rule anyway).
@@ -276,28 +314,37 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 sent = jnp.sum(send_mask & (dest != jax.lax.axis_index(
                     "shard")), dtype=jnp.int64)
                 owner = jnp.where(send_mask, dest, n)
-                order = jnp.argsort(owner, stable=True)
-                so = owner[order]
-                starts = jnp.searchsorted(so, jnp.arange(n + 1))
-                rank = jnp.arange(S) - starts[jnp.clip(so, 0, n)]
-                slot = so * CAP + rank   # invalid bucket rows drop
+                order = jnp.argsort(owner, stable=True).astype(jnp.int32)
+                starts = jnp.searchsorted(
+                    owner[order], jnp.arange(n + 1)).astype(jnp.int32)
+                counts = starts[1:] - starts[:-1]   # rows for each owner
+                # Every shard takes the rounds that the fullest bucket on
+                # any shard needs.
+                n_rounds = jnp.maximum(jax.lax.pmax(
+                    -(-jnp.max(counts) // CAP), "shard"), 1)
+                # Bucket d of this round: rows starts[d] + r*CAP + j of
+                # the owner-sorted order, those below its count.
+                j = r * CAP + jnp.arange(CAP, dtype=jnp.int32)
+                take = (j < counts[:, None]).reshape(RR)
+                src = order[jnp.minimum(starts[:n, None] + j,
+                                        S - 1)].reshape(RR)
 
-                def scatter(x, fill):
-                    out = jnp.full((n * CAP,) + x.shape[1:], fill, x.dtype)
-                    return out.at[slot].set(x[order], mode="drop")
+                def bucket(x, fill):
+                    keep = take.reshape((RR,) + (1,) * (x.ndim - 1))
+                    return jnp.where(keep, x[src], fill)
 
                 a2a = partial(jax.lax.all_to_all, axis_name="shard",
                               split_axis=0, concat_axis=0, tiled=True)
-                recv_vecs = a2a(scatter(succ_store, 0).reshape(
-                    n, CAP, Wr)).reshape(R, Wr)
-                recv_dedup = a2a(scatter(dedup_fps, sentinel).reshape(
-                    n, CAP)).reshape(R)
-                recv_path = a2a(scatter(path_fps, sentinel).reshape(
-                    n, CAP)).reshape(R)
-                recv_parent = a2a(scatter(parent_fps, sentinel).reshape(
-                    n, CAP)).reshape(R)
-                recv_ebits = a2a(scatter(child_ebits, 0).reshape(
-                    n, CAP)).reshape(R)
+                recv_vecs = a2a(bucket(succ_store, 0).reshape(
+                    n, CAP, Wr)).reshape(RR, Wr)
+                recv_dedup = a2a(bucket(dedup_fps, sentinel).reshape(
+                    n, CAP)).reshape(RR)
+                recv_path = a2a(bucket(path_fps, sentinel).reshape(
+                    n, CAP)).reshape(RR)
+                recv_parent = a2a(bucket(parent_fps, sentinel).reshape(
+                    n, CAP)).reshape(RR)
+                recv_ebits = a2a(bucket(child_ebits, 0).reshape(
+                    n, CAP)).reshape(RR)
 
             new_mask, new_count, cand_count, visited, wave_rounds = (
                 dedup_and_insert_counted(recv_dedup, visited, capacity))
@@ -314,7 +361,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                     err_col = (new_vecs[:, err_lane] if layout is None
                                else layout.lane(new_vecs, err_lane))
                     err = err | jnp.any((err_col != 0)
-                                        & (jnp.arange(R) < new_count))
+                                        & (jnp.arange(RR) < new_count))
                 vecs_a = jax.lax.dynamic_update_slice(
                     vecs_a, new_vecs, (tail, jnp.int64(0)))
                 fps_a = jax.lax.dynamic_update_slice(
@@ -325,21 +372,28 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                     eb_a, recv_ebits[comp], (tail,))
 
             nc = new_count.astype(jnp.int64)
+            # A wave's successors and sent rows count in its first round.
             succ_all, cand_all, sent_all = jax.lax.psum(jnp.stack(
-                [succ_count, cand_count.astype(jnp.int64), sent]), "shard")
-            # A wave waits for its slowest shard at the next exchange:
+                [jnp.where(first, succ_count, 0),
+                 cand_count.astype(jnp.int64),
+                 jnp.where(first, sent, 0)]), "shard")
+            # A round waits for its slowest shard at the next exchange:
             # the mesh's rounds are each loop's most on any shard.
             wave_rounds = jax.lax.pmax(jnp.stack(wave_rounds), "shard")
+            last = r + 1 >= n_rounds
             return (vecs_a, fps_a, par_a, eb_a, visited,
-                    jnp.minimum(head + B, tail), tail + nc, occ + nc,
+                    jnp.where(last, jnp.minimum(head + B, wave_tail), head),
+                    tail + nc, occ + nc,
                     succ_total + succ_all, cand_total + cand_all,
-                    sent_total + sent_all, err, disc, waves + 1,
-                    tuple(r + wave_rounds[i] for i, r in enumerate(rounds)),
+                    sent_total + sent_all, err, disc,
+                    waves + last.astype(jnp.int64),
+                    tuple(k + wave_rounds[i] for i, k in enumerate(rounds)),
+                    x_rounds + 1, jnp.where(last, 0, r + 1), wave_tail,
                     target)
 
         def cond(carry):
             (_, _, _, _, _, head, tail, occ, succ_total, _cand, _sent, err,
-             disc, waves, _rounds, target) = carry
+             disc, waves, _rounds, _x_rounds, r, _wave_tail, target) = carry
             # Every operand is either replicated (succ_total, disc,
             # waves, target) or globally reduced, so all shards agree.
             # The TPU lowers 64-bit all-reduces for sums only, so the
@@ -350,11 +404,13 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             worst_occ = jax.lax.pmax(occ.astype(jnp.int32), "shard")
             any_err = jax.lax.pmax(err.astype(jnp.int32), "shard") > 0
             more = (waves < K) & (live > 0) & ~any_err
-            more = more & (worst_tail + R <= ucap)
+            more = more & (worst_tail + WIN <= ucap)
             more = more & (worst_occ + R <= capacity // 2)
             if Pn:
                 more = more & ~jnp.all(disc != sentinel)
-            return more & (succ_total < target)
+            # A wave's later rounds always run: the loop stops between
+            # waves (r is the same on every shard).
+            return (r > 0) | (more & (succ_total < target))
 
         def local(vecs_a, fps_a, par_a, eb_a, visited, disc, stats_in):
             # Per-shard views: vecs_a [U, W], visited [capacity],
@@ -368,14 +424,16 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             succ_total = stats_in[0, ST_SUCC]
             cand_total = stats_in[0, ST_CAND]
             target = stats_in[0, ST_TARGET]
-            # Waves, loop rounds and rows sent count per dispatch.
+            # Waves, loop rounds, rows sent and exchange rounds count
+            # per dispatch; a dispatch starts and ends between waves.
             carry = (vecs_a, fps_a, par_a, eb_a, visited, head, tail,
                      occ, succ_total, cand_total, jnp.zeros((), jnp.int64),
                      stats_in[0, ST_ERR] != 0, disc,
-                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2, target)
+                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2,
+                     jnp.int32(0), jnp.int32(0), tail, target)
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
              succ_total, cand_total, sent, err, disc, waves, rounds,
-             _) = jax.lax.while_loop(cond, wave, carry)
+             x_rounds, *_) = jax.lax.while_loop(cond, wave, carry)
             local_rounds, probe_rounds = (r.astype(jnp.int64)
                                           for r in rounds)
             # Discovery slots (replicated) ride in each shard's stats row
@@ -383,7 +441,8 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             stats = jnp.concatenate([
                 jnp.stack([head, tail, occ, succ_total, cand_total,
                            target, err.astype(jnp.int64), waves,
-                           probe_rounds, local_rounds, sent]),
+                           probe_rounds, local_rounds, sent,
+                           x_rounds.astype(jnp.int64)]),
                 jax.lax.bitcast_convert_type(disc, jnp.int64)])[None]
             return vecs_a, fps_a, par_a, eb_a, visited, disc, stats
 
@@ -600,6 +659,8 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                 now = time.monotonic()
                 self.wave_log.append((now, self._state_count))
                 waves = int(stats_h[0, ST_WAVES])
+                x_rounds = int(stats_h[0, ST_EXCHANGE_ROUNDS])
+                cap = exchange_bucket_rows(meta["bucket"] * F, n)
                 # Unified wave event (obs schema): deltas vs the last
                 # processed dispatch; load factor is the fullest
                 # shard's table slice (the growth-gating quantity).
@@ -617,16 +678,18 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                     probe_rounds=int(stats_h[0, ST_PROBE_ROUNDS]),
                     dedup_rounds=int(stats_h[0, ST_DEDUP_ROUNDS]),
                     # v17: the rows those rounds carried, each round
-                    # over a chunk of a shard's n*B*F received rows.
+                    # over a chunk of the n*CAP rows a shard receives in
+                    # an exchange round.
                     probe_slots=int(stats_h[0, ST_PROBE_ROUNDS])
-                    * probe_chunk(n * meta["bucket"] * F),
+                    * probe_chunk(n * cap),
                     host_s=launch_s + (now - t_proc) - waited,
                     # v16: successor rows sent to another shard, and the
-                    # rows the all-to-alls carry between shards (each
-                    # shard's n-1 off-shard buckets of B*F rows a wave).
+                    # rows the all-to-alls carried between shards (each
+                    # shard's n-1 off-shard buckets of CAP rows a round).
                     exchange_rows=int(stats_h[0, ST_EXCHANGE_ROWS]),
-                    exchange_slots=waves * n * (n - 1) * meta["bucket"]
-                    * F,
+                    exchange_slots=x_rounds * n * (n - 1) * cap,
+                    # v18: the exchange rounds the dispatch's waves took.
+                    exchange_rounds=x_rounds,
                     # Frontier rows consumed across every shard (the
                     # kernel-occupancy numerator).
                     rows=int((heads - heads_prev).sum()),
@@ -699,9 +762,12 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             bucket = pick_bucket(
                 self._buckets,
                 int((self._shard_tails - self._shard_heads).max()))
+            # A wave admits at most R_b rows and writes at most W_b past
+            # a shard's tail (the dispatch's cond holds the same bounds).
             R_b = n * bucket * F
+            W_b = exchange_window_rows(bucket * F, n)
             growth = (int(occs.max()) + R_b > self._capacity // 2
-                      or int(self._shard_tails.max()) + R_b > ucap)
+                      or int(self._shard_tails.max()) + W_b > ucap)
             ckpt_due = (self._ckpt_path is not None
                         and (self._unique_count - last_ckpt_states
                              >= self._ckpt_every * self._B))
@@ -729,7 +795,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                                                       new_cap)(visited)
                             self._capacity = new_cap
                             self._visited = visited
-                        while int(self._shard_tails.max()) + R_b > ucap:
+                        while int(self._shard_tails.max()) + W_b > ucap:
                             budget = self._store.device_budget \
                                 if self._store.active else None
                             over = (budget is not None

@@ -24,6 +24,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 import chip_smoke
 from benchmark.trace_stages import STAGES, stage_of
 from stateright_tpu.tpu import engine
+from stateright_tpu.tpu.sharded_fused import exchange_bucket_rows
 from two_phase_commit import TwoPhaseSys
 
 
@@ -195,9 +196,10 @@ def test_fused_probe_loop_carries_one_chunk(fused_programs):
 
 
 #: sha256 of the twopc11-check-4chip dispatch compiled for the
-#: described 2x2, its text without metadata (``_hlo_digest``)
-TWOPC11_MESH_HLO_SHA256 = ("c25c870d02fe0bcc55ace87b3c1e1935"
-                           "485515e9a4baacf815129ffaac3d8b7e")
+#: described 2x2, its text without metadata (``_hlo_digest``): the
+#: program as the sized exchange buckets left it
+TWOPC11_MESH_HLO_SHA256 = ("6e71a2eef168b3a88f6d496bfe702eb0"
+                           "9e2ae0a24efaffd2386e12bdf713d847")
 
 
 def test_twopc11_mesh_dispatch_hlo_is_unchanged(twopc11_mesh_program):
@@ -224,8 +226,10 @@ def test_twopc11_mesh_dispatch_names_its_stages(twopc11_mesh_program):
 
 def test_twopc11_mesh_wave_has_one_local_dedup_loop(twopc11_mesh_program):
     """A wave's edge in a trace is its one top-level ``while`` in scope
-    ``local_dedup`` (``benchmark/trace_stages.py``): the owner's. The
-    sender side's duplicate collapse runs under ``exchange``."""
+    ``local_dedup`` (``benchmark/trace_stages.py``): the owner's, one a
+    trip of the dispatch loop, which is one exchange round (a whole
+    wave where its buckets fit one round). The sender side's duplicate
+    collapse runs under ``exchange``."""
     loops = _ops(twopc11_mesh_program.as_text(), "while")
     assert [p for p in loops if stage_of(p) == "local_dedup"] == [
         "jit(local)/shard_map/while/body/local_dedup/while"]
@@ -238,6 +242,28 @@ def test_twopc11_mesh_probe_loop_carries_one_chunk(twopc11_mesh_program):
     candidates, not all n*B*F of them."""
     assert _probe_loop_gather_rows(twopc11_mesh_program.as_text()) == {
         engine.PROBE_CHUNK}
+
+
+def test_twopc11_mesh_all_to_alls_carry_one_bucket_per_owner(
+        twopc11_mesh_program):
+    """Every all-to-all carries the n*CAP rows of one exchange round,
+    CAP the balanced share of a shard's B*F successors, not n*B*F; and
+    the buckets are gathered: no scatter sits under ``exchange`` but the
+    sender-side collapse loop's own."""
+    n, (batch, _, _) = 4, TWOPC11_SHARD
+    succ = batch * TwoPhaseSys(11).device_model().max_fanout
+    cap = exchange_bucket_rows(succ, n)
+    assert cap == succ // n
+    text = twopc11_mesh_program.as_text()
+    shapes = re.findall(r"= [a-z0-9]+\[([\d,]+)\][^\n]*? all-to-all\(",
+                        text)
+    assert len(shapes) >= 5
+    for dims in shapes:
+        dims = [int(d) for d in dims.split(",")]
+        assert dims[0] == n and cap in dims[1:], dims
+    scatters = [p for p in _ops(text, "scatter")
+                if "exchange" in p.split("/")]
+    assert all("/exchange/while/" in p for p in scatters), scatters
 
 
 def test_twopc11_mesh_all_to_alls_sit_in_exchange(twopc11_mesh_program):

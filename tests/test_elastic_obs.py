@@ -368,9 +368,33 @@ def test_lint_probe_slots_match_rounds(rounds, slots, ok):
 def test_lint_v16_wave_has_no_probe_slots():
     line = json.loads(_worker_wave("w0", 1))
     line["schema_version"] = 16
+    del line["exchange_rounds"]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert any("probe_slots" in e for e in errors), errors
     del line["probe_slots"]
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
+    assert not errors, errors
+
+
+@pytest.mark.parametrize("rounds,slots,waves,ok", [
+    (None, None, 1, True), (2, 2 * 3 * 8, 2, True), (3, 3 * 3 * 8, 2, True),
+    (0, 0, 0, True), (2, None, 2, False), (None, 48, 2, False),
+    (1, 24, 2, False)])
+def test_lint_exchange_rounds_match_slots(rounds, slots, waves, ok):
+    """v18: exchange rounds are counted where the exchange's slots are,
+    and every wave of an exchanging dispatch runs at least one round."""
+    line = _worker_wave("w0", 1, exchange_rounds=rounds,
+                        exchange_slots=slots, waves=waves)
+    _, errors = trace_lint.lint_lines([line])
+    assert (not errors) == ok, errors
+
+
+def test_lint_v17_wave_has_no_exchange_rounds():
+    line = json.loads(_worker_wave("w0", 1))
+    line["schema_version"] = 17
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
+    assert any("exchange_rounds" in e for e in errors), errors
+    del line["exchange_rounds"]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert not errors, errors
 

@@ -14,7 +14,9 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "examples"))
 
-from stateright_tpu.tpu.sharded_fused import ShardedFusedTpuBfsChecker
+from stateright_tpu.tpu.sharded_fused import (ShardedFusedTpuBfsChecker,
+                                              exchange_bucket_rows,
+                                              exchange_window_rows)
 from stateright_tpu.tpu.sharded import ShardedTpuBfsChecker
 from two_phase_commit import TwoPhaseSys
 
@@ -34,12 +36,62 @@ def test_matches_classic_sharded_engine_bit_for_bit():
         sharded=True, batch_size=32).join()
     assert isinstance(classic, ShardedTpuBfsChecker)
     assert not isinstance(classic, ShardedFusedTpuBfsChecker)
+    # every wave's buckets fit one exchange round: the owners receive
+    # the rows in the classic engine's shard-major order
+    assert all(e["exchange_rounds"] == e["waves"]
+               for e in fused.dispatch_log)
     assert fused.unique_state_count() == classic.unique_state_count()
     assert fused.state_count() == classic.state_count()
     assert set(fused.discoveries()) == set(classic.discoveries())
     for name in fused.discoveries():
         assert (fused.discovery(name).encode()
                 == classic.discovery(name).encode())
+
+
+@pytest.mark.parametrize("rows,shards", [
+    (4096 * 57, 4), (8 * 22, 4), (32 * 14, 8), (100, 3)])
+def test_exchange_buckets_and_window(rows, shards):
+    """A bucket is the balanced share, a multiple of 8, so one round
+    carries evenly spread rows and a wave takes at most ``shards``
+    rounds. Round r appends a full ``shards * CAP``-row window after at
+    most ``shards * min(rows, r * CAP)`` rows admitted before it: the
+    window a wave may write is the worst of those, ``shards * rows``
+    only where CAP divides ``rows``."""
+    cap = exchange_bucket_rows(rows, shards)
+    assert cap % 8 == 0 and rows <= shards * cap < rows + 8 * shards
+    rounds = -(-rows // cap)
+    assert rounds <= shards
+    worst = max(shards * (min(rows, r * cap) + cap) for r in range(rounds))
+    assert exchange_window_rows(rows, shards) == worst >= shards * rows
+    assert (worst == shards * rows) == (rows % cap == 0)
+
+
+def test_waves_of_several_exchange_rounds(monkeypatch):
+    """Buckets of 8 rows send a wave's successors home in several
+    rounds, each deduplicated, probed and appended by its owner: the
+    admitted states, the counts and the discoveries stay the host
+    checker's, and every discovery path replays."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from stateright_tpu.tpu import sharded_fused
+
+    monkeypatch.setattr(sharded_fused, "exchange_bucket_rows",
+                        lambda rows, shards: 8)
+    model = TwoPhaseSys(4)
+    host = model.checker().spawn_bfs().join()
+    c = model.checker().spawn_tpu_bfs(
+        fused=True, batch_size=32,
+        mesh=Mesh(np.array(jax.devices()[:4]), ("shard",))).join()
+    log = [e for e in c.dispatch_log if e["waves"]]
+    assert (sum(e["exchange_rounds"] for e in log)
+            > sum(e["waves"] for e in log))
+    assert c.unique_state_count() == host.unique_state_count()
+    assert c.state_count() == host.state_count()
+    assert set(c.discoveries()) == set(host.discoveries())
+    for name, path in c.discoveries().items():
+        assert model.property(name).condition(model, path.last_state())
 
 
 def test_on_device_growth_paths():

@@ -11,8 +11,9 @@
   profiler annotations, on the host plane of a profiler session; with
   no session and ``STpu_TRACE`` unset they record nothing.
 - **The sharded-fused engine** names the same stages plus ``exchange``,
-  counts its slowest shard's rounds and the rows it sends between
-  shards, and opens the same spans, with results unchanged.
+  counts its slowest shard's rounds, its exchange rounds and the rows it
+  sends between shards, and opens the same spans, with results
+  unchanged.
 """
 
 import glob
@@ -29,6 +30,8 @@ sys.path.insert(0, os.path.join(
 from stateright_tpu.obs import NULL_TRACER  # noqa: E402
 from stateright_tpu.tpu.engine import probe_chunk  # noqa: E402
 from stateright_tpu.tpu.hashing import SENTINEL  # noqa: E402
+from stateright_tpu.tpu.sharded_fused import (  # noqa: E402
+    exchange_bucket_rows)
 from two_phase_commit import TwoPhaseSys  # noqa: E402
 
 STAGES = ("load", "properties", "expand", "fingerprint", "local_dedup",
@@ -146,17 +149,23 @@ def test_sharded_dispatch_counts_rounds_and_exchange(mesh_pair):
         # a wave waits for its slowest shard: at least one round each
         for key in ("probe_rounds", "dedup_rounds"):
             assert e["waves"] <= e[key] <= e["candidates"], (key, e)
-        assert e["exchange_slots"] == (e["waves"] * n * (n - 1)
-                                       * e["bucket"] * c._F)
-        # a shard sends at most its B*F successors into (n-1)*B*F slots
-        assert 0 < e["exchange_rows"] <= e["exchange_slots"] // (n - 1)
+        # every wave runs at least one exchange round, at most n
+        assert e["waves"] <= e["exchange_rounds"] <= n * e["waves"], e
+        # each round carries n-1 off-shard buckets of CAP rows a shard
+        cap = exchange_bucket_rows(e["bucket"] * c._F, n)
+        assert e["exchange_slots"] == (e["exchange_rounds"] * n * (n - 1)
+                                       * cap)
+        # a shard sends at most its B*F successors a wave, each in a slot
+        assert 0 < e["exchange_rows"] <= e["exchange_slots"]
+        assert e["exchange_rows"] <= e["waves"] * n * e["bucket"] * c._F
         assert e["exchange_rows"] <= e["candidates"]
-        # the slowest shard's rounds, each over a chunk of its n*B*F
-        # received rows
+        # the slowest shard's rounds, each over a chunk of the n*CAP
+        # rows it receives in an exchange round
         assert e["probe_slots"] == e["probe_rounds"] * probe_chunk(
-            n * e["bucket"] * c._F), e
+            n * cap), e
     assert all(e["probe_rounds"] == e["dedup_rounds"] == e["probe_slots"]
-               == e["exchange_rows"] == e["exchange_slots"] == 0
+               == e["exchange_rows"] == e["exchange_slots"]
+               == e["exchange_rounds"] == 0
                for e in c.dispatch_log if not e["waves"])
     assert all(e["host_s"] >= 0 for e in c.dispatch_log)
 

@@ -118,6 +118,11 @@ Schema v17 (the chunked probe) adds ``probe_slots`` to wave events,
 null exactly where ``probe_rounds`` is and never below it: every
 counted round carries at least one row.
 
+Schema v18 (the sized exchange buckets) adds ``exchange_rounds`` to
+wave events, null exactly where ``exchange_slots`` is and never below
+``waves``: every wave of an exchanging dispatch runs at least one
+round.
+
 Schema v6 (the tiered state store) adds three more: every FRONTIER
 ``spill`` is eventually followed by a ``page_in`` or the producing
 run's end (a stream that stops with paged-out frontier blocks
@@ -599,6 +604,21 @@ def lint_lines(lines) -> Tuple[Dict[str, int], List[str]]:
                     f"line {lineno}: wave probe_slots {slots!r} against "
                     f"probe_rounds {rounds!r}: each counted round "
                     "carries at least one row")
+            # v18: the exchange's rounds are counted where its slots
+            # are, and every wave runs at least one.
+            x_rounds = obj.get("exchange_rounds")
+            x_slots, waves = obj.get("exchange_slots"), obj.get("waves")
+            if (isinstance(obj.get("schema_version"), int)
+                    and obj["schema_version"] >= 18
+                    and ((x_rounds is None) != (x_slots is None)
+                         or (isinstance(x_rounds, int)
+                             and isinstance(waves, int)
+                             and x_rounds < waves))):
+                errors.append(
+                    f"line {lineno}: wave exchange_rounds {x_rounds!r} "
+                    f"against exchange_slots {x_slots!r} and waves "
+                    f"{waves!r}: each wave of an exchange runs at least "
+                    "one round")
             # v9 attribution window (wave multiplexing): a TOTAL mux
             # wave (job_id null, jobs_in_wave set) opens a window that
             # exactly jobs_in_wave attributed lines must close, their
