@@ -198,6 +198,13 @@ class PaxosModelCfg:
     #: clients never retry, so dueling proposers can wedge (the classic
     #: Paxos liveness caveat; FLP). BASELINE.json config 5.
     liveness: bool = False
+    #: checks under client symmetry: the model is ``symmetric``, so
+    #: ``checker()`` returns a ``CheckerBuilder`` with ``.symmetry()``
+    #: on and the device and native engines dedup by
+    #: ``RegisterWorkloadDevice.representative`` (clients exchanged
+    #: within their residue class mod the server count). BASELINE.json
+    #: config 5.
+    symmetry: bool = False
 
     def into_model(self) -> ActorModel:
         def value_chosen(_model, state):
@@ -235,6 +242,7 @@ class PaxosModelCfg:
                                liveness=self.liveness)
 
         model.device_model = device_model
+        model.symmetric = self.symmetry
         return model
 
 

@@ -33,6 +33,8 @@ class CheckerBuilder:
         self._target_state_count: Optional[int] = None
         self._thread_count = 1
         self._visitor = None
+        if getattr(model, "symmetric", False):
+            self.symmetry()
 
     def spawn_bfs(self) -> Checker:
         """Spawns a breadth-first checker: more memory than DFS but finds

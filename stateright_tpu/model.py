@@ -95,6 +95,13 @@ class Model(Generic[State, Action]):
     3
     """
 
+    #: Whether the model is checked under symmetry reduction: its states
+    #: implement ``representative()``, and ``checker()`` returns a
+    #: builder with ``.symmetry()`` on. Engines that build from the
+    #: model itself read it too (the job service keeps such a model out
+    #: of a multiplexed group, which has no symmetry path).
+    symmetric: bool = False
+
     def init_states(self) -> List[State]:
         """Returns the initial possible states."""
         raise NotImplementedError

@@ -308,7 +308,7 @@ class _WorkerRuntime:
             conds = eval_properties(prop_fns, vecs)
             succ_flat, sflat, succ_count, terminal = expand_frontier(
                 dm, vecs, valid)
-            dedup_fps, path_fps = fingerprint_successors(
+            dedup_fps, path_fps, _ = fingerprint_successors(
                 dm, succ_flat, sflat, use_sym)
             cleared = ebits
             for i in eventually_device:
@@ -957,7 +957,6 @@ class ElasticChecker:
         self._transport = transport
         self._lease_s = float(lease_s)
         self._hb_s = float(heartbeat_s)
-        self._symmetry = bool(symmetry)
         self._target = target_state_count
         self._resume_from = resume_from
         self._kill_at = dict(kill_at or {})
@@ -966,6 +965,10 @@ class ElasticChecker:
         self._cmd_timeout = float(command_timeout_s)
 
         self._model = model_factory()
+        # a symmetric model is checked under symmetry whatever the
+        # argument says, as its ``checker()`` is
+        self._symmetry = bool(symmetry) or bool(
+            getattr(self._model, "symmetric", False))
         self._dm = self._model.device_model()
         self._W = self._dm.state_width
         from ..model import Expectation

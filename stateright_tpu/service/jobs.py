@@ -624,8 +624,11 @@ class JobService:
         ``_MUX_CAPABLE = False``), and only performance-schedule knobs
         — notably NOT ``target_state_count``, whose wave-granular early
         stop would make residual counts depend on who shared the wave
-        (the solo-identity contract would silently break)."""
+        (the solo-identity contract would silently break) — and no
+        ``symmetric`` model: a group checks the raw space only."""
         if not self._mux or job.spec["engine"] != "classic":
+            return False
+        if getattr(job.model, "symmetric", False):
             return False
         try:
             from ..tpu.engine import TpuBfsChecker

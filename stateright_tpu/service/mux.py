@@ -218,6 +218,9 @@ class MuxGroup:
         bad = set(knobs) - MUX_KNOBS
         if bad:
             raise ValueError(f"knobs {sorted(bad)} are not mux-eligible")
+        if getattr(model, "symmetric", False):
+            raise ValueError("a symmetric model is not mux-eligible: a "
+                             "group checks the raw space only")
         self._model = model
         dm = model.device_model()
         self._dm = dm

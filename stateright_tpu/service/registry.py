@@ -133,12 +133,13 @@ def _twopc(rm_count):
     return TwoPhaseSys(rm_count)
 
 
-def _paxos(client_count, server_count):
+def _paxos(client_count, server_count, liveness, symmetry):
     _examples()
     from paxos import PaxosModelCfg
 
     return PaxosModelCfg(client_count=client_count,
-                         server_count=server_count).into_model()
+                         server_count=server_count, liveness=liveness,
+                         symmetry=symmetry).into_model()
 
 
 def _increment(thread_count):
@@ -220,8 +221,11 @@ def default_registry() -> ModelRegistry:
         r.register("twopc", _twopc, {"rm_count": 3},
                    "two-phase commit (Gray & Lamport TLA+ subset)")
         r.register("paxos", _paxos,
-                   {"client_count": 2, "server_count": 3},
-                   "single-decree Paxos with linearizability history")
+                   {"client_count": 2, "server_count": 3,
+                    "liveness": False, "symmetry": False},
+                   "single-decree Paxos with linearizability history "
+                   "(liveness: adds \"eventually chosen\"; symmetry: "
+                   "clients exchanged within their residue class)")
         r.register("increment", _increment, {"thread_count": 3},
                    "racy read-inc-write counter (finds the lost update)")
         r.register("increment_lock", _increment_lock,

@@ -1788,7 +1788,7 @@ def build_wave(dm: DeviceModel, batch_size: int, capacity: int,
         conds = eval_properties(prop_fns, reg)
         succ_flat, sflat, succ_count, terminal = expand_frontier(
             dm, reg, valid)
-        dedup_fps, path_fps = fingerprint_successors(
+        dedup_fps, path_fps, _ = fingerprint_successors(
             dm, succ_flat, sflat, use_sym)
         new_mask, new_count, cand_count, merged, _ = (
             dedup_and_insert_counted(dedup_fps, visited, capacity))
@@ -1929,7 +1929,7 @@ def build_regather(dm: DeviceModel, batch_size: int, out_rows: int,
         if layout is not None:
             vecs = layout.unpack(vecs)
         succ_flat, sflat, _, _ = expand_frontier(dm, vecs, valid)
-        _, path_fps = fingerprint_successors(dm, succ_flat, sflat,
+        _, path_fps, _ = fingerprint_successors(dm, succ_flat, sflat,
                                              use_sym)
         comp = compaction_order(new_mask)[:K]
         new_vecs = succ_flat[comp]
@@ -1980,17 +1980,24 @@ def expand_frontier(dm: DeviceModel, vecs, valid):
 @jax.named_scope("fingerprint")
 def fingerprint_successors(dm: DeviceModel, succ_flat, valid_flat,
                            use_sym: bool):
-    """``(dedup_fps, path_fps)``: under symmetry, dedup by the
+    """``(dedup_fps, path_fps, folded)``: under symmetry, dedup by the
     representative's fingerprint but continue paths with the original
-    state's (the dfs.rs:258-267 rule). Invalid rows carry the sentinel."""
+    state's (the dfs.rs:258-267 rule). Invalid rows carry the sentinel.
+    ``folded`` counts the valid successors whose representative is not
+    the successor itself, those the group action moved (int32; None
+    without symmetry)."""
     if use_sym:
-        dedup_fps = device_fp64(jax.vmap(dm.representative)(succ_flat))
+        reps = jax.vmap(dm.representative)(succ_flat)
+        dedup_fps = device_fp64(reps)
         path_fps = device_fp64(succ_flat)
+        folded = jnp.sum(valid_flat & jnp.any(reps != succ_flat, axis=1),
+                         dtype=jnp.int32)
     else:
         dedup_fps = device_fp64(succ_flat)
         path_fps = dedup_fps
+        folded = None
     dedup_fps = jnp.where(valid_flat, dedup_fps, jnp.uint64(SENTINEL))
-    return dedup_fps, path_fps
+    return dedup_fps, path_fps, folded
 
 
 def compaction_order(mask):

@@ -64,11 +64,30 @@ __all__ = ["FusedTpuBfsChecker", "FusedUnsupported"]
 # collapse telemetry); ``PROBE_ROUNDS``/``DEDUP_ROUNDS`` are the rounds
 # the dispatch's table-probe and local-dedup loops ran, summed over its
 # waves (per dispatch, like ``WAVES``); discovery fingerprints are
-# bitcast into the tail slots (they also travel as a separate donated
-# array between dispatches).
+# bitcast into the slots after ``ST_DISC`` (they also travel as a
+# separate donated array between dispatches). Programs built with
+# symmetry, or with an eventually property, append their per-dispatch
+# counters after the discovery slots (``stat_counters``), so the other
+# programs' stats vector keeps its length.
 (ST_HEAD, ST_TAIL, ST_OCC, ST_SUCC, ST_CAND, ST_TARGET, ST_ERR,
  ST_WAVES, ST_PROBE_ROUNDS, ST_DEDUP_ROUNDS) = range(10)
 ST_DISC = 10
+
+
+#: the optional per-dispatch counters (schema v19)
+STAT_COUNTERS = ("sym_folded", "eventually_pending")
+
+
+def stat_counters(use_sym: bool, properties) -> tuple:
+    """The optional counters a dispatch program carries, in stats-vector
+    order: ``sym_folded`` (successors the symmetry group moved to
+    another representative) where it dedups under symmetry,
+    ``eventually_pending`` (admitted rows with an eventually bit still
+    set) where a property is an eventually one."""
+    names = ("sym_folded",) if use_sym else ()
+    if any(p.expectation is Expectation.EVENTUALLY for p in properties):
+        names += ("eventually_pending",)
+    return names
 
 
 class FusedUnsupported(TypeError):
@@ -205,6 +224,7 @@ class FusedTpuBfsChecker(TpuBfsChecker):
         sentinel = jnp.uint64(SENTINEL)
         err_lane = dm.error_lane
         ebits_masks = [jnp.uint32(1 << i) for i in range(P)]
+        counters = stat_counters(use_sym, properties)
 
         def first_hit(disc_i, hit, bfps):
             """Keeps the first (frontier-order) hit's fingerprint, set
@@ -215,7 +235,8 @@ class FusedTpuBfsChecker(TpuBfsChecker):
 
         def wave(carry):
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
-             succ_total, cand_total, err, disc, waves, rounds) = carry
+             succ_total, cand_total, err, disc, waves, rounds,
+             counts) = carry
             with jax.named_scope("load"):
                 idx = head + jnp.arange(B, dtype=jnp.int64)
                 valid = idx < tail
@@ -240,8 +261,9 @@ class FusedTpuBfsChecker(TpuBfsChecker):
 
             succ_flat, sflat, succ_count, terminal = expand_frontier(
                 dm, bvecs, valid)
-            dedup_fps, path_fps = fingerprint_successors(
+            dedup_fps, path_fps, folded = fingerprint_successors(
                 dm, succ_flat, sflat, use_sym)
+            wave_counts = {"sym_folded": folded}
             new_mask, new_count, cand_count, visited, wave_rounds = (
                 dedup_and_insert_counted(dedup_fps, visited, capacity))
 
@@ -285,6 +307,10 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                 par_a = jax.lax.dynamic_update_slice(par_a, new_parent,
                                                      start)
                 eb_a = jax.lax.dynamic_update_slice(eb_a, new_ebits, start)
+                if "eventually_pending" in counters:
+                    wave_counts["eventually_pending"] = jnp.sum(
+                        (new_ebits != 0) & (jnp.arange(S) < new_count),
+                        dtype=jnp.int32)
 
             nc = new_count.astype(jnp.int64)
             return (vecs_a, fps_a, par_a, eb_a, visited,
@@ -292,11 +318,13 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     succ_total + succ_count,
                     cand_total + cand_count.astype(jnp.int64), err, disc,
                     waves + 1,
-                    tuple(r + w for r, w in zip(rounds, wave_rounds)))
+                    tuple(r + w for r, w in zip(rounds, wave_rounds)),
+                    tuple(c + wave_counts[n]
+                          for c, n in zip(counts, counters)))
 
         def cond(carry):
             (_, _, _, _, _, head, tail, occ, succ_total, _cand, err,
-             disc, waves, _rounds, target) = carry
+             disc, waves, _rounds, _counts, target) = carry
             more = (waves < K) & (head < tail) & ~err
             more = more & (tail + S <= ucap)
             more = more & (occ + S <= capacity // 2)
@@ -317,19 +345,24 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                                       ST_SUCC, ST_CAND, ST_TARGET))
             carry = (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
                      succ_total, cand_total, stats_in[ST_ERR] != 0, disc,
-                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2, target)
+                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2,
+                     (jnp.int32(0),) * len(counters), target)
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
-             succ_total, cand_total, err, disc, waves, rounds,
+             succ_total, cand_total, err, disc, waves, rounds, counts,
              _) = jax.lax.while_loop(cond, wave_t, carry)
             # Discovery slots ride in the stats vector (bitcast, so the
             # SENTINEL survives) — one host fetch per dispatch, not two.
             local_rounds, probe_rounds = (r.astype(jnp.int64)
                                           for r in rounds)
-            stats = jnp.concatenate([
+            stats = [
                 jnp.stack([head, tail, occ, succ_total, cand_total,
                            target, err.astype(jnp.int64), waves,
                            probe_rounds, local_rounds]),
-                jax.lax.bitcast_convert_type(disc, jnp.int64)])
+                jax.lax.bitcast_convert_type(disc, jnp.int64)]
+            if counters:
+                stats.append(jnp.stack([c.astype(jnp.int64)
+                                        for c in counts]))
+            stats = jnp.concatenate(stats)
             return vecs_a, fps_a, par_a, eb_a, visited, disc, stats
 
         # stats_in is NOT donated: the host reads dispatch k's stats
@@ -340,7 +373,7 @@ class FusedTpuBfsChecker(TpuBfsChecker):
             sds((ucap, Wr), jnp.uint32), sds((ucap,), jnp.uint64),
             sds((ucap,), jnp.uint64), sds((ucap,), jnp.uint32),
             sds((capacity,), jnp.uint64), sds((max(P, 1),), jnp.uint64),
-            sds((ST_DISC + max(P, 1),), jnp.int64)))
+            sds((ST_DISC + max(P, 1) + len(counters),), jnp.int64)))
         return jitted
 
     def _grow_fn(self, old_cap: int, new_cap: int, dtype, width: int = 0):
@@ -453,7 +486,11 @@ class FusedTpuBfsChecker(TpuBfsChecker):
         F, W = self._F, self._Wrow  # storage row width (packed form)
         properties = self._properties
         P = len(properties)
-        L = ST_DISC + max(P, 1)
+        counters = stat_counters(self._use_symmetry, properties)
+        # the counters' slots, after the discovery slots
+        counter_at = {n: ST_DISC + max(P, 1) + i
+                      for i, n in enumerate(counters)}
+        L = ST_DISC + max(P, 1) + len(counters)
 
         # Seed the arena from the pending blocks (fresh init states, or a
         # checkpoint's frontier). Parents of these rows are already known
@@ -583,6 +620,11 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     probe_slots=(int(stats_h[ST_PROBE_ROUNDS])
                                  * probe_chunk(meta["bucket"] * self._F)),
                     host_s=launch_s + (now - t_proc) - waited,
+                    # v19: what symmetry and the eventually bits did
+                    # (null where the program does not count them).
+                    **{n: (int(stats_h[counter_at[n]])
+                           if n in counter_at else None)
+                       for n in STAT_COUNTERS},
                     # Frontier rows this dispatch consumed (the head
                     # advance) — the kernel-occupancy numerator.
                     rows=head - head_prev,

@@ -246,7 +246,7 @@ class ShardedTpuBfsChecker(EpochOwnership, TpuBfsChecker):
             conds = eval_properties(prop_fns, vecs)
             succ_flat, sflat, succ_count, terminal = expand_frontier(
                 dm, vecs, valid)
-            dedup_fps, path_fps = fingerprint_successors(
+            dedup_fps, path_fps, _ = fingerprint_successors(
                 dm, succ_flat, sflat, use_sym)
             parent_fps = jnp.repeat(fps, F)
             # Children inherit the parent's ebits *after* clearing bits for

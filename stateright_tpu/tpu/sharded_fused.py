@@ -274,7 +274,7 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
 
             succ_flat, sflat, succ_count, terminal = expand_frontier(
                 dm, bvecs, valid)
-            dedup_fps, path_fps = fingerprint_successors(
+            dedup_fps, path_fps, _ = fingerprint_successors(
                 dm, succ_flat, sflat, use_sym)
 
             cleared = bebits

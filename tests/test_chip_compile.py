@@ -273,6 +273,37 @@ def test_twopc11_mesh_all_to_alls_sit_in_exchange(twopc11_mesh_program):
                for p in paths)
 
 
+#: BASELINE config 5 as the paxos4-sym-live-check cell builds it
+#: (benchmark/configs/paxos-4-sym-live.json)
+PAXOS4_SYM_LIVE = {"client_count": 4, "server_count": 3,
+                   "liveness": True, "symmetry": True}
+
+
+@pytest.fixture(scope="module")
+def paxos4_sym_live_program(one_chip):
+    """The cell's fused dispatch compiled for the described chip, at
+    B=512 (the cell runs 4096; the loop structure does not depend on
+    B, and 4096 takes about 110 s here) and the cell's table and
+    arena."""
+    from stateright_tpu.service.registry import default_registry
+
+    model, _ = default_registry().build("paxos", PAXOS4_SYM_LIVE)
+    c = _engine(model, fused=True, batch_size=64)
+    assert c._use_symmetry
+    c._aot = _for_chip(one_chip)
+    return c._build_dispatch_fn(512, 1 << 22, 1 << 21)
+
+
+def test_paxos4_sym_live_wave_has_one_local_dedup_loop(
+        paxos4_sym_live_program):
+    """A wave's edge in a trace is its one top-level ``while`` in scope
+    ``local_dedup`` (``benchmark/trace_stages.py``): the symmetry
+    representative and the eventually bits add none."""
+    loops = _ops(paxos4_sym_live_program.as_text(), "while")
+    assert [p for p in loops if stage_of(p) == "local_dedup"] == [
+        "jit(dispatch)/while/body/local_dedup/while"]
+
+
 def test_paxos3_step_compiles_without_loops(one_chip):
     """The register workload's server gather/scatter select over the
     static servers: a traced-offset slice or update under ``vmap``

@@ -365,10 +365,15 @@ def test_lint_probe_slots_match_rounds(rounds, slots, ok):
     assert (not errors) == ok, errors
 
 
+#: the v19 wave keys, absent from older captures
+V19_KEYS = ("sym_folded", "eventually_pending")
+
+
 def test_lint_v16_wave_has_no_probe_slots():
     line = json.loads(_worker_wave("w0", 1))
     line["schema_version"] = 16
-    del line["exchange_rounds"]
+    for key in ("exchange_rounds",) + V19_KEYS:
+        del line[key]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert any("probe_slots" in e for e in errors), errors
     del line["probe_slots"]
@@ -392,9 +397,22 @@ def test_lint_exchange_rounds_match_slots(rounds, slots, waves, ok):
 def test_lint_v17_wave_has_no_exchange_rounds():
     line = json.loads(_worker_wave("w0", 1))
     line["schema_version"] = 17
+    for key in V19_KEYS:
+        del line[key]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert any("exchange_rounds" in e for e in errors), errors
     del line["exchange_rounds"]
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
+    assert not errors, errors
+
+
+def test_lint_v18_wave_has_no_sym_counters():
+    line = json.loads(_worker_wave("w0", 1))
+    line["schema_version"] = 18
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
+    assert any("sym_folded" in e for e in errors), errors
+    for key in V19_KEYS:
+        del line[key]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert not errors, errors
 

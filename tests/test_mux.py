@@ -425,3 +425,59 @@ def test_mux_early_stop_equals_solo_at_effective_width():
     for h in handles:
         assert h.state_count() == solo16.state_count()
         assert h.unique_state_count() == solo16.unique_state_count()
+
+
+#: paxos check 4 under client symmetry: the orbits of its 2,372,188
+#: states (tests/test_paxos_symmetry.py)
+PAXOS4_SYM = {"model": "paxos",
+              "params": {"client_count": 4, "symmetry": True}}
+
+
+def test_a_symmetric_job_runs_solo(tmp_path):
+    """A group has no symmetry path and would check the raw space, so a
+    job whose model checks under symmetry never joins one: with the mux
+    on it is not mux-eligible, a group refuses its model, and the solo
+    engine the service builds for it dedups by the representative. The
+    same job without ``symmetry`` is mux-eligible."""
+    svc = JobService(workers=1, data_dir=str(tmp_path / "svc"),
+                     tenant_quota=1)
+    try:
+        # Pin tenant "t" at its running quota: the jobs stay queued.
+        with svc._queue._cv:
+            svc._queue._active["t"] = 1
+        jobs = {}
+        for sym in (False, True):
+            spec = dict(PAXOS4_SYM, knobs={"batch_size": 64}, tenant="t")
+            spec["params"] = dict(spec["params"], symmetry=sym)
+            jobs[sym] = svc._job(svc.submit(spec)["id"])
+        assert svc._mux_eligible(jobs[False])
+        assert not svc._mux_eligible(jobs[True])
+        with pytest.raises(ValueError, match="symmetric"):
+            MuxGroup(jobs[True].model, knobs={"batch_size": 64})
+        checker = svc._factory(jobs[True])()
+        checker.preempt()
+        checker.join()
+        assert checker._use_symmetry
+        for job in jobs.values():
+            svc.preempt(job.id)
+    finally:
+        svc.close()
+
+
+@pytest.mark.slow
+def test_a_symmetric_job_counts_orbits_with_the_mux_on(tmp_path):
+    """paxos check 4 with ``symmetry`` through the service, mux on:
+    1,194,428 orbits, not the raw space's 2,372,188 states."""
+    svc = JobService(workers=1, data_dir=str(tmp_path / "svc"))
+    try:
+        job = svc.submit(dict(PAXOS4_SYM, knobs={
+            "batch_size": 4096, "table_capacity": 1 << 22}))["id"]
+        deadline = time.monotonic() + 1800
+        while (svc.status(job)["state"] in ("queued", "running")
+               and time.monotonic() < deadline):
+            time.sleep(0.5)
+        payload = svc.status(job)
+        assert payload["state"] == "done", payload["error"]
+        assert payload["unique"] == 1_194_428
+    finally:
+        svc.close()

@@ -163,6 +163,27 @@ def test_single_copy_symmetry_47_orbits_all_engines():
             == nat.unique_state_count() == 47)
 
 
+def test_a_symmetric_model_is_checked_under_symmetry_everywhere():
+    """A model that declares itself ``symmetric`` is checked under
+    symmetry by engines built from its ``checker()`` and by the elastic
+    checker, which builds from the model itself: the 47 orbits of the
+    single-copy space at 2 clients and 1 server, not its 93 states."""
+    from single_copy_register import SingleCopyModelCfg
+
+    from stateright_tpu.resilience.elastic import ElasticChecker
+
+    def model():
+        m = SingleCopyModelCfg(2, 1).into_model()
+        m.symmetric = True
+        return m
+
+    assert model().checker()._symmetry is not None
+    dev = model().checker().spawn_tpu_bfs().join()
+    elastic = ElasticChecker(model, workers=1, n_partitions=2,
+                             batch_rows=64, transport="thread").join()
+    assert dev.unique_state_count() == elastic.unique_state_count() == 47
+
+
 def test_single_copy_commutation():
     """The automorphism property for the single-copy rewrite."""
     from single_copy_register import SingleCopyModelCfg
@@ -300,3 +321,39 @@ def test_c4_raw_full_space_fused_and_direct_sigma_fixed():
                      == chunk).all(axis=1).sum())
     assert fixed == 2 * C4_ORBITS - C4_TOTAL == 16_668
     assert reps == C4_ORBITS
+
+
+def test_registry_builds_config_5_with_symmetry_on():
+    """``symmetry`` and ``liveness`` reach the engine through the model
+    registry, as the job service and the benchmark build models: at 4
+    clients the symmetric check admits fewer states than the raw one
+    after the same waves. The old parameter sets build as before."""
+    from stateright_tpu.service.registry import default_registry
+
+    reg = default_registry()
+    logs = {}
+    for sym in (False, True):
+        model, params = reg.build("paxos", {"client_count": 4,
+                                            "server_count": 3,
+                                            "liveness": True,
+                                            "symmetry": sym})
+        assert params["symmetry"] is sym
+        assert "eventually chosen" in [p.name for p in model.properties()]
+        c = model.checker().target_state_count(3000).spawn_tpu_bfs(
+            fused=True, batch_size=64, waves_per_dispatch=4,
+            table_capacity=1 << 14, arena_capacity=1 << 14).join()
+        assert c._use_symmetry is sym
+        logs[sym] = c.dispatch_log
+    raw, sym = logs[False][0], logs[True][0]
+    assert raw["waves"] == sym["waves"]
+    assert sym["unique"] < raw["unique"]
+    assert sym["sym_folded"] > 0 and raw["sym_folded"] is None
+    assert raw["eventually_pending"] == raw["novel"]
+
+    model, params = reg.build("paxos", {"client_count": 2,
+                                        "server_count": 3})
+    assert params == {"client_count": 2, "server_count": 3,
+                      "liveness": False, "symmetry": False}
+    assert model.checker()._symmetry is None
+    assert [p.name for p in model.properties()] == ["linearizable",
+                                                    "value chosen"]
