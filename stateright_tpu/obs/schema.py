@@ -36,7 +36,8 @@ __all__ = [
     "WAVE_FIELDS_V5", "WAVE_FIELDS_V6", "WAVE_FIELDS_V8",
     "WAVE_FIELDS_V9", "WAVE_FIELDS_V11", "WAVE_FIELDS_V12",
     "WAVE_FIELDS_V14", "WAVE_FIELDS_V15", "WAVE_FIELDS_V16",
-    "WAVE_FIELDS_V17", "WAVE_FIELDS_V18", "WAVE_NULL_DEFAULTS",
+    "WAVE_FIELDS_V17", "WAVE_FIELDS_V18", "WAVE_FIELDS_V19",
+    "WAVE_NULL_DEFAULTS",
     "validate_event",
     "validate_line",
 ]
@@ -230,10 +231,15 @@ SHED_REASONS = ("slo_burn", "brownout", "retry_budget", "queue_full")
 #: the dispatch's waves) and ``eventually_pending`` (admitted rows that
 #: still carry an eventually bit). ``null`` where the program is built
 #: without symmetry, or without an eventually property.
-#: v1-v18 streams still validate (against their version's field set);
+#: v20: fused dispatches count the rows their store's append loop
+#: carried — wave events gained ``append_slots`` (each append trip's
+#: rows, summed over the dispatch's waves; on a mesh the slowest
+#: shard's per exchange round). Σ``novel`` / Σ``append_slots`` is how
+#: full the trips run. ``null`` where ``probe_slots`` is.
+#: v1-v19 streams still validate (against their version's field set);
 #: streams NEWER than this validator are rejected with a clear
 #: upgrade message instead of a cascade of field-set mismatches.
-SCHEMA_VERSION = 19
+SCHEMA_VERSION = 20
 
 #: Environment knob: set to a file path to stream JSONL events there.
 #: Unset means the null tracer — the hot loop pays one attribute check.
@@ -370,6 +376,9 @@ WAVE_FIELDS: Dict[str, tuple] = {
     # an eventually bit left (null where the program counts neither).
     "sym_folded": _INT + (_NULL,),
     "eventually_pending": _INT + (_NULL,),
+    # v20: the rows the store's append trips carried (null where the
+    # trips are not counted).
+    "append_slots": _INT + (_NULL,),
 }
 
 #: The wave keys a producer stamps ``null`` where its entry carries no
@@ -406,6 +415,8 @@ WAVE_NULL_DEFAULTS = (
     "exchange_rounds",
     # v19 symmetry and eventually counters: null where not counted.
     "sym_folded", "eventually_pending",
+    # v20 append slots: null where the probe's slots are.
+    "append_slots",
 )
 
 #: v5 attribution keys (absent from v2-v4 wave events).
@@ -447,6 +458,9 @@ _WAVE_V18_KEYS = ("exchange_rounds",)
 #: events).
 _WAVE_V19_KEYS = ("sym_folded", "eventually_pending")
 
+#: v20 append-slot key (absent from v1-v19 wave events).
+_WAVE_V20_KEYS = ("append_slots",)
+
 #: The v1 wave field set (no bandwidth gauges) — v1 captures validate
 #: against this exactly.
 WAVE_FIELDS_V1: Dict[str, tuple] = {
@@ -455,7 +469,7 @@ WAVE_FIELDS_V1: Dict[str, tuple] = {
     + _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
     + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
     + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS
-    + _WAVE_V19_KEYS}
+    + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v2-v4 wave field set (bandwidth gauges, no attribution keys).
 WAVE_FIELDS_V2: Dict[str, tuple] = {
@@ -463,74 +477,83 @@ WAVE_FIELDS_V2: Dict[str, tuple] = {
     if k not in _WAVE_V5_KEYS + _WAVE_V6_KEYS + _WAVE_V8_KEYS
     + _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
     + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS
-    + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    + _WAVE_V18_KEYS + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v5 wave field set (attribution keys, no tier gauges).
 WAVE_FIELDS_V5: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V6_KEYS + _WAVE_V8_KEYS + _WAVE_V9_KEYS
     + _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS
-    + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS
+    + _WAVE_V20_KEYS}
 
 #: The v6-v7 wave field set (tier gauges, no kernel-path keys).
 WAVE_FIELDS_V6: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V8_KEYS + _WAVE_V9_KEYS + _WAVE_V10_KEYS
     + _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS
-    + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v8 wave field set (kernel-path keys, no mux attribution).
 WAVE_FIELDS_V8: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V9_KEYS + _WAVE_V10_KEYS + _WAVE_V12_KEYS
     + _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS
-    + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    + _WAVE_V18_KEYS + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v9 wave field set (mux attribution, no async-I/O gauge).
 WAVE_FIELDS_V9: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V10_KEYS + _WAVE_V12_KEYS + _WAVE_V13_KEYS
     + _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS
-    + _WAVE_V19_KEYS}
+    + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v10-v11 wave field set (async-I/O gauge, no expand_impl).
 WAVE_FIELDS_V11: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V12_KEYS + _WAVE_V13_KEYS + _WAVE_V15_KEYS
-    + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    + _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS
+    + _WAVE_V20_KEYS}
 
 #: The v12 wave field set (expand_impl, no cost attribution).
 WAVE_FIELDS_V12: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V13_KEYS + _WAVE_V15_KEYS + _WAVE_V16_KEYS
-    + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    + _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v13-v14 wave field set (cost attribution, no loop rounds).
 WAVE_FIELDS_V14: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V15_KEYS + _WAVE_V16_KEYS + _WAVE_V17_KEYS
-    + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    + _WAVE_V18_KEYS + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v15 wave field set (loop rounds, no shard-exchange counts).
 WAVE_FIELDS_V15: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
     if k not in _WAVE_V16_KEYS + _WAVE_V17_KEYS + _WAVE_V18_KEYS
-    + _WAVE_V19_KEYS}
+    + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v16 wave field set (shard-exchange counts, no probe slots).
 WAVE_FIELDS_V16: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    if k not in _WAVE_V17_KEYS + _WAVE_V18_KEYS + _WAVE_V19_KEYS
+    + _WAVE_V20_KEYS}
 
 #: The v17 wave field set (probe slots, no exchange rounds).
 WAVE_FIELDS_V17: Dict[str, tuple] = {
     k: v for k, v in WAVE_FIELDS.items()
-    if k not in _WAVE_V18_KEYS + _WAVE_V19_KEYS}
+    if k not in _WAVE_V18_KEYS + _WAVE_V19_KEYS + _WAVE_V20_KEYS}
 
 #: The v18 wave field set (exchange rounds, no symmetry or eventually
 #: counters).
 WAVE_FIELDS_V18: Dict[str, tuple] = {
-    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V19_KEYS}
+    k: v for k, v in WAVE_FIELDS.items()
+    if k not in _WAVE_V19_KEYS + _WAVE_V20_KEYS}
+
+#: The v19 wave field set (symmetry and eventually counters, no append
+#: slots).
+WAVE_FIELDS_V19: Dict[str, tuple] = {
+    k: v for k, v in WAVE_FIELDS.items() if k not in _WAVE_V20_KEYS}
 
 _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            3: WAVE_FIELDS_V2, 4: WAVE_FIELDS_V2,
@@ -545,7 +568,7 @@ _WAVE_FIELDS_BY_VERSION = {1: WAVE_FIELDS_V1, 2: WAVE_FIELDS_V2,
                            13: WAVE_FIELDS_V14, 14: WAVE_FIELDS_V14,
                            15: WAVE_FIELDS_V15, 16: WAVE_FIELDS_V16,
                            17: WAVE_FIELDS_V17, 18: WAVE_FIELDS_V18,
-                           19: WAVE_FIELDS}
+                           19: WAVE_FIELDS_V19, 20: WAVE_FIELDS}
 
 #: Required fields per trace event type (beyond the stamped
 #: schema_version/engine/run/t, which every event carries).

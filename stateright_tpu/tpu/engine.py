@@ -132,9 +132,9 @@ class TpuBfsChecker(Checker):
 
     #: whether this engine can bound its wave outputs with the successor
     #: ladder (per-wave engines: outputs cross to the host, so K-bounded
-    #: gathers and transfers pay off; the fused engines append on device
-    #: with a full window — narrowing it breaks the donated arena's
-    #: in-place aliasing, see fused.py — and opt out).
+    #: gathers and transfers pay off; the fused engines append only the
+    #: admitted rows on device, a chunk at a time — ``append_rows`` —
+    #: and opt out).
     _SUCC_LADDER_CAPABLE = True
 
     #: whether jobs targeting this engine shape can be admitted into a
@@ -258,7 +258,7 @@ class TpuBfsChecker(Checker):
         self._pack_on = bool(pack_arena) and self._layout.packs
         self._Wrow = self._layout.packed_width if self._pack_on else self._W
         # Successor-side output ladder (classic per-wave engines only:
-        # the fused engines keep full-window arena appends — see
+        # the fused engines append on device — see
         # _SUCC_LADDER_CAPABLE). Results are K-independent (overflowed
         # waves regather losslessly), so this is purely a performance
         # schedule, like the input bucket ladder.
@@ -2011,6 +2011,90 @@ def compaction_order(mask):
     slot = jnp.where(mask, kept, total_kept + dropped)
     return (jnp.zeros((n,), jnp.int32).at[slot].set(
         jnp.arange(n, dtype=jnp.int32), mode="drop"))
+
+
+#: Rows the fused waves' append loop carries per trip. A wave admits a
+#: few percent of its B*F rows, and gathering, packing and writing a
+#: row costs the same whether it is admitted or not; so the store
+#: compacts the admitted rows' indices and appends them in chunks of
+#: this many rows (fewer where a call has fewer rows). On a v5e the
+#: store took 0.96 / 0.92 / 1.65 ms a wave in paxos-3 (~4,000 rows
+#: admitted a wave) and 1.68 / 1.86 / 1.89 ms in 2pc-10 (~8,500) at
+#: 4096 / 8192 / 16384-row chunks; the whole waves at 4096 and 8192
+#: came within 0.8% of each other, the one faster in each cell. On the
+#: 2x2 mesh (2pc-11, ~10,000 rows admitted an owner a round) the store
+#: took 1.95 / 2.19 ms at 4096 / 16384, the waves within 0.3%.
+APPEND_CHUNK = 8192
+
+
+def append_chunk(rows: int) -> int:
+    """The rows each trip of the append loop carries for a call over
+    ``rows`` rows."""
+    return min(rows, APPEND_CHUNK)
+
+
+def append_window(rows: int) -> int:
+    """Arena rows past the tail that appending up to ``rows`` rows may
+    write: whole chunks. ``dynamic_update_slice`` moves a window that
+    would overrun the arena back over admitted rows, so a wave runs only
+    where this many rows are free."""
+    chunk = append_chunk(rows)
+    return -(-rows // chunk) * chunk
+
+
+def gather_rows(rows, idx):
+    """``rows[idx]`` for a 2-D ``rows``, one gather per column. Where a
+    chunk of whole rows is appended to an arena as it was gathered, the
+    TPU compiler lays the arena out as the gather's output and copies it
+    (32 GiB for a 2^26-row arena of 13 words); stacked columns leave the
+    arena's layout alone."""
+    return jnp.stack([rows[:, w][idx] for w in range(rows.shape[1])],
+                     axis=-1)
+
+
+def append_rows(arena, tail, new_mask, take, acc):
+    """Appends the rows ``new_mask`` marks to the ``arena`` arrays at
+    ``tail``, in row order (the bfs.rs:262 enqueue order), walking them
+    in chunks of ``append_chunk`` rows, so that the cost follows the
+    admitted rows and not the wave's.
+
+    ``take(idx, live, acc)`` gives one chunk: ``idx`` are the int32
+    source indices of its rows, ``live`` marks those that are admitted
+    (the rest are written past the new tail, where no read looks). It
+    returns the chunk's rows, one array per arena array in the arena's
+    row format, and ``acc`` with the live rows folded in. Returns
+    ``(arena, acc, trips)``; ``trips`` (int32) is the chunks written."""
+    n = new_mask.shape[0]
+    chunk = append_chunk(n)
+    padded = -(-n // chunk) * chunk
+    # Compact: admitted row r's index goes to slot rank[r]. One int32
+    # cumsum and one int32 scatter (the probe compacts its candidates
+    # the same way).
+    rank = jnp.cumsum(new_mask, dtype=jnp.int32) - 1
+    count = rank[-1] + 1
+    order = jnp.zeros((padded,), jnp.int32).at[
+        jnp.where(new_mask, rank, padded)].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+    lanes = jnp.arange(chunk, dtype=jnp.int32)
+
+    def cond(carry):
+        _, start, _, _ = carry
+        return start < count
+
+    def body(carry):
+        arena, start, acc, trips = carry
+        idx = jax.lax.dynamic_slice(order, (start,), (chunk,))
+        rows, acc = take(idx, start + lanes < count, acc)
+        at = tail + start.astype(tail.dtype)
+        zero = jnp.zeros((), tail.dtype)
+        arena = tuple(
+            jax.lax.dynamic_update_slice(a, r, (at,) + (zero,) * (a.ndim - 1))
+            for a, r in zip(arena, rows))
+        return arena, start + chunk, acc, trips + 1
+
+    arena, _, acc, trips = jax.lax.while_loop(
+        cond, body, (tuple(arena), jnp.int32(0), acc, jnp.int32(0)))
+    return arena, acc, trips
 
 
 # Fibonacci mixing constant (2^64 / golden ratio). The *high* bits of

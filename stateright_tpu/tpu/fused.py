@@ -12,8 +12,8 @@ config). This engine removes the boundary entirely:
   Rows ``[head, tail)`` are the not-yet-expanded BFS frontier, so the
   arena *is* the queue (FIFO ⇒ level order, like the pending queue of
   `bfs.rs:70-74`), *is* the parent map (`bfs.rs:26`), and *is* the
-  checkpoint payload. Appends are one ``dynamic_update_slice`` per wave —
-  contiguous, no scatter.
+  checkpoint payload. A wave appends its admitted rows a chunk at a
+  time, each one ``dynamic_update_slice`` — contiguous, no scatter.
 - **Fused waves**: one dispatch runs up to ``waves_per_dispatch`` BFS
   waves in a ``lax.while_loop``; property discoveries are resolved on
   device (first-hit fingerprint per property, in frontier order — the
@@ -46,10 +46,11 @@ import jax
 import jax.numpy as jnp
 
 from ..model import Expectation
-from .engine import (TpuBfsChecker, compaction_order, dedup_and_insert,
+from .engine import (TpuBfsChecker, append_chunk, append_rows,
+                     append_window, dedup_and_insert,
                      dedup_and_insert_counted, eval_properties,
-                     expand_frontier, fingerprint_successors, pick_bucket,
-                     probe_chunk)
+                     expand_frontier, fingerprint_successors, gather_rows,
+                     pick_bucket, probe_chunk)
 from .hashing import SENTINEL
 
 __all__ = ["FusedTpuBfsChecker", "FusedUnsupported"]
@@ -62,16 +63,17 @@ __all__ = ["FusedTpuBfsChecker", "FusedUnsupported"]
 # dispatch; ``TARGET`` rides along unchanged; ``CAND`` accumulates the
 # distinct candidates that reached the global probe (the local-dedup
 # collapse telemetry); ``PROBE_ROUNDS``/``DEDUP_ROUNDS`` are the rounds
-# the dispatch's table-probe and local-dedup loops ran, summed over its
-# waves (per dispatch, like ``WAVES``); discovery fingerprints are
+# the dispatch's table-probe and local-dedup loops ran, and
+# ``APPEND_TRIPS`` the chunks its store appended, summed over its waves
+# (per dispatch, like ``WAVES``); discovery fingerprints are
 # bitcast into the slots after ``ST_DISC`` (they also travel as a
 # separate donated array between dispatches). Programs built with
 # symmetry, or with an eventually property, append their per-dispatch
 # counters after the discovery slots (``stat_counters``), so the other
 # programs' stats vector keeps its length.
 (ST_HEAD, ST_TAIL, ST_OCC, ST_SUCC, ST_CAND, ST_TARGET, ST_ERR,
- ST_WAVES, ST_PROBE_ROUNDS, ST_DEDUP_ROUNDS) = range(10)
-ST_DISC = 10
+ ST_WAVES, ST_PROBE_ROUNDS, ST_DEDUP_ROUNDS, ST_APPEND_TRIPS) = range(11)
+ST_DISC = 11
 
 
 #: the optional per-dispatch counters (schema v19)
@@ -88,6 +90,41 @@ def stat_counters(use_sym: bool, properties) -> tuple:
     if any(p.expectation is Expectation.EVENTUALLY for p in properties):
         names += ("eventually_pending",)
     return names
+
+
+def append_successors(arena, tail, new_mask, succ_flat, path_fps, bfps,
+                      cleared, layout=None, err_lane=None,
+                      count_pending=False):
+    """The fused wave's store: appends the admitted successors
+    (``new_mask`` over the wave's B*F rows) to the arena arrays ``(vecs,
+    fps, parent fps, ebits)`` at ``tail``, in frontier order. A row's
+    parent is its frontier row, ``index // F``: its parent fingerprint
+    comes from ``bfps`` and its eventually bits from ``cleared``.
+    Returns ``(arena, err, pending, trips)``: whether an admitted row
+    sets ``err_lane``, the admitted rows with an eventually bit set
+    (int32; 0 unless ``count_pending``), and the append loop's trips."""
+    F = succ_flat.shape[0] // bfps.shape[0]
+
+    def take(idx, live, acc):
+        err, pending = acc
+        # Packing builds the rows anew; unpacked ones are gathered a
+        # column at a time (``gather_rows``).
+        vecs = succ_flat[idx] if layout is not None else gather_rows(
+            succ_flat, idx)
+        parent = idx // F
+        ebits = cleared[parent]
+        if err_lane is not None:
+            err = err | jnp.any((vecs[:, err_lane] != 0) & live)
+        if count_pending:
+            pending = pending + jnp.sum((ebits != 0) & live,
+                                        dtype=jnp.int32)
+        if layout is not None:
+            vecs = layout.pack(vecs)
+        return (vecs, path_fps[idx], bfps[parent], ebits), (err, pending)
+
+    arena, (err, pending), trips = append_rows(
+        arena, tail, new_mask, take, (jnp.bool_(False), jnp.int32(0)))
+    return arena, err, pending, trips
 
 
 class FusedUnsupported(TypeError):
@@ -147,9 +184,8 @@ class FusedTpuBfsChecker(TpuBfsChecker):
     # (the jit cache), never dispatches (service/mux.py checks this).
     _MUX_CAPABLE = False
 
-    # The fused wave appends to the donated arena through a full-window
-    # dynamic_update_slice on purpose (narrowing it breaks XLA's
-    # in-place aliasing — see the wave body), and its outputs never
+    # The fused wave appends only its admitted rows to the donated
+    # arena, a chunk at a time (``append_rows``), and its outputs never
     # cross the host boundary, so the successor output ladder has
     # nothing to bound here. Local dedup still runs (inside
     # dedup_and_insert_counted), and its collapse telemetry rides the
@@ -281,36 +317,22 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     disc = disc.at[i].set(first_hit(disc[i], hit, bfps))
 
             # Append the survivors at the arena tail (frontier order —
-            # the bfs.rs:262 enqueue order). Rows past new_count are
-            # garbage beyond tail: overwritten by the next wave, never
-            # read (all reads mask by tail). The append window is the
-            # full S rows on purpose: narrowing it behind a lax.cond
-            # breaks XLA's in-place aliasing of the donated arena and
-            # forces whole-arena copies per wave (measured ~2x wall on
-            # the CPU backend), which dwarfs the bytes saved.
+            # the bfs.rs:262 enqueue order), a chunk of admitted rows a
+            # trip. Rows of the last chunk past new_count are garbage
+            # beyond tail: overwritten by the next wave, never read (all
+            # reads mask by tail). The donated arena stays aliased in
+            # place through the append loop: on a v5e the memory peak did
+            # not rise, and the store fell 6.62 -> 0.92 ms a wave in
+            # paxos-3 against the full S-row window it replaced.
             with jax.named_scope("store"):
-                comp = compaction_order(new_mask)
-                parent_rows = comp // F
-                new_vecs = succ_flat[comp]
-                new_fps = path_fps[comp]
-                new_parent = bfps[parent_rows]
-                new_ebits = cleared[parent_rows]
-                if err_lane is not None:
-                    err = err | jnp.any((new_vecs[:, err_lane] != 0)
-                                        & (jnp.arange(S) < new_count))
-                if layout is not None:
-                    new_vecs = layout.pack(new_vecs)
-                start = (tail,)
-                vecs_a = jax.lax.dynamic_update_slice(
-                    vecs_a, new_vecs, (tail, jnp.int64(0)))
-                fps_a = jax.lax.dynamic_update_slice(fps_a, new_fps, start)
-                par_a = jax.lax.dynamic_update_slice(par_a, new_parent,
-                                                     start)
-                eb_a = jax.lax.dynamic_update_slice(eb_a, new_ebits, start)
-                if "eventually_pending" in counters:
-                    wave_counts["eventually_pending"] = jnp.sum(
-                        (new_ebits != 0) & (jnp.arange(S) < new_count),
-                        dtype=jnp.int32)
+                (vecs_a, fps_a, par_a, eb_a), new_err, pending, trips = (
+                    append_successors(
+                        (vecs_a, fps_a, par_a, eb_a), tail, new_mask,
+                        succ_flat, path_fps, bfps, cleared, layout,
+                        err_lane, "eventually_pending" in counters))
+                err = err | new_err
+                wave_counts["eventually_pending"] = pending
+                wave_rounds += (trips,)
 
             nc = new_count.astype(jnp.int64)
             return (vecs_a, fps_a, par_a, eb_a, visited,
@@ -326,7 +348,7 @@ class FusedTpuBfsChecker(TpuBfsChecker):
             (_, _, _, _, _, head, tail, occ, succ_total, _cand, err,
              disc, waves, _rounds, _counts, target) = carry
             more = (waves < K) & (head < tail) & ~err
-            more = more & (tail + S <= ucap)
+            more = more & (tail + append_window(S) <= ucap)
             more = more & (occ + S <= capacity // 2)
             if P:
                 more = more & ~jnp.all(disc != sentinel)
@@ -345,19 +367,19 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                                       ST_SUCC, ST_CAND, ST_TARGET))
             carry = (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
                      succ_total, cand_total, stats_in[ST_ERR] != 0, disc,
-                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2,
+                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 3,
                      (jnp.int32(0),) * len(counters), target)
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
              succ_total, cand_total, err, disc, waves, rounds, counts,
              _) = jax.lax.while_loop(cond, wave_t, carry)
             # Discovery slots ride in the stats vector (bitcast, so the
             # SENTINEL survives) — one host fetch per dispatch, not two.
-            local_rounds, probe_rounds = (r.astype(jnp.int64)
-                                          for r in rounds)
+            local_rounds, probe_rounds, append_trips = (
+                r.astype(jnp.int64) for r in rounds)
             stats = [
                 jnp.stack([head, tail, occ, succ_total, cand_total,
                            target, err.astype(jnp.int64), waves,
-                           probe_rounds, local_rounds]),
+                           probe_rounds, local_rounds, append_trips]),
                 jax.lax.bitcast_convert_type(disc, jnp.int64)]
             if counters:
                 stats.append(jnp.stack([c.astype(jnp.int64)
@@ -619,6 +641,9 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                     # each round over a chunk of the wave's B*F rows.
                     probe_slots=(int(stats_h[ST_PROBE_ROUNDS])
                                  * probe_chunk(meta["bucket"] * self._F)),
+                    # v20: the rows the store's append trips carried.
+                    append_slots=(int(stats_h[ST_APPEND_TRIPS])
+                                  * append_chunk(meta["bucket"] * self._F)),
                     host_s=launch_s + (now - t_proc) - waited,
                     # v19: what symmetry and the eventually bits did
                     # (null where the program does not count them).
@@ -696,8 +721,9 @@ class FusedTpuBfsChecker(TpuBfsChecker):
             # Intended next bucket + its per-wave append bound.
             bucket = pick_bucket(self._buckets, tail - head)
             S_b = bucket * F
+            A_b = append_window(S_b)
             growth = (occ + S_b > self._capacity // 2
-                      or tail + S_b > ucap)
+                      or tail + A_b > ucap)
             ckpt_due = (self._ckpt_path is not None
                         and (self._unique_count - last_ckpt_states
                              >= self._ckpt_every * self._B))
@@ -734,7 +760,7 @@ class FusedTpuBfsChecker(TpuBfsChecker):
                                                       new_cap)(visited)
                             self._capacity = new_cap
                             self._visited = visited
-                        while tail + S_b > ucap:
+                        while tail + A_b > ucap:
                             budget = self._store.device_budget \
                                 if self._store.active else None
                             over = (budget is not None

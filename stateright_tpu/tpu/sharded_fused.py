@@ -62,14 +62,16 @@ from ._compat import shard_map
 
 from ..model import Expectation
 from ..resilience.membership import EpochOwnership, OwnerMap
-from .engine import (compaction_order, dedup_and_insert,
-                     dedup_and_insert_counted, eval_properties,
-                     expand_frontier, fingerprint_successors,
-                     first_occurrence_unscoped, host_table_insert,
-                     pick_bucket, probe_chunk)
-from .fused import (FusedTpuBfsChecker, ST_CAND, ST_DEDUP_ROUNDS, ST_DISC,
-                    ST_ERR, ST_HEAD, ST_OCC, ST_PROBE_ROUNDS, ST_SUCC,
-                    ST_TAIL, ST_TARGET, ST_WAVES, _pow2, _releasing)
+from .engine import (append_chunk, append_rows, append_window,
+                     dedup_and_insert, dedup_and_insert_counted,
+                     eval_properties, expand_frontier,
+                     fingerprint_successors, first_occurrence_unscoped,
+                     gather_rows, host_table_insert, pick_bucket,
+                     probe_chunk)
+from .fused import (FusedTpuBfsChecker, ST_APPEND_TRIPS, ST_CAND,
+                    ST_DEDUP_ROUNDS, ST_DISC, ST_ERR, ST_HEAD, ST_OCC,
+                    ST_PROBE_ROUNDS, ST_SUCC, ST_TAIL, ST_TARGET, ST_WAVES,
+                    _pow2, _releasing)
 from .hashing import SENTINEL
 
 __all__ = ["ShardedFusedTpuBfsChecker"]
@@ -77,7 +79,8 @@ __all__ = ["ShardedFusedTpuBfsChecker"]
 # The stats row is the single-chip layout with two more slots before
 # the discovery fingerprints: the successor rows the dispatch's waves
 # sent to another shard, summed over the mesh, and the exchange rounds
-# they took.
+# they took. Its loop counts (``ST_PROBE_ROUNDS``, ``ST_DEDUP_ROUNDS``,
+# ``ST_APPEND_TRIPS``) are the slowest shard's each round.
 ST_EXCHANGE_ROWS = ST_DISC
 ST_EXCHANGE_ROUNDS = ST_DISC + 1
 SH_DISC = ST_DISC + 2
@@ -94,13 +97,40 @@ def exchange_bucket_rows(rows: int, shards: int) -> int:
 
 def exchange_window_rows(rows: int, shards: int) -> int:
     """Arena rows past a shard's tail that one wave's appends may
-    write. Round ``r`` appends a window of ``shards * CAP`` rows after
-    what the rounds before it admitted (at most ``shards * r * CAP``),
-    and a wave takes at most ``ceil(rows / CAP)`` rounds: so
-    ``shards * ceil(rows / CAP) * CAP``, which is ``shards * rows``
-    where ``CAP`` divides ``rows``."""
+    write. Round ``r`` appends what it admitted of the ``shards * CAP``
+    rows it received, in whole chunks (``append_window``), after what
+    the rounds before it admitted (at most ``shards * r * CAP``), and a
+    wave takes at most ``ceil(rows / CAP)`` rounds: so
+    ``shards * (ceil(rows / CAP) - 1) * CAP + append_window(shards *
+    CAP)``, which is ``shards * rows`` where ``CAP`` divides ``rows``
+    and the chunk divides ``shards * CAP``."""
     cap = exchange_bucket_rows(rows, shards)
-    return shards * -(-rows // cap) * cap
+    return (shards * (-(-rows // cap) - 1) * cap
+            + append_window(shards * cap))
+
+
+def append_received(arena, tail, new_mask, recv_vecs, recv_path,
+                    recv_parent, recv_ebits, layout=None, err_lane=None):
+    """The mesh owner's store: appends the admitted rows (``new_mask``
+    over the rows it received in an exchange round, already in the
+    arena's row format) to its arena arrays ``(vecs, fps, parent fps,
+    ebits)`` at ``tail``, in received order. Returns ``(arena, err,
+    trips)``: whether an admitted row sets ``err_lane``, and the append
+    loop's trips."""
+    def take(idx, live, err):
+        vecs = gather_rows(recv_vecs, idx)
+        if err_lane is not None:
+            # Rows are packed here; extract just the error lane from
+            # the packed words (no full unpack).
+            err_col = (vecs[:, err_lane] if layout is None
+                       else layout.lane(vecs, err_lane))
+            err = err | jnp.any((err_col != 0) & live)
+        return (vecs, recv_path[idx], recv_parent[idx],
+                recv_ebits[idx]), err
+
+    arena, err, trips = append_rows(arena, tail, new_mask, take,
+                                    jnp.bool_(False))
+    return arena, err, trips
 
 
 class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
@@ -349,27 +379,14 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             new_mask, new_count, cand_count, visited, wave_rounds = (
                 dedup_and_insert_counted(recv_dedup, visited, capacity))
 
-            # Full-window append on purpose: a cond-narrowed window
-            # breaks the donated arena's in-place aliasing (see the
-            # single-chip fused wave).
             with jax.named_scope("store"):
-                comp = compaction_order(new_mask)
-                new_vecs = recv_vecs[comp]
-                if err_lane is not None:
-                    # Rows are packed here; extract just the error lane
-                    # from the packed words (no full unpack).
-                    err_col = (new_vecs[:, err_lane] if layout is None
-                               else layout.lane(new_vecs, err_lane))
-                    err = err | jnp.any((err_col != 0)
-                                        & (jnp.arange(RR) < new_count))
-                vecs_a = jax.lax.dynamic_update_slice(
-                    vecs_a, new_vecs, (tail, jnp.int64(0)))
-                fps_a = jax.lax.dynamic_update_slice(
-                    fps_a, recv_path[comp], (tail,))
-                par_a = jax.lax.dynamic_update_slice(
-                    par_a, recv_parent[comp], (tail,))
-                eb_a = jax.lax.dynamic_update_slice(
-                    eb_a, recv_ebits[comp], (tail,))
+                (vecs_a, fps_a, par_a, eb_a), new_err, trips = (
+                    append_received(
+                        (vecs_a, fps_a, par_a, eb_a), tail, new_mask,
+                        recv_vecs, recv_path, recv_parent, recv_ebits,
+                        layout, err_lane))
+                err = err | new_err
+                wave_rounds += (trips,)
 
             nc = new_count.astype(jnp.int64)
             # A wave's successors and sent rows count in its first round.
@@ -429,19 +446,19 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
             carry = (vecs_a, fps_a, par_a, eb_a, visited, head, tail,
                      occ, succ_total, cand_total, jnp.zeros((), jnp.int64),
                      stats_in[0, ST_ERR] != 0, disc,
-                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 2,
+                     jnp.zeros((), jnp.int64), (jnp.int32(0),) * 3,
                      jnp.int32(0), jnp.int32(0), tail, target)
             (vecs_a, fps_a, par_a, eb_a, visited, head, tail, occ,
              succ_total, cand_total, sent, err, disc, waves, rounds,
              x_rounds, *_) = jax.lax.while_loop(cond, wave, carry)
-            local_rounds, probe_rounds = (r.astype(jnp.int64)
-                                          for r in rounds)
+            local_rounds, probe_rounds, append_trips = (
+                r.astype(jnp.int64) for r in rounds)
             # Discovery slots (replicated) ride in each shard's stats row
             # so the host reads one packed array per dispatch.
             stats = jnp.concatenate([
                 jnp.stack([head, tail, occ, succ_total, cand_total,
                            target, err.astype(jnp.int64), waves,
-                           probe_rounds, local_rounds, sent,
+                           probe_rounds, local_rounds, append_trips, sent,
                            x_rounds.astype(jnp.int64)]),
                 jax.lax.bitcast_convert_type(disc, jnp.int64)])[None]
             return vecs_a, fps_a, par_a, eb_a, visited, disc, stats
@@ -682,6 +699,10 @@ class ShardedFusedTpuBfsChecker(EpochOwnership, FusedTpuBfsChecker):
                     # an exchange round.
                     probe_slots=int(stats_h[0, ST_PROBE_ROUNDS])
                     * probe_chunk(n * cap),
+                    # v20: the rows the owner's append trips carried,
+                    # the slowest shard's each round.
+                    append_slots=int(stats_h[0, ST_APPEND_TRIPS])
+                    * append_chunk(n * cap),
                     host_s=launch_s + (now - t_proc) - waited,
                     # v16: successor rows sent to another shard, and the
                     # rows the all-to-alls carried between shards (each

@@ -90,13 +90,13 @@ FUSED = {"paxos3": (chip_smoke.paxos3, (4096, 1 << 22, 1 << 21)),
 
 #: sha256 of each cell's fused dispatch compiled for one described v5e
 #: chip, its text without metadata (``_hlo_digest``): the program as the
-#: chunked probe left it. A change to the single-chip wave changes
+#: chunked append left it. A change to the single-chip wave changes
 #: these on purpose.
 FUSED_HLO_SHA256 = {
-    "paxos3": ("7fe0aeeed73766fad593b9e7f0941ebf"
-                "f0e6aa079a241b2bff47419b1448b64b"),
-    "twopc10": ("627d1e29c014eba15882a4b72148feaa"
-                "3fd2d4a42b62a3b54ff8274d60b588a9"),
+    "paxos3": ("0a5317472c241fa39a61189181501e92"
+               "c13e59c8a4748a1c3148aee6daceb2a0"),
+    "twopc10": ("a4ce8f930f617f76f3fc30316583bd1a"
+                "4cdd2ee829273b3f671cfcd4208dfc67"),
 }
 
 #: the stack-frame tables at the head of a compiled module's text
@@ -136,7 +136,7 @@ def test_fused_dispatch_compiles(fused_programs, name):
 @pytest.mark.parametrize("name", sorted(FUSED_HLO_SHA256))
 def test_fused_dispatch_hlo_is_unchanged(fused_programs, name):
     """The single-chip cells' programs, modulo metadata, are the ones
-    the chunked probe made: a later change that was meant to leave the
+    the chunked append made: a later change that was meant to leave the
     single-chip wave alone shows here."""
     assert _hlo_digest(fused_programs[name].as_text()) == (
         FUSED_HLO_SHA256[name])
@@ -179,27 +179,71 @@ def _ops(text: str, kind: str) -> list:
                       + r'\([^\n]*?metadata=\{op_name="([^"]*)"', text)
 
 
-def _probe_loop_gather_rows(text: str) -> set:
-    """The leading dimension of every gather inside the ``probe``
-    scope's loops."""
+def _loop_gather_rows(text: str, scope: str) -> set:
+    """The leading dimension of every gather inside ``scope``'s loops."""
     return {int(rows) for rows, path in re.findall(
         r"= [a-z0-9]+\[(\d+)[^\n]*? gather\([^\n]*?"
         r'metadata=\{op_name="([^"]*)"', text)
-        if "probe/while" in path}
+        if f"{scope}/while" in path}
 
 
 def test_fused_probe_loop_carries_one_chunk(fused_programs):
     """The twopc10-check wave's probe rounds gather a chunk of compacted
     candidates, not the wave's B*F rows."""
-    assert _probe_loop_gather_rows(fused_programs["twopc10"].as_text()) == {
-        engine.PROBE_CHUNK}
+    assert _loop_gather_rows(fused_programs["twopc10"].as_text(),
+                             "probe") == {engine.PROBE_CHUNK}
+
+
+@pytest.mark.parametrize("name", ["paxos3", "twopc10"])
+def test_fused_store_loop_carries_one_chunk(fused_programs, name):
+    """The single-chip cells' stores gather a chunk of admitted rows a
+    trip, not the wave's B*F rows."""
+    assert _loop_gather_rows(fused_programs[name].as_text(),
+                             "store") == {engine.APPEND_CHUNK}
+
+
+def _arena_bytes(prog, shards: int = 1) -> int:
+    """A chip's bytes of the dispatch's four arena arrays, its first
+    four arguments."""
+    args = prog.in_avals[0][:4]
+    return sum(a.size * a.dtype.itemsize for a in args) // shards
+
+
+def _assert_arena_in_place(prog, shards: int = 1):
+    """The donated arena is aliased to the program's output, and no
+    copy of an arena array is made (a copy into another layout of the
+    twopc11 vecs arena alone would take 64 GiB)."""
+    assert prog.memory_analysis().alias_size_in_bytes >= _arena_bytes(
+        prog, shards)
+    rows = prog.in_avals[0][0].shape[0] // shards
+    copies = re.findall(r"= [a-z0-9]+\[(\d+)[^\n]*? copy\(",
+                        prog.as_text())
+    assert str(rows) not in copies
+
+
+def test_fused_twopc10_appends_in_place(fused_programs):
+    _assert_arena_in_place(fused_programs["twopc10"])
+
+
+def test_fused_unpacked_twopc10_appends_in_place(one_chip):
+    """Unpacked rows are appended as gathered, so this is where a chunk
+    gathered as whole rows has the arena copied into its layout (32 GiB
+    here). At the cell's table and arena, and B=1024 to keep the
+    compile short (the copy does not depend on B); a small arena is no
+    test, since the compiler may lay one out anew at a dispatch's
+    ends."""
+    c = (TwoPhaseSys(10).checker().target_state_count(1)
+         .spawn_tpu_bfs(fused=True, batch_size=64, pack_arena=False)
+         .join())
+    c._aot = _for_chip(one_chip)
+    _assert_arena_in_place(c._build_dispatch_fn(1024, 1 << 27, 1 << 26))
 
 
 #: sha256 of the twopc11-check-4chip dispatch compiled for the
 #: described 2x2, its text without metadata (``_hlo_digest``): the
-#: program as the sized exchange buckets left it
-TWOPC11_MESH_HLO_SHA256 = ("6e71a2eef168b3a88f6d496bfe702eb0"
-                           "9e2ae0a24efaffd2386e12bdf713d847")
+#: program as the chunked append left it
+TWOPC11_MESH_HLO_SHA256 = ("1da5c8fc6940aae9cc9f3a8fbf991a8f"
+                           "fc0f428249f24ff164b204e58607ca5e")
 
 
 def test_twopc11_mesh_dispatch_hlo_is_unchanged(twopc11_mesh_program):
@@ -240,8 +284,19 @@ def test_twopc11_mesh_wave_has_one_local_dedup_loop(twopc11_mesh_program):
 def test_twopc11_mesh_probe_loop_carries_one_chunk(twopc11_mesh_program):
     """An owner's probe rounds gather a chunk of its received rows'
     candidates, not all n*B*F of them."""
-    assert _probe_loop_gather_rows(twopc11_mesh_program.as_text()) == {
-        engine.PROBE_CHUNK}
+    assert _loop_gather_rows(twopc11_mesh_program.as_text(),
+                             "probe") == {engine.PROBE_CHUNK}
+
+
+def test_twopc11_mesh_store_loop_carries_one_chunk(twopc11_mesh_program):
+    """An owner's store gathers a chunk of its admitted rows a trip, not
+    all n*CAP rows of an exchange round."""
+    assert _loop_gather_rows(twopc11_mesh_program.as_text(),
+                             "store") == {engine.APPEND_CHUNK}
+
+
+def test_twopc11_mesh_appends_in_place(twopc11_mesh_program):
+    _assert_arena_in_place(twopc11_mesh_program, shards=4)
 
 
 def test_twopc11_mesh_all_to_alls_carry_one_bucket_per_owner(

@@ -360,19 +360,46 @@ def test_lint_elastic_wave_requires_attribution():
 def test_lint_probe_slots_match_rounds(rounds, slots, ok):
     """v17: probe slots are counted where the probe's rounds are, and
     every counted round carries at least one row."""
-    line = _worker_wave("w0", 1, probe_rounds=rounds, probe_slots=slots)
+    # (v20 counts the append's slots wherever the probe's are counted)
+    line = _worker_wave("w0", 1, probe_rounds=rounds, probe_slots=slots,
+                        append_slots=slots)
     _, errors = trace_lint.lint_lines([line])
     assert (not errors) == ok, errors
 
 
 #: the v19 wave keys, absent from older captures
 V19_KEYS = ("sym_folded", "eventually_pending")
+#: the v20 wave key, absent from older captures
+V20_KEYS = ("append_slots",)
+
+
+@pytest.mark.parametrize("probe,append,ok", [
+    (None, None, True), (3 * 64, 2 * 64, True), (64, 0, True),
+    (64, None, False), (None, 64, False), (64, -1, False)])
+def test_lint_append_slots_match_probe_slots(probe, append, ok):
+    """v20: the append's slots are counted where the probe's are, and
+    never below zero."""
+    line = _worker_wave("w0", 1, probe_rounds=None if probe is None else 3,
+                        probe_slots=probe, append_slots=append)
+    _, errors = trace_lint.lint_lines([line])
+    assert (not errors) == ok, errors
+
+
+def test_lint_v19_wave_has_no_append_slots():
+    line = json.loads(_worker_wave("w0", 1))
+    line["schema_version"] = 19
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
+    assert any("append_slots" in e for e in errors), errors
+    for key in V20_KEYS:
+        del line[key]
+    _, errors = trace_lint.lint_lines([json.dumps(line)])
+    assert not errors, errors
 
 
 def test_lint_v16_wave_has_no_probe_slots():
     line = json.loads(_worker_wave("w0", 1))
     line["schema_version"] = 16
-    for key in ("exchange_rounds",) + V19_KEYS:
+    for key in ("exchange_rounds",) + V19_KEYS + V20_KEYS:
         del line[key]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert any("probe_slots" in e for e in errors), errors
@@ -397,7 +424,7 @@ def test_lint_exchange_rounds_match_slots(rounds, slots, waves, ok):
 def test_lint_v17_wave_has_no_exchange_rounds():
     line = json.loads(_worker_wave("w0", 1))
     line["schema_version"] = 17
-    for key in V19_KEYS:
+    for key in V19_KEYS + V20_KEYS:
         del line[key]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert any("exchange_rounds" in e for e in errors), errors
@@ -411,7 +438,7 @@ def test_lint_v18_wave_has_no_sym_counters():
     line["schema_version"] = 18
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert any("sym_folded" in e for e in errors), errors
-    for key in V19_KEYS:
+    for key in V19_KEYS + V20_KEYS:
         del line[key]
     _, errors = trace_lint.lint_lines([json.dumps(line)])
     assert not errors, errors

@@ -14,6 +14,7 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "examples"))
 
+from stateright_tpu.tpu.engine import append_chunk
 from stateright_tpu.tpu.sharded_fused import (ShardedFusedTpuBfsChecker,
                                               exchange_bucket_rows,
                                               exchange_window_rows)
@@ -53,17 +54,22 @@ def test_matches_classic_sharded_engine_bit_for_bit():
 def test_exchange_buckets_and_window(rows, shards):
     """A bucket is the balanced share, a multiple of 8, so one round
     carries evenly spread rows and a wave takes at most ``shards``
-    rounds. Round r appends a full ``shards * CAP``-row window after at
-    most ``shards * min(rows, r * CAP)`` rows admitted before it: the
-    window a wave may write is the worst of those, ``shards * rows``
-    only where CAP divides ``rows``."""
+    rounds. Round r appends what it admitted of its ``shards * CAP``
+    received rows in whole append chunks, after at most ``shards *
+    min(rows, r * CAP)`` rows admitted before it: the window a wave may
+    write is the worst of those, ``shards * rows`` only where CAP
+    divides ``rows`` and the chunk divides ``shards * CAP``."""
     cap = exchange_bucket_rows(rows, shards)
     assert cap % 8 == 0 and rows <= shards * cap < rows + 8 * shards
     rounds = -(-rows // cap)
     assert rounds <= shards
-    worst = max(shards * (min(rows, r * cap) + cap) for r in range(rounds))
+    chunk = append_chunk(shards * cap)
+    round_window = -(-shards * cap // chunk) * chunk
+    worst = max(shards * min(rows, r * cap) + round_window
+                for r in range(rounds))
     assert exchange_window_rows(rows, shards) == worst >= shards * rows
-    assert (worst == shards * rows) == (rows % cap == 0)
+    assert (worst == shards * rows) == (
+        rows % cap == 0 and round_window == shards * cap)
 
 
 def test_waves_of_several_exchange_rounds(monkeypatch):

@@ -123,6 +123,9 @@ wave events, null exactly where ``exchange_slots`` is and never below
 ``waves``: every wave of an exchanging dispatch runs at least one
 round.
 
+Schema v20 (the chunked append) adds ``append_slots`` to wave events,
+null exactly where ``probe_slots`` is and never negative.
+
 Schema v6 (the tiered state store) adds three more: every FRONTIER
 ``spill`` is eventually followed by a ``page_in`` or the producing
 run's end (a stream that stops with paged-out frontier blocks
@@ -619,6 +622,16 @@ def lint_lines(lines) -> Tuple[Dict[str, int], List[str]]:
                     f"against exchange_slots {x_slots!r} and waves "
                     f"{waves!r}: each wave of an exchange runs at least "
                     "one round")
+            # v20: the append's slots are counted where the probe's are.
+            a_slots = obj.get("append_slots")
+            if (isinstance(obj.get("schema_version"), int)
+                    and obj["schema_version"] >= 20
+                    and ((a_slots is None) != (slots is None)
+                         or (isinstance(a_slots, int) and a_slots < 0))):
+                errors.append(
+                    f"line {lineno}: wave append_slots {a_slots!r} "
+                    f"against probe_slots {slots!r}: the append's slots "
+                    "are counted where the probe's are")
             # v9 attribution window (wave multiplexing): a TOTAL mux
             # wave (job_id null, jobs_in_wave set) opens a window that
             # exactly jobs_in_wave attributed lines must close, their
